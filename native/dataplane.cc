@@ -2595,7 +2595,7 @@ class Engine {
     // No artificial accumulation window: the write pipeline is a closed
     // latency loop (fixed client concurrency), so delaying commits to
     // widen batches proportionally lowers the arrival rate instead —
-    // measured round 5 (BENCH_NOTES): a 6 ms window moved batches only
+    // measured round 5: a 6 ms window moved batches only
     // 1.7 -> 2.1 entries at equal throughput. The stage budgets put the
     // chain at 75-93% of the disk's sustained fdatasync rate already;
     // arrivals during an in-flight sync batch naturally.

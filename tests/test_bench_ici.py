@@ -1,13 +1,9 @@
-"""Pin for the r05->r06 ici_write/ici_ec_scatter halving diagnosis.
+"""Pin for the bench's ICI microbench entry points.
 
-BENCH_r05 recorded ici_write 0.081 / ici_ec_scatter 0.048 GB/s;
-BENCH_r06 recorded 0.041 / 0.038 on the byte-identical kernels (no
-commit touched tpudfs/tpu/ between the rounds). The root cause is the
-host, not the code: on the CPU-fallback protocol these microbenches
-measure one core's emulated-collective throughput, which moves with
-machine state (r05 ran at raw_infeed 3.453, r06 at 2.286 — the same
-~0.6x swing; a probe of the unchanged r06 code on a contended host
-measured 0.021). Full write-up: BENCH_NOTES.md round-8 section.
+BENCH_r05 and BENCH_r06 recorded a 2x swing in ici_write /
+ici_ec_scatter on byte-identical kernels: both ran on the CPU backend,
+where these microbenches measure one core's emulated collectives and
+move with machine state — not a device number and not a code change.
 
 This test pins what CAN regress in code: the exact bench entry points
 must keep producing verified replicas/acks and per-window samples, so a

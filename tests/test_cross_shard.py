@@ -225,9 +225,12 @@ async def test_commit_rpc_failure_recovers(tmp_path):
         # forward only.
         ctx["updated_at_ms"] -= TX_STALE_MS + 1
         # Recovery loop (1 s interval) re-sends Prepare+Commit, then finishes.
+        # (The ack marker is its own proposal, one raft round after the
+        # state flips to committed: wait for both.)
         for _ in range(200):
             ctx = next(iter(src_m.state.transactions.values()), None)
-            if ctx and ctx["state"] == "committed":
+            if ctx and ctx["state"] == "committed" \
+                    and ctx["participant_acked"]:
                 break
             await asyncio.sleep(0.1)
         assert ctx["state"] == "committed" and ctx["participant_acked"]

@@ -256,3 +256,33 @@ def test_malformed_peer_messages_are_rejected_without_state_damage():
         follower.core.handle_message(msg, c.now)
     c.run(1.0)
     c.propose_and_commit({"v": 2})
+
+
+def test_sustained_proposals_keep_quorum_contact_and_lease():
+    """A leader that proposes more often than the heartbeat interval never
+    reaches the tick's heartbeat branch, which used to be the only place a
+    probe round opened: every append carried the last heartbeat's stale
+    seq, quorum contact stopped advancing, and check-quorum deposed a
+    perfectly connected leader 2 * election_max into any write burst (a
+    2 GiB put at 1 MiB blocks: `AllocateBlock failed ... Not Leader`).
+    An append round is a probe round too."""
+    from tests.raft_sim import SimCluster
+
+    c = SimCluster(3, seed=77)
+    lead = c.wait_for_leader()
+    c.propose_and_commit({"v": 0})
+    term = lead.core.term
+    span = 3 * 2 * lead.core.timings.election_max  # 3x the check-quorum bound
+    dt = 0.01
+    assert 2 * dt < lead.core.timings.heartbeat  # proposals outpace it
+    for i in range(int(span / dt)):
+        if i % 2 == 0:
+            c.propose({"v": i})
+        c.step(dt)
+        assert lead.core.role == Role.LEADER and lead.core.term == term, \
+            f"leader deposed {c.now:.2f}s into a sustained write burst"
+    assert lead.stepdowns == 0
+    # Lease reads stay available through the burst (appends renew it).
+    assert lead.core.lease_valid(c.now)
+    status = lead.core.status(c.now)
+    assert status["quorum_contact_age_s"] < lead.core.timings.heartbeat

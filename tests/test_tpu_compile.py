@@ -1,0 +1,220 @@
+"""Compile the main path's device programs for a TPU v5e that is described,
+not attached — the only file in the tree that describes the chip.
+
+A CPU test run cannot execute a Mosaic kernel, but the TPU compiler is
+installed and compiles for a topology description: what it refuses here
+(an unaligned slice, too much VMEM, a program that does not fit HBM) it
+would refuse on the chip. Shapes are ``chip_smoke.py``'s real ones. Nothing
+runs, so this says nothing about results or times — ``chip_smoke.py`` on the
+chip does.
+
+The kernels pick interpret mode from ``on_tpu()``, which asks
+``jax.devices()`` and sees the CPU here; the ``chip`` fixture steers all
+three imported names from the test instead of adding an option to the
+program. Everything that touches the topology lives in fixtures of THIS
+file: only the xdist worker that is handed the file loads libtpu.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+MIB = 1 << 20
+CHUNKS_1MIB = MIB // 512
+#: Padded shard length of RS(6,3) over one 64 MiB block (the client's
+#: default block): ceil(64 MiB / 6) rounded up to the 128-byte lane.
+RS_SHARD = -(-(-(-(64 * MIB) // 6)) // 128) * 128
+#: One v5e chip's HBM as the runtime reports it (`bytes_limit`, PR 22).
+HBM_BYTES = 16_909_336_064
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip; keep it out.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def chip(monkeypatch):
+    """Steer the kernels' single decision point to "on the chip" and keep
+    the traces made under it out of every other test: jitted functions
+    cache their trace per shape, interpret flag included."""
+    import tpudfs.tpu
+    from tpudfs.tpu import crc32c_pallas, rs_pallas
+
+    jax.clear_caches()
+    for mod in (tpudfs.tpu, crc32c_pallas, rs_pallas):
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)
+    yield
+    jax.clear_caches()
+
+
+def _words(chunks: int, sharding):
+    return jax.ShapeDtypeStruct((chunks, 128), jnp.uint32, sharding=sharding)
+
+
+def _ring(topo, n: int):
+    mesh = Mesh(np.array(topo.devices[:n]), ("hosts",))
+    return mesh, NamedSharding(mesh, P("hosts"))
+
+
+# Each case: (topo, one_chip) -> (jitted, args, expect_kernel, needles).
+
+
+def _crc_pallas_case(chunks: int):
+    def build(topo, one_chip):
+        from tpudfs.tpu.crc32c_pallas import _crc_pallas
+
+        wcontrib = jax.ShapeDtypeStruct((32, 128), jnp.uint32,
+                                        sharding=one_chip)
+        return _crc_pallas, (_words(chunks, one_chip), wcontrib), True, ()
+
+    return build
+
+
+def _block_crc(topo, one_chip):
+    from tpudfs.tpu.crc32c_pallas import block_crc_device
+
+    return block_crc_device, (_words(CHUNKS_1MIB, one_chip),), True, ()
+
+
+def _batch_block_crc(topo, one_chip):
+    from tpudfs.tpu.crc32c_pallas import batch_block_crc_device
+
+    return (jax.jit(lambda w: batch_block_crc_device(w, 32)),
+            (_words(32 * CHUNKS_1MIB, one_chip),), True, ())
+
+
+def _verify_block(topo, one_chip):
+    from tpudfs.tpu.crc32c_pallas import verify_block_device
+
+    expected = jax.ShapeDtypeStruct((CHUNKS_1MIB,), jnp.uint32,
+                                    sharding=one_chip)
+    return (jax.jit(verify_block_device),
+            (_words(CHUNKS_1MIB, one_chip), expected), True, ())
+
+
+def _rs_encode(topo, one_chip):
+    from tpudfs.tpu.rs_pallas import rs_encode_device
+
+    shards = jax.ShapeDtypeStruct((6, RS_SHARD), jnp.uint8,
+                                  sharding=one_chip)
+    return (jax.jit(lambda d: rs_encode_device(d, 6, 3)), (shards,),
+            True, ())
+
+
+def _rs_decode(topo, one_chip):
+    from tpudfs.tpu.rs_pallas import rs_decode_device
+
+    shards = jax.ShapeDtypeStruct((6, RS_SHARD), jnp.uint8,
+                                  sharding=one_chip)
+    present = (0, 1, 2, 3, 5, 7, 8)  # data 4 and parity 6 missing
+    return (jax.jit(lambda a: rs_decode_device(a, 6, 3, present)),
+            (shards,), True, ())
+
+
+def _gf_matmul_runtime(topo, one_chip):
+    from tpudfs.tpu.rs_pallas import gf_matmul_runtime
+
+    mat = jax.ShapeDtypeStruct((2, 4), jnp.uint8, sharding=one_chip)
+    words = jax.ShapeDtypeStruct((4, 4 * MIB // 4), jnp.uint32,
+                                 sharding=one_chip)
+    # A runtime matrix means no baked constants: plain XLA by design.
+    return jax.jit(gf_matmul_runtime), (mat, words), False, ()
+
+
+def _write_step_case(n: int, needles: tuple):
+    def build(topo, one_chip):
+        from tpudfs.tpu.ici_replication import replicated_write_step
+
+        mesh, sharding = _ring(topo, n)
+        chunks = n * 8 * CHUNKS_1MIB  # 8 MiB per ring position
+        crcs = jax.ShapeDtypeStruct((chunks,), jnp.uint32,
+                                    sharding=sharding)
+        step = replicated_write_step(mesh, replication=3)
+        return jax.jit(step), (_words(chunks, sharding), crcs), True, needles
+
+    return build
+
+
+def _ec_scatter(topo, one_chip):
+    from tpudfs.tpu.ici_replication import EcShardScatter
+
+    mesh, sharding = _ring(topo, 4)
+    scatter = EcShardScatter(mesh, 2, 2)
+    return (scatter._fn, (_words(4 * 8 * CHUNKS_1MIB, sharding),), True,
+            ("collective-permute", "all-reduce"))
+
+
+def _ec_gather(topo, one_chip):
+    from tpudfs.tpu.ici_replication import EcShardGather
+
+    mesh, sharding = _ring(topo, 4)
+    gather = EcShardGather(mesh, 2, 2)
+    shards = jax.ShapeDtypeStruct((4 * 4, 4 * CHUNKS_1MIB, 128), jnp.uint32,
+                                  sharding=sharding)
+    mats = jax.ShapeDtypeStruct((4, 2, 4), jnp.uint8, sharding=sharding)
+    return gather._fn, (shards, mats), False, ("collective-permute",)
+
+
+CASES = {
+    "crc_pallas_2048_chunks": _crc_pallas_case(2048),
+    "crc_pallas_65536_chunks": _crc_pallas_case(65536),
+    "block_crc_device_1MiB": _block_crc,
+    "batch_block_crc_device_32x1MiB": _batch_block_crc,
+    "verify_block_device_1MiB": _verify_block,
+    "rs_encode_device_6_3_64MiB_block": _rs_encode,
+    "rs_decode_device_6_3_64MiB_block": _rs_decode,
+    "gf_matmul_runtime": _gf_matmul_runtime,
+    "replicated_write_step_1_device": _write_step_case(
+        1, ("collective-permute",)),
+    "replicated_write_step_4_devices": _write_step_case(
+        4, ("collective-permute", "all-reduce")),
+    "ec_shard_scatter_2_2_4_devices": _ec_scatter,
+    "ec_shard_gather_2_2_4_devices": _ec_gather,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_compiles_for_v5e(case, topo, one_chip, chip):
+    jitted, args, expect_kernel, needles = CASES[case](topo, one_chip)
+    compiled = jitted.lower(*args).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) is expect_kernel, (
+        f"{case}: expected a Mosaic kernel: {expect_kernel}")
+    for needle in needles:
+        assert needle in text, f"{case}: no {needle} in the compiled program"
+    # The RS programs' uint8 pack is ~100x padded in XLA temp (8.5 GiB for
+    # a 64 MiB block). It runs on the chip, so PR 22 left it; this only
+    # holds each program inside one chip's HBM.
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 0
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < HBM_BYTES, f"{case}: does not fit HBM"

@@ -97,6 +97,9 @@ async def test_watermark_max_merge_under_reordered_acks():
             w.writelines(_pack_frame(dict(ack), None))
         await w.drain()
         served.set()
+        # Python 3.12's Server.wait_closed() waits for every connection's
+        # transport, so the handler has to close its side.
+        w.close()
 
     server = await asyncio.start_server(serve, "127.0.0.1", 0)
     port = server.sockets[0].getsockname()[1]

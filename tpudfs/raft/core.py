@@ -536,6 +536,13 @@ class RaftCore:
         if self.role != Role.LEADER or self._transfer_target:
             raise NotLeaderError(self._transfer_target or self.leader_id)
         effects = self._append_local_batch(commands)
+        # An append round is a probe round: it postpones the tick's
+        # heartbeat, which is otherwise the only place a round opens. A
+        # leader proposing faster than the heartbeat interval would keep
+        # sending the last heartbeat's stale seq, stop renewing quorum
+        # contact and its lease, and be deposed by check-quorum
+        # 2 * election_max into any sustained write burst.
+        self._new_probe_round(now)
         effects += self._broadcast_append()
         self._heartbeat_due = now + self.timings.heartbeat
         first = self.last_index - len(commands) + 1

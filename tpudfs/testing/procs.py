@@ -19,10 +19,44 @@ import time
 REPO = pathlib.Path(__file__).resolve().parent.parent.parent
 
 
-def free_port() -> int:
+#: Servers put their ops HTTP listener at rpc port + this by default
+#: (``--http-port -1``, common/ops_http.py maybe_start_ops).
+OPS_PORT_OFFSET = 1000
+
+
+def ops_twin_free(port: int) -> bool:
+    """Whether ``port``'s ops-HTTP twin is a valid port nobody holds."""
+    twin = port + OPS_PORT_OFFSET
+    if twin > 65535:
+        return False
     with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
+        try:
+            s.bind(("127.0.0.1", twin))
+        except OSError:
+            return False
+    return True
+
+
+def free_port() -> int:
+    """A free port for a server's rpc listener whose ops twin can be
+    bound too. Where the kernel hands out ephemeral ports up to 65535
+    (``ip_local_port_range``), a bare bind-to-0 pick lands above 64535
+    about once in thirty, and that server dies at start-up in
+    ``bind(): port must be 0-65535``. Rejected picks stay bound until
+    the search ends so the kernel cannot hand them out again."""
+    rejected: list[socket.socket] = []
+    try:
+        while True:
+            s = socket.socket()
+            s.bind(("127.0.0.1", 0))
+            port = s.getsockname()[1]
+            if ops_twin_free(port):
+                s.close()
+                return port
+            rejected.append(s)
+    finally:
+        for s in rejected:
+            s.close()
 
 
 # Bound at import: preexec_fn runs between fork and exec, where imports or

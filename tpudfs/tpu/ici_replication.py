@@ -35,24 +35,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.6: experimental namespace, check_rep spelling
-    from functools import wraps
-
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    @wraps(_shard_map_legacy)
-    def shard_map(f, *, check_vma=True, **kw):
-        return _shard_map_legacy(f, check_rep=check_vma, **kw)
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpudfs.tpu.crc32c_pallas import WORDS_PER_CHUNK, crc32c_chunks_device
 
 
 def make_mesh(devices=None, axis: str = "hosts") -> Mesh:
-    import numpy as np
-
     devices = devices if devices is not None else jax.devices()
     return Mesh(np.array(devices), (axis,))
 
