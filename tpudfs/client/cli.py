@@ -22,6 +22,7 @@ from tpudfs.client.checker import check_linearizability, load_history
 from tpudfs.client.client import Client, DfsError
 from tpudfs.client.workload import WorkloadConfig, dump_history, run_workload
 from tpudfs.common.rpc import add_tls_args, tls_from_args
+from tpudfs.common import telemetry
 from tpudfs.common.telemetry import setup_logging
 
 
@@ -35,6 +36,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--etag-mode", choices=["md5", "crc64"], default="md5",
                    help="put-path ETag: md5 (S3 conformance) or hardware "
                         "CRC-64/NVME (~50x cheaper, '-crc64' suffix)")
+    p.add_argument("--trace-out", default="", metavar="FILE",
+                   help="record this command's stage spans and write them "
+                        "as Chrome trace-event JSON (load in Perfetto)")
     sub = p.add_subparsers(dest="cmd", required=True)
 
     sp = sub.add_parser("put", help="upload a local file")
@@ -318,7 +322,19 @@ def main(argv=None) -> None:
     args = build_parser().parse_args(argv)
     if args.cmd == "presign":
         sys.exit(cmd_presign(args))
-    sys.exit(asyncio.run(amain(args)))
+    if not args.trace_out:
+        sys.exit(asyncio.run(amain(args)))
+    telemetry.enable()
+    try:
+        code = asyncio.run(amain(args))
+    finally:
+        telemetry.disable()
+        with open(args.trace_out, "w") as f:
+            json.dump(telemetry.chrome_trace(telemetry.drain()), f)
+        if telemetry.dropped():
+            print(f"trace: {telemetry.dropped()} spans dropped (buffer "
+                  f"holds {telemetry.BUFFER_CAP})", file=sys.stderr)
+    sys.exit(code)
 
 
 if __name__ == "__main__":

@@ -32,7 +32,7 @@ import os
 import uuid
 from pathlib import Path
 
-from tpudfs.common import writestream
+from tpudfs.common import telemetry, writestream
 from tpudfs.common.blocknet import BlockConnPool
 from tpudfs.common.checksum import crc32c
 from tpudfs.common.erasure import decode as ec_decode
@@ -855,28 +855,30 @@ class Client:
         read-heavy infeed the metadata plane otherwise pays a full RPC
         (~0.7 ms of the single bench core) per file. Disable with
         ``meta_coalescing=False`` for strict per-call RPCs."""
-        if not self.meta_coalescing:
-            return await self._get_file_info_single(path)
-        fut: asyncio.Future = asyncio.get_running_loop().create_future()
-        fut.add_done_callback(
-            lambda f: None if f.cancelled() else f.exception()
-        )
-        self._meta_pending.append((path, fut))
-        if self._meta_drainer is None or self._meta_drainer.done():
-            self._meta_drainer = asyncio.create_task(self._drain_meta())
-        # The drainer is shared and deadline-shielded; each WAITER applies
-        # its own budget here so a budgeted op stays bounded even when its
-        # batch is stuck behind a slow shard.
-        rem = remaining_budget()
-        if rem is None:
-            return await asyncio.shield(fut)
-        try:
-            return await asyncio.wait_for(asyncio.shield(fut), max(rem, 0.01))
-        except asyncio.TimeoutError:
-            raise IndeterminateError(
-                f"get_file_info({path}): deadline budget exhausted waiting "
-                "on metadata batch"
-            ) from None
+        with telemetry.span("client.get_file_info"):
+            if not self.meta_coalescing:
+                return await self._get_file_info_single(path)
+            fut: asyncio.Future = asyncio.get_running_loop().create_future()
+            fut.add_done_callback(
+                lambda f: None if f.cancelled() else f.exception()
+            )
+            self._meta_pending.append((path, fut))
+            if self._meta_drainer is None or self._meta_drainer.done():
+                self._meta_drainer = asyncio.create_task(self._drain_meta())
+            # The drainer is shared and deadline-shielded; each WAITER
+            # applies its own budget here so a budgeted op stays bounded
+            # even when its batch is stuck behind a slow shard.
+            rem = remaining_budget()
+            if rem is None:
+                return await asyncio.shield(fut)
+            try:
+                return await asyncio.wait_for(asyncio.shield(fut),
+                                              max(rem, 0.01))
+            except asyncio.TimeoutError:
+                raise IndeterminateError(
+                    f"get_file_info({path}): deadline budget exhausted "
+                    "waiting on metadata batch"
+                ) from None
 
     async def _get_file_info_single(self, path: str) -> dict | None:
         resp, _ = await self._execute("GetFileInfo", {"path": path}, path=path)

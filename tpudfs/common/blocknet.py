@@ -43,6 +43,7 @@ import struct
 import grpc
 import msgpack
 
+from tpudfs.common import telemetry
 from tpudfs.common.resilience import (
     TENANT_FRAME_KEY,
     OVERLOADED_PREFIX,
@@ -137,6 +138,11 @@ async def _read_frame(r: asyncio.StreamReader, into=None
     of materializing one multi-MiB bytes via readexactly (which also
     forces the caller into slice copies); returns (header, None). A None
     result from the callback falls back to the bytes path."""
+    header, plen = await _read_header(r)
+    return header, await _read_payload(r, header, plen, into)
+
+
+async def _read_header(r: asyncio.StreamReader) -> tuple[dict, int]:
     hlen = _U32.unpack(await r.readexactly(4))[0]
     if hlen > _MAX_HEADER:
         raise ConnectionError(f"blockport header too large: {hlen}")
@@ -145,13 +151,17 @@ async def _read_frame(r: asyncio.StreamReader, into=None
     plen = _U64.unpack(await r.readexactly(8))[0]
     if plen > _MAX_PAYLOAD:
         raise ConnectionError(f"blockport payload too large: {plen}")
+    return header, plen
+
+
+async def _read_payload(r: asyncio.StreamReader, header: dict, plen: int,
+                        into=None) -> bytes | None:
     if plen and into is not None:
         segments = into(header, plen)
         if segments is not None:
             await _read_into(r, segments, plen)
-            return header, None
-    payload = await r.readexactly(plen) if plen else b""
-    return header, payload
+            return None
+    return await r.readexactly(plen) if plen else b""
 
 
 async def _read_into(r: asyncio.StreamReader, segments, plen: int) -> None:
@@ -684,7 +694,16 @@ class BlockConnPool:
                 header[TENANT_FRAME_KEY] = tenant
             w.writelines(_pack_frame(header, req.get("data")))
             await w.drain()
-            resp, payload = await _read_frame(r, into=payload_into)
+            # Client side only: the peer sends nothing before it has the
+            # whole answer, so the wait for the header is its share of the
+            # call and the payload is the wire's and this loop's.
+            with telemetry.span("blockport.wait_header", method=method,
+                                addr=hostport) as waited:
+                resp, plen = await _read_header(r)
+                waited.set(bytes=plen)
+            with telemetry.span("blockport.recv_payload", method=method,
+                                addr=hostport, bytes=plen):
+                payload = await _read_payload(r, resp, plen, payload_into)
         except BaseException:
             w.close()
             raise

@@ -147,6 +147,7 @@ def crc32c_chunks_jax(data: bytes, **kw) -> np.ndarray:
 
 
 @jax.jit
+@jax.named_scope("tpudfs.crc_verify")
 def block_crc_device(words: jax.Array) -> jax.Array:
     """Whole-(padded-)block CRC32C, entirely on device — uint32 scalar.
 
@@ -172,6 +173,7 @@ def block_crc_device(words: jax.Array) -> jax.Array:
 
 
 @partial(jax.jit, static_argnames=("nblocks",))
+@jax.named_scope("tpudfs.crc_verify")
 def batch_block_crc_device(words: jax.Array, nblocks: int) -> jax.Array:
     """Whole-block CRC32C of ``nblocks`` equal-chunk-count blocks laid out
     contiguously in ONE (nblocks*cpb, 128) device array -> (nblocks,) uint32.

@@ -23,6 +23,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpudfs.client.client import ChecksumMismatchError, Client, DfsError
+from tpudfs.common import telemetry
 from tpudfs.common.checksum import CHECKSUM_CHUNK_SIZE, crc32c_combine
 from tpudfs.tpu.crc32c_pallas import (
     WORDS_PER_CHUNK,
@@ -375,7 +376,8 @@ class HbmReader:
                 return np.concatenate([np.asarray(p) for p in parts]) \
                     if len(parts) > 1 else np.asarray(parts[0])
 
-            got = await asyncio.to_thread(fetch)
+            with telemetry.span("hbm.confirm", blocks=len(blocks)):
+                got = await asyncio.to_thread(fetch)
         else:
             # Every batch here was resolved by an earlier confirm call
             # (blocks of one fused round confirmed file-by-file) — nothing
@@ -414,7 +416,11 @@ class HbmReader:
             b for b in bad
             if retry and b.source is not None and b.device is not None
         ]
-        rereads = await asyncio.gather(*(_reread(b) for b in retryable))
+        rereads = []
+        if retryable:
+            with telemetry.span("hbm.confirm_reread", blocks=len(retryable)):
+                rereads = await asyncio.gather(
+                    *(_reread(b) for b in retryable))
         fixed = {id(b): nb for b, nb in zip(retryable, rereads)}
         unrecovered = []
         for b in bad:
@@ -553,6 +559,16 @@ class HbmReader:
         round is released — ring depth keeps the producer ahead anyway.
         The CPU backend's copies are synchronous-by-probe (see
         read_combiner's aliasing notes; buffers come misaligned)."""
+        with telemetry.span("hbm.sweep") as whole:
+            out = await self._sweep_metas(metas, device, round_blocks, ring)
+            whole.set(blocks=len(out))
+            return out
+
+    async def _sweep_metas(self, metas: list[dict], device,
+                           round_blocks: int, ring: int) -> list[DeviceBlock]:
+        """The sweep itself, inside its caller's ``hbm.sweep`` span (one
+        per public call, the metadata fan-out inside it when the caller
+        came by paths)."""
         import ctypes
 
         from tpudfs.common import native
@@ -571,6 +587,7 @@ class HbmReader:
         expected_sizes: list[int] = []
         expected_crcs: list[int] = []
         stores: dict[str, object] = {}  # addr -> store|None, sweep-local
+        resolving = telemetry.span("sweep.resolve")
         for meta in metas:
             for block in meta["blocks"]:
                 size = int(block.get("size") or 0)
@@ -604,6 +621,7 @@ class HbmReader:
                 paths.append(bpath.encode())
                 expected_sizes.append(size)
                 expected_crcs.append(int(block["checksum_crc32c"]))
+        resolving.end(blocks=len(entries))
 
         fallback_idx = [i for i, (slot, _b) in enumerate(entries)
                         if slot is None]
@@ -657,21 +675,27 @@ class HbmReader:
                         # not at dispatch (measured: mutating the source
                         # right after device_put corrupts ~15% of 4 MiB
                         # transfers without this wait).
-                        prev = outstanding[r - ring]
-                        if prev is not None:
-                            await asyncio.to_thread(
-                                jax.block_until_ready, prev)
-                        lib.tpudfs_sweep_release(handle, r - ring)
-                    nblk = await asyncio.to_thread(
-                        lib.tpudfs_sweep_wait, handle, r)
+                        with telemetry.span("sweep.gate", round=r):
+                            prev = outstanding[r - ring]
+                            if prev is not None:
+                                await asyncio.to_thread(
+                                    jax.block_until_ready, prev)
+                            lib.tpudfs_sweep_release(handle, r - ring)
+                    with telemetry.span("sweep.pump_wait",
+                                        round=r) as pumped:
+                        nblk = await asyncio.to_thread(
+                            lib.tpudfs_sweep_wait, handle, r)
+                        pumped.set(blocks=nblk)
                     if nblk < 0:
                         break
                     lo = r * round_blocks
                     hi = lo + nblk
                     ok = (sizes[lo:hi] == exp_sizes[lo:hi]) \
                         & (crcs[lo:hi] == exp_crcs[lo:hi])
-                    words = jax.device_put(
-                        buf_words[r % ring][: nblk * spb], device)
+                    with telemetry.span("sweep.device_put", round=r,
+                                        blocks=nblk, bytes=nblk * stride):
+                        words = jax.device_put(
+                            buf_words[r % ring][: nblk * spb], device)
                     outstanding[r] = words
                     batch = DeviceBatch(words=words, crcs=None,
                                         cpb=spb, nblocks=nblk)
@@ -693,10 +717,11 @@ class HbmReader:
             finally:
                 # Completion before stop: a dispatched transfer may still
                 # be reading a ring buffer (any backend).
-                pend = [w for w in outstanding if w is not None]
-                if pend:
-                    await asyncio.to_thread(jax.block_until_ready, pend)
-                lib.tpudfs_sweep_stop(handle)
+                with telemetry.span("sweep.drain", round=nrounds):
+                    pend = [w for w in outstanding if w is not None]
+                    if pend:
+                        await asyncio.to_thread(jax.block_until_ready, pend)
+                    lib.tpudfs_sweep_stop(handle)
 
         if fallback_idx:
             async def fb(eidx: int):
@@ -704,7 +729,8 @@ class HbmReader:
                 results[eidx] = await self.read_block_to_device(
                     block, device, verify=True)
 
-            await asyncio.gather(*(fb(i) for i in fallback_idx))
+            with telemetry.span("sweep.fallback", blocks=len(fallback_idx)):
+                await asyncio.gather(*(fb(i) for i in fallback_idx))
         return results
 
     def _cpu_copies(self, device) -> bool:
@@ -726,13 +752,16 @@ class HbmReader:
         """sweep_metas_to_device with the metadata fan-out in front (the
         'cold' flagship pattern: nothing cached, metadata fetched
         in-sweep, then the native pump drives the data plane)."""
-        metas = await asyncio.gather(
-            *(self.client.get_file_info(p) for p in paths))
-        missing = [p for p, m in zip(paths, metas) if m is None]
-        if missing:
-            raise DfsError(f"file not found: {missing[0]}")
-        return await self.sweep_metas_to_device(
-            metas, device, round_blocks=round_blocks, ring=ring)
+        with telemetry.span("hbm.sweep") as whole:
+            with telemetry.span("sweep.metadata", files=len(paths)):
+                metas = await asyncio.gather(
+                    *(self.client.get_file_info(p) for p in paths))
+            missing = [p for p, m in zip(paths, metas) if m is None]
+            if missing:
+                raise DfsError(f"file not found: {missing[0]}")
+            out = await self._sweep_metas(metas, device, round_blocks, ring)
+            whole.set(blocks=len(out))
+            return out
 
     # ------------------------------------------------------------- per file
 
@@ -745,21 +774,23 @@ class HbmReader:
         concat). ``round_robin``: block i → device i % n (spreads a stream of
         blocks). ``contiguous``: block i → device i // ceil(blocks/n) (keeps
         file order within each device — required for read_file_sharded)."""
-        meta = await self.client.get_file_info(path)
-        if meta is None:
-            raise DfsError(f"file not found: {path}")
-        blocks = meta["blocks"]
-        n = len(self.devices)
-        if placement == "contiguous":
-            per = -(-len(blocks) // n) if blocks else 1
-            device_of = lambda i: self.devices[i // per]  # noqa: E731
-        else:
-            device_of = lambda i: self.devices[i % n]  # noqa: E731
-        coros = [
-            self.read_block_to_device(block, device_of(i), verify=verify)
-            for i, block in enumerate(blocks)
-        ]
-        return list(await asyncio.gather(*coros))
+        with telemetry.span("hbm.read_file") as whole:
+            meta = await self.client.get_file_info(path)
+            if meta is None:
+                raise DfsError(f"file not found: {path}")
+            blocks = meta["blocks"]
+            whole.set(blocks=len(blocks))
+            n = len(self.devices)
+            if placement == "contiguous":
+                per = -(-len(blocks) // n) if blocks else 1
+                device_of = lambda i: self.devices[i // per]  # noqa: E731
+            else:
+                device_of = lambda i: self.devices[i % n]  # noqa: E731
+            coros = [
+                self.read_block_to_device(block, device_of(i), verify=verify)
+                for i, block in enumerate(blocks)
+            ]
+            return list(await asyncio.gather(*coros))
 
     async def read_file_sharded(self, path: str, mesh: Mesh | None = None,
                                 verify: bool | str = True) -> jax.Array:

@@ -88,6 +88,9 @@ class IciReplicator:
         mesh = self.mesh
         n = mesh.shape[axis]
 
+        # The trace's readers find the program as ``jit_step`` (the inner
+        # function's name) and its insides by this scope.
+        @jax.named_scope("tpudfs.ici_replicate")
         def step(local_words: jnp.ndarray, local_crcs: jnp.ndarray):
             # local_words: (C, 128) uint32 — this host's pending chunk batch.
             # local_crcs:  (C,) uint32 — expected per-chunk CRCs.
@@ -188,6 +191,7 @@ class EcShardScatter:
         mesh = self.mesh
         n = mesh.shape[axis]
 
+        @jax.named_scope("tpudfs.ec_scatter")
         def step(local_words: jnp.ndarray):
             # local_words: (C, 128) uint32 — this host's block batch.
             C = local_words.shape[0]
@@ -311,6 +315,7 @@ class EcShardGather:
         mesh = self.mesh
         n = mesh.shape[axis]
 
+        @jax.named_scope("tpudfs.ec_gather")
         def step(local_shards, mats):
             # local_shards: (k+m, S, 128) — row j = shard j of host
             # (d - j) mod n. Send row j back to its owner: src -> src - j.

@@ -47,7 +47,7 @@ from dataclasses import dataclass, field
 import jax
 import numpy as np
 
-from tpudfs.common import native
+from tpudfs.common import native, telemetry
 from tpudfs.common.checksum import CHECKSUM_CHUNK_SIZE
 from tpudfs.tpu.crc32c_pallas import WORDS_PER_CHUNK, batch_block_crc_device
 
@@ -87,6 +87,9 @@ class _Req:
     size: int
     addr: str | None = None  # remote origin chunkserver (None = local)
     fut: asyncio.Future = field(default=None)  # created on the running loop
+    #: ``combiner.queued``: opened by the reader that staged the block (its
+    #: request), ended by the read stage when a round takes it.
+    queued: object = None
 
 
 _FALLBACK = object()  # resolve-to-slow-path sentinel
@@ -159,6 +162,8 @@ class ReadCombiner:
         #: rounds fused / blocks served (observability + tests).
         self.rounds = 0
         self.blocks = 0
+        #: rounds the read stage took; the ``round`` of every stage span.
+        self._round_seq = 0
 
     def _alloc_round_buf(self, nrows: int) -> np.ndarray:
         """One round's pread target. On the CPU backend the data pointer
@@ -253,6 +258,7 @@ class ReadCombiner:
         req.fut.add_done_callback(
             lambda f: None if f.cancelled() else f.exception()
         )
+        req.queued = telemetry.span("combiner.queued")
         self._pending.append(req)
         self._ensure_running()
         result = await asyncio.shield(req.fut)
@@ -294,14 +300,27 @@ class ReadCombiner:
                 self._pending = [
                     r for r in self._pending if id(r) not in taken
                 ]
+                self._round_seq += 1
+                rnd = self._round_seq
+                for r in reqs:
+                    r.queued.end(round=rnd)
                 buf = self._get_buf(len(reqs) * cpb)
                 try:
-                    if origin is not None:
-                        ok, crcs = await self._fetch_remote(reqs, buf)
-                    else:
-                        ok, crcs = await asyncio.to_thread(
-                            self._fill_buffer, reqs, buf
-                        )
+                    # The stage tasks serve every reader (request=None):
+                    # their context is that of whichever reader started
+                    # them and says nothing about this round.
+                    with telemetry.span(
+                            "combiner.fetch", request=None, round=rnd,
+                            blocks=len(reqs),
+                            bytes=len(reqs) * cpb * CHECKSUM_CHUNK_SIZE,
+                            origin=origin or "local") as fetched:
+                        if origin is not None:
+                            ok, crcs = await self._fetch_remote(reqs, buf)
+                        else:
+                            ok, crcs = await asyncio.to_thread(
+                                self._fill_buffer, reqs, buf
+                            )
+                        fetched.set(fell_back=len(ok) - sum(ok))
                 except asyncio.CancelledError:
                     self._put_buf(buf)
                     self._fail_out(reqs)
@@ -360,13 +379,15 @@ class ReadCombiner:
                     while off < len(good):
                         take = 1 << ((len(good) - off).bit_length() - 1)
                         last = off + take >= len(good)
-                        await queue.put((
-                            good[off : off + take],
-                            rows[off * cpb : (off + take) * cpb],
-                            cpb, crcs is not None,
-                            rows if (pooled and last) else None,
-                            pooled,
-                        ))
+                        with telemetry.span("combiner.handoff", request=None,
+                                            round=rnd, blocks=take):
+                            await queue.put((
+                                good[off : off + take],
+                                rows[off * cpb : (off + take) * cpb],
+                                cpb, crcs is not None,
+                                rows if (pooled and last) else None,
+                                pooled, rnd,
+                            ))
                         off += take
                 else:
                     self._put_buf(buf)
@@ -574,16 +595,22 @@ class ReadCombiner:
         since_release: list = []
         skip_next_release = False  # a sub-round of this buffer failed
         while True:
-            item = await queue.get()
+            with telemetry.span("combiner.upload_wait", request=None):
+                item = await queue.get()
             if item is None:
                 return
-            reqs, rows, cpb, host_verified, release, pooled = item
+            reqs, rows, cpb, host_verified, release, pooled, rnd = item
+            stage = {"request": None, "round": rnd, "blocks": len(reqs),
+                     "bytes": rows.nbytes}
             try:
-                words = await asyncio.to_thread(
-                    jax.device_put, rows, self.device
-                )
-                crcs = None if host_verified else \
-                    batch_block_crc_device(words, len(reqs))
+                with telemetry.span("combiner.device_put", **stage):
+                    words = await asyncio.to_thread(
+                        jax.device_put, rows, self.device
+                    )
+                crcs = None
+                if not host_verified:
+                    with telemetry.span("combiner.crc_dispatch", **stage):
+                        crcs = batch_block_crc_device(words, len(reqs))
                 if release is not None and not skip_next_release:
                     # The pooled buffer may only be reused once every
                     # transfer out of it COMPLETED — on every backend
@@ -592,9 +619,10 @@ class ReadCombiner:
                     # try: a device error here must take the same
                     # fall-back path as a failed device_put, not kill
                     # the consumer task.
-                    await asyncio.to_thread(
-                        jax.block_until_ready, since_release + [words]
-                    )
+                    with telemetry.span("combiner.release_wait", **stage):
+                        await asyncio.to_thread(
+                            jax.block_until_ready, since_release + [words]
+                        )
             except asyncio.CancelledError:
                 self._fail_out(reqs)
                 raise

@@ -141,6 +141,7 @@ def _unpack_words(words: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(words, jnp.uint8).reshape(m, W * 4)
 
 
+@jax.named_scope("tpudfs.rs_encode")
 def rs_encode_device(data_shards: jax.Array, k: int, m: int, *,
                      use_pallas: bool | None = None) -> jax.Array:
     """Parity shards for on-device data ((k, L) uint8 -> (m, L) uint8).
@@ -230,6 +231,7 @@ def decode_matrix(k: int, m: int, present: tuple) -> np.ndarray:
     return _matrix_invert(encode_matrix(k, m)[rows])
 
 
+@jax.named_scope("tpudfs.rs_decode")
 def rs_decode_device(avail: jax.Array, k: int, m: int, present: tuple, *,
                      use_pallas: bool | None = None) -> jax.Array:
     """Reconstruct the k data shards ON DEVICE from any k survivors.
