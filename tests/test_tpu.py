@@ -943,6 +943,7 @@ async def test_fused_read_roundtrip(tmp_path, host_verify):
         blocks = await reader.read_file_to_device_blocks("/fu/a",
                                                          verify="lazy")
         assert comb.blocks >= 1, "combiner never engaged"
+        assert comb.overlapped == 0, "the local disk is one source"
         await reader.confirm(blocks)
         assert all(b.verified for b in blocks)
         got = b"".join(device_array_to_bytes(b.array, b.size)
@@ -1003,6 +1004,72 @@ async def test_fused_read_held_blocks_survive_buffer_recycle(tmp_path):
         got = b"".join(device_array_to_bytes(b.array, b.size)
                        for b in held)
         assert got == d1, "recycled host buffer leaked into held blocks"
+    finally:
+        await c.stop()
+
+
+@pytest.mark.parametrize("host_verify", [True, False])
+async def test_fused_read_buffer_recycle_rounds_out_of_order(tmp_path,
+                                                             host_verify):
+    """The held-blocks check above with rounds that END out of order: the
+    first round's frame stays open while four later rounds (another
+    origin) recycle the pool, then it is handed off last. No buffer is
+    handed out again while a round still owns it, each returns to the pool
+    only with every transfer out of it complete, and every block held
+    across the recycling reads back exactly."""
+    data = _rand(16 * 64 * 1024, seed=59)
+    c, client = await _cluster_with_files(tmp_path, [("/fu/ooo", data)])
+    try:
+        reader, comb, addrs = _remote_reader(client, host_verify, origins=2)
+        await client.get_file_info("/fu/ooo")
+        gate = _FetchGate(comb, held={addrs[0]})
+        out: dict = {}  # data pointer of a buffer a round owns -> its reqs
+        get_buf, put_buf = comb._get_buf, comb._put_buf
+        upload_round = comb._upload_round
+        recycled = []
+
+        def tracked_get(nrows):
+            buf = get_buf(nrows)
+            assert buf.ctypes.data not in out, "buffer handed out twice"
+            out[buf.ctypes.data] = None
+            return buf
+
+        async def tracked_upload(reqs, rows, *rest):
+            out[rows.ctypes.data] = reqs
+            await upload_round(reqs, rows, *rest)
+
+        def tracked_put(buf):
+            if buf is not None:
+                words = [r.fut.result().batch.words
+                         for r in out.pop(buf.ctypes.data)]
+                assert len(words) == 2 and all(w.is_ready() for w in words), \
+                    "buffer pooled before its transfer completed"
+                recycled.append(buf.ctypes.data)
+            put_buf(buf)
+
+        async def settled(addr):
+            # A round of the other origin ends only once the rounds before
+            # it are uploaded and their buffers pooled, so the round after
+            # it finds a pooled buffer whatever the host's timing.
+            if addr != addrs[0] and not gate.release.is_set():
+                before = gate.issued.count(addr) - 1
+                await _until(lambda: comb.rounds >= before,
+                             "the earlier rounds are uploaded")
+
+        gate.before = settled
+
+        comb._get_buf, comb._put_buf = tracked_get, tracked_put
+        comb._upload_round = tracked_upload
+        read = asyncio.create_task(reader.read_file_to_device_blocks(
+            "/fu/ooo", verify="lazy"))
+        await _until(lambda: comb.blocks == 8, "the other origin is done")
+        assert gate.active[addrs[0]] == 1
+        assert len(recycled) == 4 and len(set(recycled)) == 2, \
+            "the other origin's rounds did not recycle the pool"
+        gate.release.set()
+        held = await read
+        assert await _confirmed_bytes(reader, held) == data
+        assert comb.blocks == 16 and not out
     finally:
         await c.stop()
 
@@ -1182,6 +1249,222 @@ async def test_fused_read_remote_corrupt_slot_falls_back(tmp_path):
         got = b"".join(device_array_to_bytes(b.array, b.size)
                        for b in blocks)
         assert got == data
+    finally:
+        await c.stop()
+
+
+# ------------------------------- one round in flight per origin (PR 26)
+
+
+def _remote_reader(client, host_verify, origins):
+    """A NON-colocated reader whose metadata names block i's replicas
+    rotated by ``i % origins``, so its rounds (2 blocks each) spread over
+    that many origin chunkservers: every chunkserver of the 3x cluster
+    holds every block. Returns (reader, combiner, the origins in order)."""
+    client.local_reads = False
+    real = client.get_file_info
+    addrs: list = []
+
+    async def spread(path):
+        meta = await real(path)
+        blocks = []
+        for i, b in enumerate(meta["blocks"]):
+            locs = sorted(b["locations"])
+            assert len(locs) == 3
+            addrs[:] = locs[:origins]
+            k = i % origins
+            blocks.append(dict(b, locations=locs[k:] + locs[:k]))
+        return dict(meta, blocks=blocks)
+
+    client.get_file_info = spread
+    reader = HbmReader(client, jax.devices()[:1], batch_reads=2)
+    comb = reader._combiner(reader.devices[0])
+    comb.host_verify = host_verify
+    return reader, comb, addrs
+
+
+class _FetchGate:
+    """Stands in front of ``ReadCombiner._fetch_remote``: counts the rounds
+    in flight per origin, and holds the rounds to the ``held`` origins open
+    until ``release`` is set."""
+
+    def __init__(self, comb, held=()):
+        self.real = comb._fetch_remote
+        self.held = held
+        self.release = asyncio.Event()
+        self.active: dict = {}
+        self.most: dict = {}
+        self.issued: list = []
+        self.before = None  # async hook(addr), run before a round's fetch
+        comb._fetch_remote = self
+
+    async def __call__(self, reqs, buf):
+        addr = reqs[0].addr
+        self.issued.append(addr)
+        self.active[addr] = self.active.get(addr, 0) + 1
+        self.most[addr] = max(self.most.get(addr, 0), self.active[addr])
+        try:
+            if self.before is not None:
+                await self.before(addr)
+            if addr in self.held:
+                await self.release.wait()
+            return await self.real(reqs, buf)
+        finally:
+            self.active[addr] -= 1
+
+
+async def _until(cond, what):
+    for _ in range(1000):
+        if cond():
+            return
+        await asyncio.sleep(0.01)
+    raise AssertionError(f"timed out waiting until {what}")
+
+
+async def _confirmed_bytes(reader, blocks):
+    await reader.confirm(blocks)
+    assert all(b.verified for b in blocks)
+    return b"".join(device_array_to_bytes(b.array, b.size) for b in blocks)
+
+
+@pytest.mark.parametrize("host_verify", [True, False])
+async def test_fused_read_rounds_overlap_across_origins(tmp_path,
+                                                        host_verify):
+    """With blocks on two origins and one origin's frame held open, the
+    other origin's rounds are issued and served meanwhile; no origin ever
+    has two rounds in flight."""
+    from tpudfs.common import telemetry
+
+    data = _rand(8 * 64 * 1024, seed=62)
+    c, client = await _cluster_with_files(tmp_path, [("/rf/two", data)])
+    try:
+        reader, comb, addrs = _remote_reader(client, host_verify, origins=2)
+        await client.get_file_info("/rf/two")
+        slow, fast = addrs
+        gate = _FetchGate(comb, held={slow})
+        telemetry.enable()
+        try:
+            read = asyncio.create_task(reader.read_file_to_device_blocks(
+                "/rf/two", verify="lazy"))
+            # Both of the fast origin's rounds are served while the slow
+            # origin's first frame is still open, and its second round
+            # waits for the first.
+            await _until(lambda: comb.blocks == 4, "the fast origin is done")
+            assert gate.active[slow] == 1 and gate.issued.count(slow) == 1
+            assert comb.overlapped == 2
+            gate.release.set()
+            blocks = await read
+        finally:
+            telemetry.disable()
+            records = telemetry.drain()
+        assert await _confirmed_bytes(reader, blocks) == data
+        assert comb.blocks == 8 and comb.overlapped == 2
+        assert gate.most == {slow: 1, fast: 1}
+        depth = {r.attrs["round"]: (r.attrs["origin"], r.attrs["in_flight"])
+                 for r in records if r.name == "combiner.fetch"}
+        assert depth == {1: (slow, 1), 2: (fast, 2), 3: (fast, 2),
+                         4: (slow, 1)}
+    finally:
+        await c.stop()
+
+
+@pytest.mark.parametrize("host_verify", [True, False])
+async def test_fused_read_one_origin_goes_a_round_at_a_time(tmp_path,
+                                                            host_verify):
+    """Traffic with one source behaves as before the read stage kept
+    several rounds in flight: a round at a time, nothing overlapped."""
+    data = _rand(8 * 64 * 1024, seed=63)
+    c, client = await _cluster_with_files(tmp_path, [("/rf/one", data)])
+    try:
+        reader, comb, addrs = _remote_reader(client, host_verify, origins=1)
+        gate = _FetchGate(comb)
+        blocks = await reader.read_file_to_device_blocks("/rf/one",
+                                                         verify="lazy")
+        assert await _confirmed_bytes(reader, blocks) == data
+        assert comb.rounds == 4 and comb.blocks == 8
+        assert comb.overlapped == 0
+        assert gate.most == {addrs[0]: 1}
+    finally:
+        await c.stop()
+
+
+@pytest.mark.parametrize("host_verify", [True, False])
+@pytest.mark.parametrize("failure", ["rpc_error", "blowup"])
+async def test_fused_read_failed_frame_frees_its_origin(tmp_path, failure,
+                                                        host_verify):
+    """A frame that fails on one origin (an RpcError the fetch absorbs, or
+    anything else the round does) falls its blocks back to the per-block
+    path while the other origin's rounds complete, and frees its origin
+    for the next round."""
+    import grpc
+
+    from tpudfs.common.rpc import RpcError
+
+    data = _rand(8 * 64 * 1024, seed=64)
+    c, client = await _cluster_with_files(tmp_path, [("/rf/bad", data)])
+    try:
+        reader, comb, addrs = _remote_reader(client, host_verify, origins=2)
+        await client.get_file_info("/rf/bad")
+        bad, good = addrs
+        real = client._data_call
+        frames = []
+
+        async def data_call(addr, method, req, **kw):
+            if addr == bad and method == "ReadBlocks":
+                frames.append(len(req["block_ids"]))
+                # Fail only once the good origin has been served: the bad
+                # one must not have held it back.
+                await _until(lambda: comb.blocks == 4,
+                             "the good origin is done")
+                if failure == "rpc_error":
+                    raise RpcError(grpc.StatusCode.UNAVAILABLE, "injected")
+                raise OSError("injected")
+            return await real(addr, method, req, **kw)
+
+        client._data_call = data_call
+        blocks = await reader.read_file_to_device_blocks("/rf/bad",
+                                                         verify="lazy")
+        assert await _confirmed_bytes(reader, blocks) == data
+        assert frames == [2, 2], "the bad origin was not freed"
+        assert comb.blocks == 4
+        assert [b.batch is not None for b in blocks] == [False, True] * 4
+    finally:
+        await c.stop()
+
+
+@pytest.mark.parametrize("host_verify", [True, False])
+async def test_fused_read_cancel_with_rounds_in_flight(tmp_path,
+                                                       host_verify):
+    """Cancelling the read stage with a round open to each of two origins
+    fails out every request (in flight and still pending), returns the
+    rounds' buffers, ends the upload stage, and leaves a stage the next
+    read restarts."""
+    data = _rand(8 * 64 * 1024, seed=65)
+    c, client = await _cluster_with_files(tmp_path, [("/rf/stop", data)])
+    try:
+        reader, comb, addrs = _remote_reader(client, host_verify, origins=2)
+        meta = await client.get_file_info("/rf/stop")
+        gate = _FetchGate(comb, held=set(addrs))
+        reads = [asyncio.create_task(reader.read_block_to_device(
+            b, reader.devices[0], verify="lazy")) for b in meta["blocks"]]
+        await _until(lambda: sum(gate.active.values()) == 2,
+                     "a round is open to each origin")
+        assert len(comb._pending) == 4
+        upload = comb._upload_task
+        comb._read_task.cancel()
+        done, pending = await asyncio.wait(reads, timeout=10)
+        assert not pending, "a request was left waiting"
+        assert all(isinstance(t.exception(), RuntimeError) for t in done)
+        await asyncio.wait_for(upload, 10)
+        assert comb._read_task is None and not comb._pending
+        assert sum(gate.active.values()) == 0
+        assert sum(len(v) for v in comb._buf_pool.values()) == 2
+        assert comb.blocks == 0
+        gate.release.set()
+        blocks = await reader.read_file_to_device_blocks("/rf/stop",
+                                                         verify="lazy")
+        assert await _confirmed_bytes(reader, blocks) == data
+        assert comb.blocks == 8
     finally:
         await c.stop()
 

@@ -16,9 +16,13 @@ round into
 3. ONE batched CRC dispatch (``batch_block_crc_device``) whose (n,) result
    is compared host-side in the caller's existing one-sync ``confirm``.
 
-The two stages are separate tasks connected by a small queue, so round
-``i+1``'s disk reads overlap round ``i``'s host→HBM transfer (both release
-the GIL). Rounds form naturally: whatever accumulated while the previous
+The two stages are separate tasks connected by a small queue, so one
+round's reads overlap another's host→HBM transfer (both release the GIL).
+The read stage keeps one round in flight per SOURCE (an origin chunkserver,
+or the local disk), ``MAX_ROUNDS_IN_FLIGHT`` over all, each a task that
+hands its round to the upload stage when its fetch ends: one origin's pread
+overlaps another origin's payload, and traffic with one source goes a round
+at a time. Rounds form naturally: whatever accumulated while a source's
 round was in flight ships next — no artificial batching delay.
 
 Round sizes are bucketed to powers of two (≤ ``max_batch``) so the batched
@@ -55,6 +59,11 @@ logger = logging.getLogger(__name__)
 
 #: Largest fused round, in blocks. 32 x 1 MiB = 32 MiB per device_put.
 DEFAULT_MAX_BATCH = 32
+#: Rounds the read stage keeps in flight over all sources, one per source
+#: (an origin chunkserver, or the local disk): one origin's pread hides
+#: behind another's payload. Bounds host memory to this + 3 round buffers
+#: (two queued for the upload stage, one in it).
+MAX_ROUNDS_IN_FLIGHT = 3
 #: Byte budget for one REMOTE round — comfortably under both transports'
 #: 100 MiB frame/message caps (blocknet._MAX_PAYLOAD, rpc MAX_MESSAGE_BYTES)
 #: including framing; oversized blocks simply round down to 1 per frame.
@@ -162,6 +171,8 @@ class ReadCombiner:
         #: rounds fused / blocks served (observability + tests).
         self.rounds = 0
         self.blocks = 0
+        #: rounds issued while another source's round was in flight.
+        self.overlapped = 0
         #: rounds the read stage took; the ``round`` of every stage span.
         self._round_seq = 0
 
@@ -195,7 +206,9 @@ class ReadCombiner:
                          "falling back to per-read allocs", exc_info=True)
             return False
 
-    _POOL_PER_SHAPE = 3
+    #: Every buffer that can be out at once: no round allocates afresh in
+    #: steady state.
+    _POOL_PER_SHAPE = MAX_ROUNDS_IN_FLIGHT + 3
 
     def _get_buf(self, nrows: int) -> np.ndarray:
         free = self._buf_pool.get(nrows)
@@ -274,19 +287,36 @@ class ReadCombiner:
                 self._upload_stage(self._queue)
             )
 
-    # ------------------------------------------------------- stage 1: disk
+    # ------------------------------------------------------ stage 1: fetch
 
     async def _read_stage(self) -> None:
         queue = self._queue
+        #: source (origin chunkserver; None = the local disk) -> the task
+        #: of its round: one round per source, MAX_ROUNDS_IN_FLIGHT in all.
+        in_flight: dict[str | None, asyncio.Task] = {}
         aborted = True
         try:
-            while self._pending:
-                # One round: the leading request's (chunk count, origin)
-                # picks the group — uniform geometry, one source (local
-                # disk, or one remote peer's ReadBlocks frame). Mixed
-                # requests only split rounds, they are never dropped.
-                cpb = self._pending[0].cpb
-                origin = self._pending[0].addr
+            while self._pending or in_flight:
+                lead = None
+                if len(in_flight) < MAX_ROUNDS_IN_FLIGHT:
+                    lead = next((r for r in self._pending
+                                 if r.addr not in in_flight), None)
+                if lead is None:
+                    # Every source with pending requests is busy, or the
+                    # cap is reached: whatever accumulates meanwhile ships
+                    # when a round ends.
+                    done, _ = await asyncio.wait(
+                        in_flight.values(),
+                        return_when=asyncio.FIRST_COMPLETED)
+                    in_flight = {s: t for s, t in in_flight.items()
+                                 if t not in done}
+                    continue
+                # One round: the first request whose source is free picks
+                # the group by its (chunk count, origin) — uniform
+                # geometry, one source (local disk, or one remote peer's
+                # ReadBlocks frame). Mixed requests only split rounds,
+                # they are never dropped.
+                cpb, origin = lead.cpb, lead.addr
                 uniform = [r for r in self._pending
                            if r.cpb == cpb and r.addr == origin]
                 cap = self.max_batch
@@ -294,117 +324,117 @@ class ReadCombiner:
                     # One frame must fit the transports' 100 MiB caps.
                     stride = cpb * CHECKSUM_CHUNK_SIZE
                     cap = min(cap, max(1, REMOTE_ROUND_BYTES // stride))
-                take = _bucket(len(uniform), cap)
-                reqs = uniform[:take]
+                reqs = uniform[:_bucket(len(uniform), cap)]
                 taken = set(map(id, reqs))
                 self._pending = [
                     r for r in self._pending if id(r) not in taken
                 ]
                 self._round_seq += 1
-                rnd = self._round_seq
                 for r in reqs:
-                    r.queued.end(round=rnd)
-                buf = self._get_buf(len(reqs) * cpb)
-                try:
-                    # The stage tasks serve every reader (request=None):
-                    # their context is that of whichever reader started
-                    # them and says nothing about this round.
-                    with telemetry.span(
-                            "combiner.fetch", request=None, round=rnd,
-                            blocks=len(reqs),
-                            bytes=len(reqs) * cpb * CHECKSUM_CHUNK_SIZE,
-                            origin=origin or "local") as fetched:
-                        if origin is not None:
-                            ok, crcs = await self._fetch_remote(reqs, buf)
-                        else:
-                            ok, crcs = await asyncio.to_thread(
-                                self._fill_buffer, reqs, buf
-                            )
-                        fetched.set(fell_back=len(ok) - sum(ok))
-                except asyncio.CancelledError:
-                    self._put_buf(buf)
-                    self._fail_out(reqs)
-                    raise
-                except Exception as e:
-                    # One bad round (allocation failure, I/O blowup) must
-                    # not kill the stage: route its blocks to the general
-                    # per-block path and keep draining.
-                    logger.warning("fused read round failed (%s); "
-                                   "falling back %d blocks", e, len(reqs))
-                    self._put_buf(buf)
-                    for r in reqs:
-                        if not r.fut.done():
-                            r.fut.set_result(_FALLBACK)
-                    continue
-                if crcs is not None:
-                    # Host-verified round: a CRC mismatch here is a corrupt
-                    # LOCAL replica — route it to the general path, whose
-                    # verified retry excludes this replica, reads a healthy
-                    # one, and triggers chunkserver self-repair.
-                    for i, r in enumerate(reqs):
-                        if ok[i] and int(crcs[i]) != int(
-                                r.block["checksum_crc32c"]):
-                            logger.warning(
-                                "fused read: CRC mismatch on local replica "
-                                "of %s; falling back", r.block["block_id"])
-                            ok[i] = False
-                good = [r for r, o in zip(reqs, ok) if o]
-                for r, o in zip(reqs, ok):
-                    if not o and not r.fut.done():
-                        r.fut.set_result(_FALLBACK)
-                if good:
-                    # Compact rows when some slots fell back, preserving
-                    # request order (row i belongs to good[i]). The pooled
-                    # buffer returns immediately (its data now lives in
-                    # the compacted copy, which is NOT pooled — its shape
-                    # is a non-bucket size _get_buf would never hand out).
-                    pooled = len(good) == len(reqs)
-                    if not pooled:
-                        rows = np.concatenate([
-                            buf[i * cpb : (i + 1) * cpb]
-                            for i, o in enumerate(ok) if o
-                        ])
-                        self._put_buf(buf)
-                    else:
-                        rows = buf
-                    # Ship in power-of-two sub-rounds: a compacted count
-                    # (15 after one dropped slot) would otherwise dispatch
-                    # a CRC shape warm() never compiled — a fresh XLA
-                    # compile mid-infeed on TPU. Full buckets pass through
-                    # in one iteration. For pooled rounds the LAST
-                    # sub-round carries `rows` as its release token: the
-                    # upload stage returns it to the pool once every
-                    # sub-round's transfer completed.
-                    off = 0
-                    while off < len(good):
-                        take = 1 << ((len(good) - off).bit_length() - 1)
-                        last = off + take >= len(good)
-                        with telemetry.span("combiner.handoff", request=None,
-                                            round=rnd, blocks=take):
-                            await queue.put((
-                                good[off : off + take],
-                                rows[off * cpb : (off + take) * cpb],
-                                cpb, crcs is not None,
-                                rows if (pooled and last) else None,
-                                pooled, rnd,
-                            ))
-                        off += take
-                else:
-                    self._put_buf(buf)
+                    r.queued.end(round=self._round_seq)
+                if in_flight:
+                    self.overlapped += 1
+                in_flight[origin] = asyncio.create_task(self._round(
+                    queue, reqs, self._round_seq, len(in_flight) + 1))
             aborted = False
         finally:
-            # Synchronously (no await since the empty-pending check) clear
-            # the task slot BEFORE the suspending sentinel put: a request
-            # staged while we drain out must see done-and-restartable state
-            # from _ensure_running, not a live task that will never serve it.
-            # On abnormal exit (cancellation) the still-pending requests are
-            # ours (no new generation can have started while the task slot
-            # was occupied) and would otherwise await forever.
+            # Synchronously (no await since the nothing-pending, nothing-in-
+            # flight check) clear the task slot BEFORE the first suspension
+            # below: a request staged while we drain out must see
+            # done-and-restartable state from _ensure_running, not a live
+            # task that will never serve it. On abnormal exit (cancellation)
+            # the still-pending requests are ours (no new generation can
+            # have started while the task slot was occupied) and would
+            # otherwise await forever; each in-flight round fails out its
+            # own. The sentinel goes last: every round has handed off.
             self._read_task = None
             if aborted:
-                self._fail_out(self._pending)
-                self._pending = []
+                if self._pending:
+                    self._fail_out(self._pending)
+                    self._pending = []
+                for task in in_flight.values():
+                    task.cancel()
+                await asyncio.gather(*in_flight.values(),
+                                     return_exceptions=True)
             await queue.put(None)
+
+    async def _round(self, queue: asyncio.Queue, reqs: list[_Req], rnd: int,
+                     in_flight: int) -> None:
+        """One round, fetch to hand-off, as a task of its own: it owns its
+        requests and its buffer until the upload stage has them, and its
+        source stays busy until it ends. Only cancellation leaves it as an
+        exception."""
+        cpb, origin = reqs[0].cpb, reqs[0].addr
+        buf = self._get_buf(len(reqs) * cpb)
+        try:
+            # The stage tasks serve every reader (request=None): their
+            # context is that of whichever reader started them and says
+            # nothing about this round.
+            with telemetry.span(
+                    "combiner.fetch", request=None, round=rnd,
+                    blocks=len(reqs),
+                    bytes=len(reqs) * cpb * CHECKSUM_CHUNK_SIZE,
+                    origin=origin or "local",
+                    in_flight=in_flight) as fetched:
+                if origin is not None:
+                    ok, crcs = await self._fetch_remote(reqs, buf)
+                else:
+                    ok, crcs = await asyncio.to_thread(
+                        self._fill_buffer, reqs, buf
+                    )
+                fetched.set(fell_back=len(ok) - sum(ok))
+            if crcs is not None:
+                # Host-verified round: a CRC mismatch here is a corrupt
+                # LOCAL replica — route it to the general path, whose
+                # verified retry excludes this replica, reads a healthy
+                # one, and triggers chunkserver self-repair.
+                for i, r in enumerate(reqs):
+                    if ok[i] and int(crcs[i]) != int(
+                            r.block["checksum_crc32c"]):
+                        logger.warning(
+                            "fused read: CRC mismatch on local replica "
+                            "of %s; falling back", r.block["block_id"])
+                        ok[i] = False
+            good = [r for r, o in zip(reqs, ok) if o]
+            self._fall_back([r for r, o in zip(reqs, ok) if not o])
+            if not good:
+                return
+            # Compact rows when some slots fell back, preserving request
+            # order (row i belongs to good[i]). The compacted copy is NOT
+            # pooled: its shape is a non-bucket size _get_buf would never
+            # hand out.
+            rows = buf
+            if len(good) < len(reqs):
+                rows = np.concatenate([
+                    buf[i * cpb : (i + 1) * cpb]
+                    for i, o in enumerate(ok) if o
+                ])
+            # One item a round, so the sub-rounds of one buffer reach the
+            # upload stage together whatever order rounds end in.
+            with telemetry.span("combiner.handoff", request=None,
+                                round=rnd, blocks=len(good)):
+                await queue.put((good, rows, cpb, crcs is not None,
+                                 rows is buf, rnd))
+            if rows is buf:
+                buf = None  # the upload stage's to release
+        except asyncio.CancelledError:
+            self._fail_out(reqs)
+            raise
+        except Exception as e:
+            # One bad round (allocation failure, I/O blowup) must not
+            # stall its source or the stage: route its blocks to the
+            # general per-block path.
+            logger.warning("fused read round failed (%s); "
+                           "falling back %d blocks", e, len(reqs))
+            self._fall_back(reqs)
+        finally:
+            self._put_buf(buf)
+
+    def _fall_back(self, reqs: list[_Req]) -> None:
+        """Route ``reqs`` to the caller's general per-block path."""
+        for r in reqs:
+            if not r.fut.done():
+                r.fut.set_result(_FALLBACK)
 
     def _fail_out(self, reqs: list[_Req]) -> None:
         for r in reqs:
@@ -579,80 +609,79 @@ class ReadCombiner:
     # ----------------------------------------------------- stage 2: device
 
     async def _upload_stage(self, queue: asyncio.Queue) -> None:
-        from tpudfs.tpu.hbm_reader import DeviceBlock
-
-        # No skip-wait fast path on ANY backend: the CPU client copies by
-        # COMPLETION, not at dispatch (measured: mutating the source right
-        # after device_put corrupts ~15% of 4 MiB transfers), so a pooled
-        # buffer may only return once its transfers are block_until_ready.
-        # (_cpu_copies still gates POOLING itself — an ALIASING backend is
-        # unsafe no matter how long we wait.)
-        #: words of sub-rounds sharing the current (unreleased) buffer —
-        #: the buffer may only return to the pool once every transfer out
-        #: of it COMPLETED (every backend may still be reading the host
-        #: buffer until the device array is ready — the CPU client copies
-        #: by completion, not at dispatch).
-        since_release: list = []
-        skip_next_release = False  # a sub-round of this buffer failed
         while True:
             with telemetry.span("combiner.upload_wait", request=None):
                 item = await queue.get()
             if item is None:
                 return
-            reqs, rows, cpb, host_verified, release, pooled, rnd = item
-            stage = {"request": None, "round": rnd, "blocks": len(reqs),
-                     "bytes": rows.nbytes}
+            await self._upload_round(*item)
+
+    async def _upload_round(self, reqs: list[_Req], rows: np.ndarray,
+                            cpb: int, host_verified: bool, pooled: bool,
+                            rnd: int) -> None:
+        """Ship one round in power-of-two sub-rounds: a compacted count (15
+        after one dropped slot) would otherwise dispatch a CRC shape warm()
+        never compiled — a fresh XLA compile mid-infeed on TPU. A full
+        bucket is one sub-round.
+
+        A pooled ``rows`` returns to the pool only once every transfer out
+        of it COMPLETED, on every backend: the CPU client copies by
+        completion, not at dispatch (measured: mutating the source right
+        after device_put corrupts ~15% of 4 MiB transfers), and an
+        accelerator may still be reading the host buffer until the device
+        array is ready. (_cpu_copies still gates POOLING itself — an
+        ALIASING backend is unsafe no matter how long we wait.)"""
+        from tpudfs.tpu.hbm_reader import DeviceBlock
+
+        #: words of the sub-rounds already shipped out of ``rows``
+        shipped: list = []
+        off = 0
+        while off < len(reqs):
+            take = 1 << ((len(reqs) - off).bit_length() - 1)
+            sub = reqs[off : off + take]
+            sub_rows = rows[off * cpb : (off + take) * cpb]
+            off += take
+            stage = {"request": None, "round": rnd, "blocks": take,
+                     "bytes": sub_rows.nbytes}
             try:
                 with telemetry.span("combiner.device_put", **stage):
                     words = await asyncio.to_thread(
-                        jax.device_put, rows, self.device
+                        jax.device_put, sub_rows, self.device
                     )
                 crcs = None
                 if not host_verified:
                     with telemetry.span("combiner.crc_dispatch", **stage):
-                        crcs = batch_block_crc_device(words, len(reqs))
-                if release is not None and not skip_next_release:
-                    # The pooled buffer may only be reused once every
-                    # transfer out of it COMPLETED — on every backend
-                    # (see the completion-not-dispatch note above).
-                    # Completion wait only — no readback. Inside the
-                    # try: a device error here must take the same
-                    # fall-back path as a failed device_put, not kill
-                    # the consumer task.
+                        crcs = batch_block_crc_device(words, take)
+                if pooled and off == len(reqs):
+                    # Completion wait only — no readback. Inside the try:
+                    # a device error here must take the same fall-back
+                    # path as a failed device_put, not kill the consumer
+                    # task.
                     with telemetry.span("combiner.release_wait", **stage):
                         await asyncio.to_thread(
-                            jax.block_until_ready, since_release + [words]
+                            jax.block_until_ready, shipped + [words]
                         )
             except asyncio.CancelledError:
                 self._fail_out(reqs)
                 raise
             except Exception as e:
                 # A failed upload must not kill the consumer — with it gone
-                # the producer would block forever on the full queue and
-                # every later read would hang. Fall this round back to the
-                # per-block path (where a genuinely broken device surfaces
-                # its own error) and keep consuming.
+                # the producers would block forever on the full queue and
+                # every later read would hang. Fall this sub-round back to
+                # the per-block path (where a genuinely broken device
+                # surfaces its own error) and keep consuming. The buffer's
+                # state is unknown now: dropped, not pooled.
                 logger.warning("fused upload failed (%s); falling back "
-                               "%d blocks", e, len(reqs))
-                since_release = []  # buffer state unknown: drop, don't pool
-                skip_next_release = pooled and release is None
-                for r in reqs:
-                    if not r.fut.done():
-                        r.fut.set_result(_FALLBACK)
+                               "%d blocks", e, take)
+                pooled = False
+                self._fall_back(sub)
                 continue
-            if release is not None:
-                if skip_next_release:
-                    skip_next_release = False  # buffer dropped, not pooled
-                else:
-                    self._put_buf(release)
-                since_release = []
-            elif pooled:
-                since_release.append(words)
+            shipped.append(words)
             batch = DeviceBatch(words=words, crcs=crcs, cpb=cpb,
-                                nblocks=len(reqs))
+                                nblocks=take)
             self.rounds += 1
-            self.blocks += len(reqs)
-            for i, r in enumerate(reqs):
+            self.blocks += take
+            for i, r in enumerate(sub):
                 db = DeviceBlock(
                     r.block["block_id"], None, r.size, host_verified,
                     expected_crc=int(r.block["checksum_crc32c"]),
@@ -662,6 +691,8 @@ class ReadCombiner:
                 )
                 if not r.fut.done():
                     r.fut.set_result(db)
+        if pooled:
+            self._put_buf(rows)
 
     # -------------------------------------------------------------- warmup
 
