@@ -155,6 +155,24 @@ def test_breaker_board_counters_and_healthy_first():
     assert board.healthy_first(["a", "b", "c"]) == ["a", "b", "c"]
 
 
+def test_breaker_board_is_open_asks_without_probing():
+    """``is_open`` says whether the open window runs and consumes no
+    probe: the EC read leaves such a holder alone, and the first ``allow``
+    after the window is still the one probe."""
+    clk = FakeClock()
+    board = BreakerBoard(failure_threshold=1, reset_timeout=5.0, clock=clk)
+    assert not board.is_open("a")  # never seen: no breaker is made
+    assert "a" not in board._breakers
+    board.record_failure("a")
+    assert board.is_open("a") and board.is_open("a")
+    assert board.counters()["breaker_short_circuits_total"] == 0
+    clk.advance(5.1)
+    assert not board.is_open("a")  # window over: the next caller probes
+    assert board.allow("a") and not board.allow("a")
+    board.record_success("a")
+    assert not board.is_open("a") and board.allow("a")
+
+
 # ------------------------------------------------------------------ deadline
 
 

@@ -666,6 +666,169 @@ async def test_hbm_reader_ec_degraded_detects_corrupt_shard(tmp_path):
         await c.stop()
 
 
+# ------------------------------ the degraded read's one decode program
+
+
+def _loss_patterns(k, m):
+    """Every loss of two slots, and a sample of losses of three where the
+    code can bear them."""
+    import itertools
+
+    n = k + m
+    lost = list(itertools.combinations(range(n), 2))
+    if m >= 3:
+        lost += [(0, 1, 2), (0, k - 1, k), (1, k, n - 1), (k, k + 1, n - 1),
+                 (2, 3, n - 1)]
+    return lost
+
+
+@pytest.mark.parametrize("k,m,size", [
+    (6, 3, 65536),   # shards of 10 923 bytes: every shard but one is shifted
+    (6, 3, 3840),    # shards of 640 bytes: a multiple of 128, word-aligned
+    (6, 3, 5000),    # not a chunk multiple: the grid's tail is zero padding
+    (4, 2, 50_000),
+    (3, 2, 7777),
+])
+def test_rs_decode_block_one_program_for_every_loss(k, m, size):
+    """The degraded read's decode program against the plain reference AND
+    the host codec, bit for bit, for every failure pattern, with ONE
+    compile per (k, shard length, block size): the inverse is an operand."""
+    from benchmarks import reference_rs
+    from tpudfs.common.erasure import reconstruct
+    from tpudfs.tpu.rs_pallas import (
+        decode_matrix,
+        rs_decode_block,
+        survivors_to_words,
+    )
+
+    data = _rand(size, seed=size)
+    shards = encode(data, k, m)
+    slen = len(shards[0])
+    want = bytes_to_words(data)
+    compiled = rs_decode_block._cache_size()
+    seen = set()
+    for lost in _loss_patterns(k, m):
+        have = [None if i in lost else s for i, s in enumerate(shards)]
+        use = tuple(i for i in range(k + m) if i not in lost)[:k]
+        seen.add(use)
+        assert reference_rs.decode(have, k, m, size) == data
+        assert b"".join(reconstruct(have, k, m)[:k])[:size] == data
+        got = np.asarray(rs_decode_block(
+            jnp.asarray(survivors_to_words([shards[i] for i in use], slen)),
+            jnp.asarray(decode_matrix(k, m, use)),
+            slen=slen, size=size))
+        np.testing.assert_array_equal(got, want, err_msg=f"lost {lost}")
+    assert len(seen) > 3
+    assert rs_decode_block._cache_size() == compiled + 1
+
+
+async def _nine_with_rs63_file(tmp_path, path, data):
+    c = MiniCluster(tmp_path, n_masters=1, n_cs=9)
+    await c.start()
+    leader = await c.leader()
+    await c.wait_out_of_safe_mode(leader)
+    client = Client(list(c.masters), rpc_client=c.client,
+                    block_size=64 * 1024, local_reads=False)
+    await client.create_file(path, data, ec=(6, 3))
+    return c, client
+
+
+async def _stop_holders(c, addrs):
+    for cs, hb in zip(list(c.chunkservers), c.heartbeats):
+        if cs.address in addrs:
+            hb.stop()
+            await cs.stop()
+
+
+async def test_hbm_reader_rs63_two_of_nine_down(tmp_path):
+    """A multi-block RS(6,3) file on nine servers, two holders of data
+    shards stopped: every block is reconstructed on the device by the one
+    warmed program and verified there; counters and spans say what ran."""
+    from tpudfs.common import telemetry
+    from tpudfs.tpu.rs_pallas import rs_decode_block
+
+    data = _rand(5 * 64 * 1024, seed=21)  # five blocks
+    c, client = await _nine_with_rs63_file(tmp_path, "/ec/nine", data)
+    try:
+        meta = await client.get_file_info("/ec/nine")
+        assert [len(b["locations"]) for b in meta["blocks"]] == [9] * 5
+        await _stop_holders(c, meta["blocks"][0]["locations"][:2])
+        reader = HbmReader(client, jax.devices()[:1])
+        reader.warm_ec(6, 3, 64 * 1024)
+        compiled = rs_decode_block._cache_size()
+        telemetry.enable()
+        try:
+            blocks = await reader.read_file_to_device_blocks(
+                "/ec/nine", verify="lazy")
+            await reader.confirm(blocks)
+        finally:
+            records = telemetry.drain()
+            telemetry.disable()
+        assert rs_decode_block._cache_size() == compiled
+        assert len(blocks) == 5 and all(b.verified for b in blocks)
+        assert b"".join(device_array_to_bytes(b.array, b.size)
+                        for b in blocks) == data
+        assert reader.ec_blocks == 5
+        # The stopped servers may hold parity slots of later blocks.
+        assert 1 <= reader.ec_degraded_blocks <= 5
+        assert reader.ec_degraded_blocks <= reader.ec_missing_data_shards \
+            <= 2 * reader.ec_degraded_blocks
+        slen = -(-64 * 1024 // 6)
+        assert reader.ec_shard_bytes == 5 * 7 * slen
+        whole = [r for r in records if r.name == "hbm.read_file"]
+        assert len(whole) == 1
+        by_name = {}
+        for r in records:
+            if r.name.startswith("ec."):
+                assert r.parent_id == whole[0].span_id, r
+                by_name.setdefault(r.name, []).append(r)
+        assert set(by_name) == {"ec.queued", "ec.fetch_shards", "ec.assemble",
+                                "ec.device_put", "ec.decode_dispatch"}
+        assert len(by_name["ec.fetch_shards"]) == len(by_name["ec.queued"]) \
+            == 5
+        assert len(by_name["ec.decode_dispatch"]) == reader.ec_degraded_blocks
+        assert len(by_name["ec.assemble"]) == len(by_name["ec.device_put"]) == 5
+        assert sum(r.attrs["degraded"] for r in by_name["ec.assemble"]) \
+            == reader.ec_degraded_blocks
+        assert all(r.attrs["present"] == 7 for r in by_name["ec.fetch_shards"])
+        assert sum(r.attrs["missing_data"]
+                   for r in by_name["ec.fetch_shards"]) \
+            == reader.ec_missing_data_shards
+        # Once the breakers know the two are down they are left alone.
+        assert sum(client.block_pool.breakers.is_open(a)
+                   for a in meta["blocks"][0]["locations"]) == 2
+    finally:
+        await c.stop()
+
+
+async def test_hbm_reader_rs63_degraded_detects_corrupt_survivor(tmp_path):
+    """Nine servers, two down, and a surviving shard the reconstruction
+    uses rotted in place (sidecar consistent): the CRC32C of the
+    RECONSTRUCTED bytes fails on the device, at confirm."""
+    data = _rand(2 * 64 * 1024, seed=22)
+    c, client = await _nine_with_rs63_file(tmp_path, "/ec/rot", data)
+    try:
+        meta = await client.get_file_info("/ec/rot")
+        block = meta["blocks"][0]
+        bid = block["block_id"]
+        await _stop_holders(c, block["locations"][:2])
+        for cs in c.chunkservers:
+            if cs.address == block["locations"][2]:
+                raw = bytearray(cs.store.read(bid))
+                raw[10] ^= 0xFF
+                cs.store.write(bid, bytes(raw))
+                cs.invalidate_cached(bid)
+        reader = HbmReader(client, jax.devices()[:1])
+        blocks = await reader.read_file_to_device_blocks(
+            "/ec/rot", verify="lazy")
+        assert not blocks[0].verified and blocks[0].pending_crc is not None
+        with pytest.raises(DfsError, match="checksum mismatch"):
+            await reader.confirm(blocks)
+        assert reader.ec_degraded_blocks >= 1
+    finally:
+        await c.stop()
+
+
 # ---------------------------------------- corrupt-local-replica failover
 
 

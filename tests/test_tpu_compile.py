@@ -140,6 +140,23 @@ def _rs_decode(topo, one_chip):
             (shards,), True, ())
 
 
+def _rs_decode_block_case(block_bytes: int):
+    """The degraded read's one program per (k, shard length, block size),
+    matrix as an operand: RS(6,3) at the benchmark's 1 MiB block and at the
+    client's default 64 MiB block."""
+    def build(topo, one_chip):
+        from tpudfs.tpu.rs_pallas import decode_rows, rs_decode_block
+
+        slen = -(-block_bytes // 6)
+        words = jax.ShapeDtypeStruct((6, decode_rows(slen), 128), jnp.uint32,
+                                     sharding=one_chip)
+        mat = jax.ShapeDtypeStruct((6, 6), jnp.uint8, sharding=one_chip)
+        return (jax.jit(lambda w, a: rs_decode_block(
+            w, a, slen=slen, size=block_bytes)), (words, mat), True, ())
+
+    return build
+
+
 def _gf_matmul_runtime(topo, one_chip):
     from tpudfs.tpu.rs_pallas import gf_matmul_runtime
 
@@ -192,6 +209,8 @@ CASES = {
     "verify_block_device_1MiB": _verify_block,
     "rs_encode_device_6_3_64MiB_block": _rs_encode,
     "rs_decode_device_6_3_64MiB_block": _rs_decode,
+    "rs_decode_block_6_3_1MiB_block": _rs_decode_block_case(MIB),
+    "rs_decode_block_6_3_64MiB_block": _rs_decode_block_case(64 * MIB),
     "gf_matmul_runtime": _gf_matmul_runtime,
     "replicated_write_step_1_device": _write_step_case(
         1, ("collective-permute",)),

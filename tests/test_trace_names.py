@@ -1,7 +1,8 @@
 """The names the trace's readers find the device programs by, pinned where
 the programs are made: each lowers on the CPU under the module name the
-benchmark's readers match (``crc_verify_roofline_pct``, ``ici_round_ms``)
-and carries its ``tpudfs.*`` scope in the lowered text."""
+benchmark's readers match (``crc_verify_roofline_pct``,
+``rs_decode_roofline_pct``, ``ici_round_ms``) and carries its ``tpudfs.*``
+scope in the lowered text."""
 
 from __future__ import annotations
 
@@ -20,7 +21,12 @@ from tpudfs.tpu.ici_replication import (
     IciReplicator,
     make_mesh,
 )
-from tpudfs.tpu.rs_pallas import rs_decode_device, rs_encode_device
+from tpudfs.tpu.rs_pallas import (
+    decode_rows,
+    rs_decode_block,
+    rs_decode_device,
+    rs_encode_device,
+)
 
 CHUNKS = 8
 
@@ -48,6 +54,16 @@ def _rs_decode():
         jax.ShapeDtypeStruct((6, 1024), jnp.uint8))
 
 
+def _rs_decode_block():
+    """The degraded read's program, RS(6,3) over a 64 KiB block."""
+    slen = -(-65536 // 6)
+    return rs_decode_block.lower(
+        jax.ShapeDtypeStruct((6, decode_rows(slen), WORDS_PER_CHUNK),
+                             jnp.uint32),
+        jax.ShapeDtypeStruct((6, 6), jnp.uint8),
+        slen=slen, size=65536)
+
+
 def _ici_replicate():
     mesh = make_mesh(jax.devices()[:4])
     return IciReplicator(mesh, replication=3)._fn.lower(
@@ -72,6 +88,7 @@ def _ec_gather():
     (_crc_block, "jit_block_crc_device", "tpudfs.crc_verify"),
     (_rs_encode, None, "tpudfs.rs_encode"),
     (_rs_decode, None, "tpudfs.rs_decode"),
+    (_rs_decode_block, "jit_rs_decode_block", "tpudfs.rs_decode"),
     (_ici_replicate, "jit_step", "tpudfs.ici_replicate"),
     (_ec_scatter, "jit_step", "tpudfs.ec_scatter"),
     (_ec_gather, "jit_step", "tpudfs.ec_gather"),

@@ -496,6 +496,14 @@ class BreakerBoard:
             self.short_circuits_total += 1
         return ok
 
+    def is_open(self, addr: str) -> bool:
+        """True while ``addr``'s open window runs. Consumes no probe, so a
+        caller may ask it to leave a peer alone that ``allow`` would turn
+        away anyhow; once the window is over the next ``allow`` probes."""
+        br = self._breakers.get(addr)
+        return br is not None and br.state == OPEN \
+            and self._clock() < br._open_until
+
     def record_success(self, addr: str) -> None:
         self.get(addr).record_success()
 

@@ -15,7 +15,9 @@ the "chunk read into TPU HBM" path of BASELINE.json.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import logging
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +35,14 @@ from tpudfs.tpu.crc32c_pallas import (
 )
 
 logger = logging.getLogger(__name__)
+
+#: erasure-coded blocks one reader holds between shard fetch and decode
+#: dispatch (k+m connections and k+m shards on the host each). No more
+#: than the blockport pool keeps idle connections a peer
+#: (``BlockConnPool.MAX_IDLE_PER_PEER``): past that every block reopens
+#: connections (on the v5e's host 4 read 0.263 GB/s, 8 0.284, 16 0.275,
+#: 32 0.19-0.21, 64 0.153: PERF.md, PR 27).
+EC_BLOCKS_IN_FLIGHT = 8
 
 
 class DeviceBlock:
@@ -109,6 +119,16 @@ class HbmReader:
         self._combiners: dict = {}
         #: blocks served by the native sweep pump (observability/bench).
         self.sweep_blocks = 0
+        #: erasure-coded blocks read, those of them that lost a data shard
+        #: and were reconstructed on the device, the data shards they
+        #: lacked, and the bytes of shards fetched for all of them.
+        self.ec_blocks = 0
+        self.ec_degraded_blocks = 0
+        self.ec_missing_data_shards = 0
+        self.ec_shard_bytes = 0
+        self._decode_matrices: dict = {}
+        self._ec_gate: asyncio.Semaphore | None = None
+        self._ec_pool: ThreadPoolExecutor | None = None
 
     def _combiner(self, device):
         c = self._combiners.get(device)
@@ -222,42 +242,66 @@ class HbmReader:
                                   safe_local: bool = False):
         """EC block → device words. All data shards present: host concat +
         one upload (the fast path). Degraded: upload the k surviving shards
-        and reconstruct ON DEVICE with the constant-matrix Pallas GF(2^8)
-        matmul (rs_decode_device) — the repair matmul runs where the data
-        lands instead of on the host CPU."""
-        from tpudfs.tpu.rs_pallas import pad_shard_len, rs_decode_device
+        as the uint32 words they already are and reconstruct ON DEVICE
+        with ``rs_pallas.rs_decode_block``: one compiled program whatever
+        the failure pattern (the inverse matrix is an operand), straight to
+        the chunk grid the CRC fold takes. One worker-thread hop does the
+        stacking, the upload and the dispatch.
 
+        Every shard of a block is a call of its own on a connection of its
+        own, so a file's blocks are not all fetched at once: one 64 MiB
+        RS(6,3) file would hold 576 sockets (16 readers: 9 216) and every
+        shard of every block on the host. ``EC_BLOCKS_IN_FLIGHT`` blocks
+        are between their fetch and their dispatch at a time."""
         k = int(block["ec_data_shards"])
         m = int(block["ec_parity_shards"])
         size = int(block.get("original_size") or block.get("size") or 0)
         device_verify = bool(verify) and bool(block.get("checksum_crc32c"))
-        shards = await self.client._read_ec_shards(
-            block, local_verify=safe_local or not device_verify
-        )
-        if all(s is not None for s in shards[:k]):
-            def _assemble():
+        if self._ec_gate is None:
+            self._ec_gate = asyncio.Semaphore(EC_BLOCKS_IN_FLIGHT)
+        queued = telemetry.span("ec.queued")
+        async with self._ec_gate:
+            queued.end()
+            return await self._ec_block_gated(block, device, k, m, size,
+                                              safe_local or not device_verify)
+
+    async def _ec_block_gated(self, block: dict, device, k: int, m: int,
+                              size: int, local_verify: bool):
+        from tpudfs.tpu import rs_pallas
+
+        with telemetry.span("ec.fetch_shards",
+                            block=block["block_id"]) as fetching:
+            shards = await self.client._read_ec_shards(
+                block, local_verify=local_verify)
+            present = tuple(i for i, s in enumerate(shards) if s is not None)
+            missing_data = k - sum(i < k for i in present)
+            fetching.set(present=len(present), missing_data=missing_data)
+        self.ec_blocks += 1
+        self.ec_shard_bytes += sum(len(shards[i]) for i in present)
+        if not missing_data:
+            def assemble():
                 # Scatter the shards straight into the padded chunk grid
                 # in ONE copy: `b"".join(shards)[:size]` copies the block
                 # once to concatenate and bytes_to_words copies it AGAIN
                 # to pad non-chunk-aligned sizes; the grid is where the
                 # bytes end up either way.
-                need = -(-max(size, 1) // CHECKSUM_CHUNK_SIZE) \
-                    * CHECKSUM_CHUNK_SIZE
-                buf = np.zeros(need, dtype=np.uint8)
-                off = 0
-                for s in shards[:k]:
-                    take = min(len(s), size - off)
-                    if take <= 0:
-                        break
-                    buf[off : off + take] = \
-                        np.frombuffer(s, dtype=np.uint8, count=take)
-                    off += take
-                words = buf.view("<u4").reshape(-1, WORDS_PER_CHUNK)
-                return jax.device_put(words, device)
+                with telemetry.span("ec.assemble", degraded=False):
+                    need = -(-max(size, 1) // CHECKSUM_CHUNK_SIZE) \
+                        * CHECKSUM_CHUNK_SIZE
+                    buf = np.zeros(need, dtype=np.uint8)
+                    off = 0
+                    for s in shards[:k]:
+                        take = min(len(s), size - off)
+                        if take <= 0:
+                            break
+                        buf[off : off + take] = \
+                            np.frombuffer(s, dtype=np.uint8, count=take)
+                        off += take
+                    words = buf.view("<u4").reshape(-1, WORDS_PER_CHUNK)
+                with telemetry.span("ec.device_put", degraded=False):
+                    return jax.device_put(words, device)
 
-            words = await asyncio.to_thread(_assemble)
-            return words, size
-        present = tuple(i for i, s in enumerate(shards) if s is not None)
+            return await self._in_ec_thread(assemble), size
         if len(present) < k:
             raise DfsError(
                 f"EC block {block['block_id']}: only {len(present)} of "
@@ -265,34 +309,67 @@ class HbmReader:
             )
         use = present[:k]
         slen = len(shards[use[0]])  # type: ignore[arg-type]
-        padded = pad_shard_len(slen)
-        stack = np.zeros((k, padded), dtype=np.uint8)
-        for r, idx in enumerate(use):
-            row = np.frombuffer(shards[idx], dtype=np.uint8)  # type: ignore[arg-type]
-            if len(row) != slen:
-                raise ChecksumMismatchError(
-                    f"EC block {block['block_id']}: shard length mismatch"
-                )
-            stack[r, :slen] = row
-        avail = await asyncio.to_thread(
-            lambda: jax.device_put(stack, device)
-        )
+        if any(len(shards[i]) != slen for i in use):  # type: ignore[arg-type]
+            raise ChecksumMismatchError(
+                f"EC block {block['block_id']}: shard length mismatch"
+            )
+        self.ec_degraded_blocks += 1
+        self.ec_missing_data_shards += missing_data
 
         def reconstruct():
-            recon = rs_decode_device(avail, k, m, use)  # (k, padded)
-            nchunks = -(-size // CHECKSUM_CHUNK_SIZE) or 1
-            need = nchunks * CHECKSUM_CHUNK_SIZE
-            flat = recon[:, :slen].reshape(-1)
-            if flat.shape[0] < need:
-                flat = jnp.pad(flat, (0, need - flat.shape[0]))
-            # Shard zero-padding means flat[size:] is zeros, so the slice
-            # to the chunk grid is exact (bytes_to_words pads the same way).
-            return jax.lax.bitcast_convert_type(
-                flat[:need].reshape(nchunks, WORDS_PER_CHUNK, 4), jnp.uint32
-            )
+            with telemetry.span("ec.assemble", degraded=True):
+                stack = rs_pallas.survivors_to_words(
+                    [shards[i] for i in use], slen)
+            with telemetry.span("ec.device_put", degraded=True):
+                survivors = jax.device_put(stack, device)
+                mat = self._decode_matrix_on(device, k, m, use)
+            with telemetry.span("ec.decode_dispatch"):
+                return rs_pallas.rs_decode_block(
+                    survivors, mat, slen=slen, size=size)
 
-        words = await asyncio.to_thread(reconstruct)
-        return words, size
+        return await self._in_ec_thread(reconstruct), size
+
+    async def _in_ec_thread(self, fn):
+        """``fn`` on the reader's ONE upload thread, in the caller's context
+        (its spans stay children of the read). One thread, not
+        ``to_thread``'s pool: a dozen threads taking the GIL from the event
+        loop in turn cost a tenth of the rate (0.257 against 0.284 GB/s)."""
+        if self._ec_pool is None:
+            self._ec_pool = ThreadPoolExecutor(
+                1, thread_name_prefix="tpudfs-ec")
+        return await asyncio.get_running_loop().run_in_executor(
+            self._ec_pool, contextvars.copy_context().run, fn)
+
+    def _decode_matrix_on(self, device, k: int, m: int, use: tuple):
+        """The (k, k) inverse for survivor set ``use`` as a device value,
+        uploaded once per set (84 sets at most for RS(6,3))."""
+        from tpudfs.tpu.rs_pallas import decode_matrix
+
+        key = (device, k, m, use)
+        mat = self._decode_matrices.get(key)
+        if mat is None:
+            mat = self._decode_matrices[key] = jax.device_put(
+                decode_matrix(k, m, use), device)
+        return mat
+
+    def warm_ec(self, k: int, m: int, block_bytes: int) -> None:
+        """Pre-compile the degraded read of RS(k, m) blocks of
+        ``block_bytes`` on every device: the decode program (ONE per shard
+        length; which servers are down does not matter) and the CRC fold
+        of its output, so no XLA compile lands in a timed window."""
+        from tpudfs.common.erasure import shard_len
+        from tpudfs.tpu import rs_pallas
+
+        slen = shard_len(block_bytes, k)
+        zeros = np.zeros((k, rs_pallas.decode_rows(slen), WORDS_PER_CHUNK),
+                         dtype=np.uint32)
+        use = tuple(range(1, k + 1))  # any set: the matrix is an operand
+        for device in self.devices:
+            words = rs_pallas.rs_decode_block(
+                jax.device_put(zeros, device),
+                self._decode_matrix_on(device, k, m, use),
+                slen=slen, size=block_bytes)
+            jax.block_until_ready(block_crc_device(words))
 
     async def _finish_block(self, block: dict, words: jax.Array, size: int,
                             verify: bool | str) -> DeviceBlock:

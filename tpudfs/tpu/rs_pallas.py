@@ -23,7 +23,7 @@ small-table gathers do not. Shards are uint8 with length padded to the
 
 from __future__ import annotations
 
-from functools import lru_cache
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -177,18 +177,20 @@ def gf_matmul_device(mat, shards: jax.Array, *,
     return _unpack_words(out)
 
 
+def _xtime(x: jnp.ndarray) -> jnp.ndarray:
+    """x * 2 over GF(2^8) on uint32-packed bytes, shifts and xors only
+    (0x1D = 1 + 4 + 8 + 16; nothing crosses a byte lane)."""
+    t = (x & jnp.uint32(0x80808080)) >> jnp.uint32(7)
+    red = t ^ (t << jnp.uint32(2)) ^ (t << jnp.uint32(3)) \
+        ^ (t << jnp.uint32(4))
+    return ((x & jnp.uint32(0x7F7F7F7F)) << jnp.uint32(1)) ^ red
+
+
 def _xtimes(words: jnp.ndarray) -> list[jnp.ndarray]:
-    """[words * 2^j for j in 0..7] over GF(2^8), on uint32-packed bytes:
-    xtime(x) = (x << 1) ^ (0x1D if x & 0x80) per byte, with the multiply
-    trick keeping carries inside byte lanes ((hi >> 7) has only byte-LSBs
-    set, and 0x1D fits a byte, so the uint32 product never crosses)."""
+    """[words * 2^j for j in 0..7] over GF(2^8), on uint32-packed bytes."""
     xs = [words]
-    cur = words
     for _ in range(7):
-        hi = cur & jnp.uint32(0x80808080)
-        lo = cur & jnp.uint32(0x7F7F7F7F)
-        cur = (lo << jnp.uint32(1)) ^ ((hi >> jnp.uint32(7)) * jnp.uint32(0x1D))
-        xs.append(cur)
+        xs.append(_xtime(xs[-1]))
     return xs
 
 
@@ -245,6 +247,135 @@ def rs_decode_device(avail: jax.Array, k: int, m: int, present: tuple, *,
     return gf_matmul_device(
         decode_matrix(k, m, tuple(present)), avail, use_pallas=use_pallas
     )
+
+
+# ------------------------------------------------- degraded read into HBM
+#
+# One program per (k, shard length, block size) serves EVERY failure
+# pattern: the inverse matrix is an operand, not a constant, so a deployment
+# that cannot know which servers will die compiles nothing when they do.
+
+_SUBLANES = 8  # rows of one uint32 vreg
+_DECODE_TILE_ROWS = 512  # rows of 128 words per grid step (k x 256 KiB)
+
+
+def decode_rows(shard_bytes: int) -> int:
+    """Rows of 128 uint32 words one shard of ``shard_bytes`` pads to in the
+    decode program's layout (whole (8, 128) vregs)."""
+    return -(-shard_bytes // (4 * _LANE * _SUBLANES)) * _SUBLANES
+
+
+def _gf_rows_kernel(src_ref, masks_ref, words_ref, out_ref):
+    """out[i] = xor_c mat[i, c] * words[c] for a RUNTIME matrix, one tile of
+    (k, rows, 128) words. ``src_ref[i]`` >= 0 says row i of the matrix is
+    the unit vector of that input (a data shard that survived): a copy.
+    Any other row is Horner over the coefficient's bit planes,
+    ``acc = 2 * acc ^ xor_c (bit_j(mat[i, c]) ? words[c] : 0)`` for j = 7..0,
+    so the work is 7 doublings per OUTPUT row and none per input.
+    ``masks_ref[(i * k + c) * 8 + j]`` is that bit as 0 / 0xFFFFFFFF."""
+    k, rows, _ = words_ref.shape
+    for i in range(k):
+        src = src_ref[i]
+
+        @pl.when(src >= 0)
+        def _():
+            out_ref[i] = words_ref[jnp.maximum(src, 0)]
+
+        @pl.when(src < 0)
+        def _():
+            def vreg(g, carry):
+                at = pl.ds(pl.multiple_of(g * _SUBLANES, _SUBLANES),
+                           _SUBLANES)
+                xs = [words_ref[c, at, :] for c in range(k)]
+                acc = jnp.zeros((_SUBLANES, _LANE), jnp.uint32)
+                for j in range(7, -1, -1):
+                    if j != 7:
+                        acc = _xtime(acc)
+                    for c in range(k):
+                        acc = acc ^ (xs[c] & masks_ref[(i * k + c) * 8 + j])
+                out_ref[i, at, :] = acc
+                return carry
+
+            jax.lax.fori_loop(0, rows // _SUBLANES, vreg, 0)
+
+
+def _gf_rows(mat: jax.Array, words: jax.Array, interpret: bool) -> jax.Array:
+    """(k, k) uint8 runtime matrix applied to (k, R, 128) uint32 words."""
+    k, rows, _ = words.shape
+    bits = (mat.astype(jnp.uint32)[:, :, None]
+            >> jnp.arange(8, dtype=jnp.uint32)) & jnp.uint32(1)
+    masks = (jnp.uint32(0) - bits).reshape(-1)
+    nonzero = mat != 0
+    unit = (nonzero.sum(axis=1) == 1) & (mat.max(axis=1) == 1)
+    src = jnp.where(unit, jnp.argmax(nonzero, axis=1), -1).astype(jnp.int32)
+    tile = min(rows, _DECODE_TILE_ROWS)
+    spec = pl.BlockSpec((k, tile, _LANE), lambda t, src, masks: (0, t, 0))
+    return pl.pallas_call(
+        _gf_rows_kernel,
+        out_shape=jax.ShapeDtypeStruct(words.shape, jnp.uint32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(pl.cdiv(rows, tile),),
+            in_specs=[spec], out_specs=spec),
+        interpret=interpret,
+    )(src, masks, words)
+
+
+def _shards_to_grid(data: jax.Array, slen: int, size: int) -> jax.Array:
+    """(k, R, 128) data-shard words -> the block's (nchunks, 128) chunk
+    grid. Shard i starts at byte ``i * slen`` of the block, which is not a
+    word boundary when ``slen`` is not a multiple of 4, so each shard is
+    funnel-shifted by its own STATIC byte offset and OR-ed into place
+    (bytes past ``slen`` in a row are zero, so neighbours never clash)."""
+    from tpudfs.common.checksum import CHECKSUM_CHUNK_SIZE
+
+    k = data.shape[0]
+    nchunks = -(-max(size, 1) // CHECKSUM_CHUNK_SIZE)
+    nwords = nchunks * (CHECKSUM_CHUNK_SIZE // 4)
+    flat = data.reshape(k, -1)
+
+    def placed(x: jax.Array, at: int) -> jax.Array:
+        take = min(x.shape[0], nwords - at)  # at <= nwords: i * slen < size
+        return jax.lax.pad(x[:take], jnp.uint32(0),
+                           [(at, nwords - at - take, 0)])
+
+    out = jnp.zeros((nwords,), jnp.uint32)
+    for i in range(k):
+        at, shift = divmod(i * slen, 4)
+        if shift == 0:
+            out = out | placed(flat[i], at)
+        else:
+            out = out | placed(flat[i] << jnp.uint32(8 * shift), at) \
+                | placed(flat[i] >> jnp.uint32(32 - 8 * shift), at + 1)
+    return out.reshape(nchunks, -1)
+
+
+@partial(jax.jit, static_argnames=("slen", "size", "interpret"))
+def rs_decode_block(words: jax.Array, mat: jax.Array, *, slen: int,
+                    size: int, interpret: bool | None = None) -> jax.Array:
+    """The degraded read's ONE device program: k surviving shards in, the
+    block's chunk-padded word grid out (what ``block_crc_device`` takes).
+
+    ``words``: (k, decode_rows(slen), 128) uint32, the shards at code-word
+    indices ``present[:k]`` as the host views them (zero past ``slen``).
+    ``mat``: ``decode_matrix(k, m, present)``, (k, k) uint8, an OPERAND:
+    every failure pattern of one (k, shard length, block size) runs this
+    one compiled program. Missing data shards are computed by the Pallas
+    kernel (interpreted off the TPU unless ``interpret`` says otherwise);
+    bit-exact with ``erasure.reconstruct``."""
+    if interpret is None:
+        interpret = not on_tpu()
+    with jax.named_scope("tpudfs.rs_decode"):
+        return _shards_to_grid(_gf_rows(mat, words, interpret), slen, size)
+
+
+def survivors_to_words(shards: list, slen: int) -> np.ndarray:
+    """Host side of :func:`rs_decode_block`: the k shard buffers of
+    ``slen`` bytes stacked zero-padded and VIEWED as their uint32 words."""
+    rows = decode_rows(slen)
+    stack = np.zeros((len(shards), rows * _LANE * 4), dtype=np.uint8)
+    for r, shard in enumerate(shards):
+        stack[r, :slen] = np.frombuffer(shard, dtype=np.uint8)
+    return stack.view("<u4").reshape(len(shards), rows, _LANE)
 
 
 def rs_encode_jax(data: bytes, k: int, m: int, **kw) -> list[bytes]:
