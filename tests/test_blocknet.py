@@ -11,15 +11,22 @@ from __future__ import annotations
 
 import asyncio
 
+import msgpack
 import numpy as np
 import pytest
 
 from tests.test_chunkserver import Cluster, _rand, _write
-from tpudfs.common import native
-from tpudfs.common.blocknet import BlockConnPool
+from tpudfs.common import blocknet, native, writestream
+from tpudfs.common.blocknet import (
+    BlockConnPool,
+    BlockPortServer,
+    _pack_frame,
+    _read_frame,
+)
 from tpudfs.common.checksum import crc32c
-from tpudfs.common.rpc import RpcError
+from tpudfs.common.rpc import ClientTls, RpcError, ServerTls
 from tpudfs.chunkserver.service import SERVICE
+from tpudfs.testing.certs import make_test_pki
 
 
 @pytest.fixture
@@ -322,3 +329,333 @@ async def test_native_term_drain_closes_python_plane_window(cluster,
     assert "Stale master term" in ei.value.message
     await pool.close()
     await cluster.stop()
+
+
+# ------------- the client's receive path (BlockConn), plain and over TLS
+#
+# One family over a loopback BlockPortServer. Handlers answer whole frames;
+# the cases that need a frame cut in two use a stream handler, which owns
+# the connection and writes raw bytes.
+
+_ADDR = "127.0.0.1:9"  # the peer's "gRPC" address: its port is cached
+_SENTINEL = 0xAA
+
+
+@pytest.fixture(scope="module")
+def pki(tmp_path_factory):
+    return make_test_pki(tmp_path_factory.mktemp("pki"))
+
+
+class _Loopback:
+    def __init__(self, tls: bool, pki: dict):
+        self.server = BlockPortServer(
+            {}, tls=ServerTls(pki["server_cert"], pki["server_key"])
+            if tls else None)
+        self.handlers = self.server.handlers
+        self.stream_handlers = self.server.stream_handlers
+        self.pool = BlockConnPool(
+            tls=ClientTls(ca_path=pki["ca"]) if tls else None)
+
+    async def __aenter__(self):
+        port = await self.server.start()
+        self.hostport = f"127.0.0.1:{port}"
+        self.pool._ports[_ADDR] = port
+        return self
+
+    async def __aexit__(self, *exc):
+        await self.pool.close()
+        await self.server.stop()
+
+    def call(self, method, req=None, **kw):
+        return self.pool.call(None, _ADDR, "Svc", method, req or {}, **kw)
+
+    def pooled(self) -> int:
+        return len(self.pool._free.get(self.hostport, []))
+
+    def cut_frame(self, payload: bytes, first: int, then):
+        """Stream handler ``Cut``: header, the payload's first ``first``
+        bytes, ``await then()``, and the rest if that returned True."""
+        sent_first = asyncio.Event()
+
+        async def cut(_req, _r, w):
+            h = msgpack.packb({"ok": True, "_d": 1})
+            w.write(blocknet._U32.pack(len(h)) + h
+                    + blocknet._U64.pack(len(payload)) + payload[:first])
+            await w.drain()
+            sent_first.set()
+            if await then():
+                w.write(payload[first:])
+                await w.drain()
+            return False
+
+        self.stream_handlers["Cut"] = cut
+        return sent_first
+
+
+def _into(buf, spans):
+    """Scatter callback handing out ``buf[a:b]`` for each span."""
+    view = memoryview(buf)
+    return lambda _header, _plen: [view[a:b] for a, b in spans]
+
+
+async def _case_direct_fills_segments(lb):
+    """Slots of a round land in place; a slot of another size than asked
+    is drained to scratch and the stream stays framed."""
+    parts = [_rand(300_000, 1), _rand(70_000, 2), _rand(200_000, 3)]
+
+    async def read(_req):
+        return {"sizes": [len(p) for p in parts], "data_parts": parts}
+
+    lb.handlers["Read"] = read
+    flat = bytearray([_SENTINEL]) * 600_000
+    scratch = bytearray(70_000)
+    view = memoryview(flat)
+
+    def scatter(header, plen):
+        assert header["sizes"] == [300_000, 70_000, 200_000]
+        assert plen == 570_000
+        return [view[0:300_000], scratch, view[300_000:500_000]]
+
+    for _ in range(2):  # the second frame rides the pooled connection
+        resp = await lb.call("Read", payload_into=scatter)
+        assert resp["data"] is None
+        assert flat[:300_000] == parts[0]
+        assert scratch == parts[1]
+        assert flat[300_000:500_000] == parts[2]
+        assert flat[500_000:] == bytes([_SENTINEL]) * 100_000
+    pool = lb.pool
+    assert pool.rx_direct_bytes + pool.rx_buffered_bytes == 2 * 570_000
+    # All but what one recv brought along with each header.
+    assert pool.rx_buffered_bytes <= 2 * blocknet._RX_BUF
+    assert lb.pooled() == 1
+
+
+async def _case_zero_length_payload(lb):
+    async def read(_req):
+        return {"data": b"", "total_size": 0}
+
+    lb.handlers["Read"] = read
+    asked = []
+    resp = await lb.call(
+        "Read", payload_into=lambda h, n: asked.append(n))
+    assert resp["data"] == b"" and resp["total_size"] == 0
+    assert not asked, "an empty payload has no destination to ask for"
+    assert lb.pool.rx_direct_bytes == lb.pool.rx_buffered_bytes == 0
+    assert lb.pooled() == 1
+
+
+async def _case_segments_short_of_plen(lb):
+    data = _rand(100_000, 4)
+
+    async def read(_req):
+        return {"data": data}
+
+    lb.handlers["Read"] = read
+    buf = bytearray(100_000)
+    with pytest.raises(ConnectionError, match="cover 99999 of 100000"):
+        await lb.pool._call_blockport(lb.hostport, "Read", {},
+                                      _into(buf, [(0, 99_999)]))
+    assert lb.pooled() == 0, "a connection mid-payload went to the pool"
+    with pytest.raises(RpcError) as ei:  # and through call(): UNAVAILABLE
+        await lb.call("Read", payload_into=_into(buf, [(0, 99_999)]))
+    assert ei.value.code.name == "UNAVAILABLE"
+
+
+async def _case_buffered_path_keeps_data(lb):
+    """No callback, a callback that declines, and an error frame: the
+    payload comes back as ``resp["data"]``, equal to the bytes sent."""
+    import grpc
+
+    sent = {n: _rand(n, 5)
+            for n in (100, blocknet._RX_BUF, blocknet._RX_BUF + 1, 600_000)}
+
+    async def read(req):
+        if req["n"] < 0:
+            raise RpcError(grpc.StatusCode.NOT_FOUND, "no such block")
+        return {"data": sent[req["n"]]}
+
+    lb.handlers["Read"] = read
+    total = 0
+    for n, data in sent.items():
+        for into in (None, lambda _h, _n: None):
+            resp = await lb.call("Read", {"n": n}, payload_into=into)
+            assert resp["data"] == data and len(resp["data"]) == n
+            total += n
+    asked = []
+    with pytest.raises(RpcError) as ei:
+        await lb.call("Read", {"n": -1},
+                      payload_into=lambda h, n: asked.append(h))
+    assert ei.value.code.name == "NOT_FOUND" and not asked
+    assert lb.pool.rx_direct_bytes == 0
+    assert lb.pool.rx_buffered_bytes == total
+    assert lb.pooled() == 1  # an error frame leaves the stream framed
+
+
+async def _case_peer_closes_mid_payload(lb):
+    payload = _rand(1 << 20, 6)
+
+    async def hang_up():
+        return False
+
+    lb.cut_frame(payload, 1 << 19, hang_up)
+    buf = bytearray([_SENTINEL]) * (1 << 20)
+    with pytest.raises(RpcError) as ei:
+        await lb.call("Cut", payload_into=_into(buf, [(0, 1 << 20)]))
+    assert ei.value.code.name == "UNAVAILABLE"
+    assert not lb.pool.breakers.allow(_ADDR), "the breaker stayed closed"
+    assert _ADDR not in lb.pool._ports, "the cached port was kept"
+    assert lb.pooled() == 0
+    assert buf[: 1 << 19] == payload[: 1 << 19]
+    assert buf[1 << 19 :] == bytes([_SENTINEL]) * (1 << 19)
+
+
+async def _abandoned_mid_payload(lb, abandon):
+    """The call ends (``abandon``) with half a payload in; the other half
+    is sent afterwards and must not reach the caller's buffer, which by
+    then may belong to another round."""
+    payload = _rand(1 << 20, 7)
+    go_on = asyncio.Event()
+
+    async def rest_later():
+        await go_on.wait()
+        return True
+
+    sent_first = lb.cut_frame(payload, 1 << 19, rest_later)
+    buf = bytearray([_SENTINEL]) * (1 << 20)
+    await abandon(lb.call("Cut", timeout=0.5,
+                          payload_into=_into(buf, [(0, 1 << 20)])),
+                  sent_first)
+    go_on.set()
+    await asyncio.sleep(0.3)
+    assert buf[1 << 19 :] == bytes([_SENTINEL]) * (1 << 19), \
+        "bytes that arrived after the call returned were written"
+    assert lb.pooled() == 0, "a connection mid-payload went to the pool"
+
+
+async def _case_timeout_mid_payload(lb):
+    async def time_out(call, _sent_first):
+        with pytest.raises(RpcError) as ei:
+            await call
+        assert ei.value.code.name == "DEADLINE_EXCEEDED"
+
+    await _abandoned_mid_payload(lb, time_out)
+
+
+async def _case_cancel_mid_payload(lb):
+    async def cancel(call, sent_first):
+        task = asyncio.ensure_future(call)
+        await sent_first.wait()
+        await asyncio.sleep(0.05)
+        task.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await task
+
+    await _abandoned_mid_payload(lb, cancel)
+
+
+async def _case_fifty_calls_one_connection(lb):
+    """Direct and buffered payloads of changing sizes, back to back on
+    one pooled connection: every frame boundary holds."""
+    async def read(req):
+        return {"data": _rand(req["n"], req["n"]), "n": req["n"]}
+
+    lb.handlers["Read"] = read
+    for i in range(50):
+        n = (i * 79_193) % 600_000 + (i % 3)  # both sides of _RX_BUF
+        if i % 2:
+            buf = bytearray(n)
+            resp = await lb.call(
+                "Read", {"n": n},
+                payload_into=_into(buf, [(0, n // 3), (n // 3, n)]))
+            got = buf
+        else:
+            resp = await lb.call("Read", {"n": n})
+            got = resp["data"]
+        assert resp["n"] == n and got == _rand(n, n)
+    assert len(lb.server._conns) == 1 and lb.pooled() == 1
+
+
+async def _case_write_stream_acks_back_to_back(lb):
+    """A write stream's receive side on the same connection class: ready,
+    then every watermark ack and the final in ONE write, so they arrive
+    in one recv and have to come apart again."""
+    data = _rand(5 * writestream.FRAME_SIZE + 17, 8)
+    frames = writestream.frame_count(len(data))
+    received = bytearray()
+
+    async def write_stream(_req, r, w):
+        w.writelines(_pack_frame({"ok": True, "ready": 1}, None))
+        for _ in range(frames):
+            _h, chunk = await _read_frame(r)
+            received.extend(chunk)
+        acks = []
+        for n in range(1, frames + 1):
+            acks += _pack_frame({"ok": True, "w": n}, None)
+        acks += _pack_frame({"ok": True, "final": 1, "success": True}, None)
+        w.write(b"".join(acks))
+        await w.drain()
+        return True
+
+    async def ping(_req):
+        return {"pong": 1}
+
+    lb.stream_handlers["WriteStream"] = write_stream
+    lb.handlers["Ping"] = ping
+    conn = await lb.pool._checkout(lb.hostport)
+    begin = writestream.begin_header(
+        "b", len(data), expected_crc32c=crc32c(data), master_term=0,
+        master_shard="", next_servers=[], next_data_ports=[])
+    final = await writestream.send_block_stream(conn, conn, begin, data)
+    assert final["success"] and final["_watermark"] == frames
+    assert received == data
+    lb.pool._release(lb.hostport, conn)
+    assert lb.pooled() == 1, "the stream left the connection unframed"
+    assert (await lb.call("Ping"))["pong"] == 1
+    assert len(lb.server._conns) == 1
+
+
+async def _case_backlog_larger_than_the_buffer(lb):
+    """More unread small frames than the connection's buffer holds: the
+    transport is paused, not overrun, and every frame comes out whole
+    and in order as the reader catches up."""
+    n = 4 * blocknet._RX_BUF // 256
+
+    async def flood(_req, _r, w):
+        w.write(b"".join(
+            b"".join(_pack_frame({"ok": True, "w": i, "pad": "x" * 240}, None))
+            for i in range(n)))
+        await w.drain()
+        return True
+
+    lb.stream_handlers["Flood"] = flood
+    conn = await lb.pool._checkout(lb.hostport)
+    conn.writelines(_pack_frame({"m": "Flood"}, None))
+    await conn.drain()
+    await asyncio.sleep(0.2)  # let the backlog build before reading
+    for i in range(n):
+        header, payload = await _read_frame(conn)
+        assert header["w"] == i and payload == b""
+    lb.pool._release(lb.hostport, conn)
+    assert lb.pooled() == 1
+
+
+_CASES = [
+    _case_direct_fills_segments,
+    _case_zero_length_payload,
+    _case_segments_short_of_plen,
+    _case_buffered_path_keeps_data,
+    _case_peer_closes_mid_payload,
+    _case_timeout_mid_payload,
+    _case_cancel_mid_payload,
+    _case_fifty_calls_one_connection,
+    _case_write_stream_acks_back_to_back,
+    _case_backlog_larger_than_the_buffer,
+]
+
+
+@pytest.mark.parametrize("tls", [False, True], ids=["plain", "tls"])
+@pytest.mark.parametrize("case", _CASES,
+                         ids=[c.__name__[len("_case_"):] for c in _CASES])
+async def test_client_receive(case, tls, pki):
+    async with _Loopback(tls, pki) as lb:
+        await case(lb)

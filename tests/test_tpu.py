@@ -1595,6 +1595,66 @@ async def test_fused_read_failed_frame_frees_its_origin(tmp_path, failure,
         await c.stop()
 
 
+async def test_fused_read_rounds_land_in_place_through_a_blockport(tmp_path):
+    """Nothing stubbed between the combiner and the socket: a full
+    16-block round, and a round with one slot the origin cannot serve
+    (its replica is gone: the slot comes back short, with no bytes),
+    travel as real ReadBlocks frames. The kernel puts the good slots
+    straight into the round buffer (all but what one recv brings along
+    with the header), on both sides of the gap; the short slot falls back
+    per block; the upload stage gets the same bytes as ever."""
+    from tpudfs.common import blocknet, telemetry
+
+    full = _rand(16 * 64 * 1024, seed=66)
+    gap = _rand(16 * 64 * 1024, seed=67)
+    c, client = await _cluster_with_files(
+        tmp_path, [("/rf/full", full), ("/rf/gap", gap)])
+    try:
+        _, _, addrs = _remote_reader(client, False, origins=1)
+        reader = HbmReader(client, jax.devices()[:1], batch_reads=16)
+        comb = reader._combiner(reader.devices[0])
+        pool = client.block_pool
+        block = (await client.get_file_info("/rf/gap"))["blocks"][5]
+        origin = next(cs for cs in c.chunkservers
+                      if cs.address == addrs[0])
+        origin.store.block_path(block["block_id"]).unlink()
+        origin.invalidate_cached(block["block_id"])
+
+        async def read(path):
+            telemetry.enable()
+            try:
+                blocks = await reader.read_file_to_device_blocks(
+                    path, verify="lazy")
+            finally:
+                telemetry.disable()
+                received = [r.attrs for r in telemetry.drain()
+                            if r.name == "blockport.recv_payload"]
+            return blocks, received
+
+        for path, data, short in (("/rf/full", full, None),
+                                  ("/rf/gap", gap, 5)):
+            before = comb.blocks, pool.rx_direct_bytes
+            blocks, received = await read(path)
+            frames = [a for a in received if a["method"] == "ReadBlocks"]
+            good = 16 - (short is not None)
+            assert comb.blocks - before[0] == good
+            assert [f["bytes"] for f in frames] == [good * 65536], \
+                "not one frame of the good slots"
+            direct = frames[0]["direct"]
+            assert good * 65536 - blocknet._RX_BUF <= direct <= good * 65536
+            # The counter agrees with the spans (the fall-back's own
+            # ReadBlock, refused by the origin and served by the next
+            # replica, lands in place too).
+            assert sum(a["bytes"] for a in received) == 16 * 65536
+            assert pool.rx_direct_bytes - before[1] == \
+                sum(a["direct"] for a in received)
+            assert [b.batch is None for b in blocks] == \
+                [i == short for i in range(16)]
+            assert await _confirmed_bytes(reader, blocks) == data
+    finally:
+        await c.stop()
+
+
 @pytest.mark.parametrize("host_verify", [True, False])
 async def test_fused_read_cancel_with_rounds_in_flight(tmp_path,
                                                        host_verify):

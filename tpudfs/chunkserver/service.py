@@ -973,7 +973,7 @@ class ChunkServer:
                     co = None
                 if co is not None:
                     fwd_hostport, fwd_conn = co
-                    fwd = writestream.ForwardStream(*fwd_conn)
+                    fwd = writestream.ForwardStream(fwd_conn, fwd_conn)
                     begin = dict(fwd_req)
                     begin.update(m="WriteStream", size=size,
                                  frame_size=frame_size)

@@ -311,7 +311,7 @@ class Client:
         ``allow_blockport=False`` forces gRPC (chain writers use it when
         the remaining chain isn't blockport-safe). ``payload_into``:
         blockport scatter callback for the response payload (blocknet
-        _read_frame); on the gRPC path the payload still arrives as
+        BlockConn.read_payload); on the gRPC path the payload still arrives as
         ``resp["data"]`` and the caller copies."""
         dialed = self._dial(addr)
         if dialed != addr or not allow_blockport:

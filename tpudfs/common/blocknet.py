@@ -5,11 +5,20 @@ HTTP/2 framing is cheap — chunkserver.rs:722-1087). This build's control
 plane is Python, and gRPC there measures ~2.3 ms of the single bench core
 per 1 MiB unary message — more CPU than the durable write it carries. Bulk
 block payloads therefore ride a dedicated length-framed TCP protocol on a
-separate listener (asyncio streams, ~1.1 ms per 1 MiB on the same host,
-measured both-endpoints-on-one-core), while EVERY control RPC — and any
-peer that doesn't advertise a blockport — stays on the msgpack-gRPC
-substrate. This is the DCN half of the SURVEY §2.6 transport split; the
-colocated half is ICI collectives (tpu/ici_replication.py).
+separate listener, while EVERY control RPC — and any peer that doesn't
+advertise a blockport — stays on the msgpack-gRPC substrate. This is the
+DCN half of the SURVEY §2.6 transport split; the colocated half is ICI
+collectives (tpu/ici_replication.py).
+
+The two ends receive differently. The server (``BlockPortServer``, the
+asyncio fallback of the native engine) reads frames off asyncio streams.
+The client (``BlockConn``, pooled by ``BlockConnPool``) is an
+``asyncio.BufferedProtocol``: headers and small frames are parsed out of a
+small buffer the connection owns, and a response payload is received by
+the kernel straight into its destination — the caller's scatter segments
+(a ``ReadBlocks`` round lands in the combiner's round buffer with one
+``recv_into`` a wake-up) or, when the caller gave none, one buffer of the
+payload's size that becomes ``resp["data"]``.
 
 Frame, both directions::
 
@@ -96,11 +105,20 @@ _U32 = struct.Struct("<I")
 _U64 = struct.Struct("<Q")
 _MAX_HEADER = 1 << 20
 _MAX_PAYLOAD = 100 * 1024 * 1024  # parity with MAX_MESSAGE_BYTES
-#: asyncio stream buffer limit. The default 64 KiB makes readexactly() on
-#: a multi-MiB frame wake the protocol once per 64 KiB (hundreds of
-#: event-loop wakeups per fused ReadBlocks frame on the one-core host);
-#: 4 MiB matches the pinned socket-buffer target in _tune_socket.
+#: Server side only: asyncio stream buffer limit of the serve loop. The
+#: default 64 KiB makes readexactly() on a multi-MiB request frame (a
+#: whole-block write) wake the protocol once per 64 KiB; 4 MiB matches the
+#: pinned socket-buffer target in _tune_socket. The client side has no
+#: stream buffer (BlockConn).
 _STREAM_LIMIT = 4 * 1024 * 1024
+#: Client side: the connection's own receive buffer, as large as one recv
+#: of an asyncio stream (256 KiB), so that a small frame (a write-stream
+#: ack, an EC shard, an error) arrives whole in ONE wake-up and is handed
+#: out from here. A recv that runs past a large frame's header brings at
+#: most this much of the payload with it, which is copied to its place
+#: once; the rest of the payload is received in place. Whatever is larger
+#: (a header too) is received into a buffer of its own.
+_RX_BUF = 256 * 1024
 
 
 def enabled() -> bool:
@@ -130,19 +148,15 @@ def _pack_frame(header: dict, payload) -> list[bytes]:
     return out
 
 
-async def _read_frame(r: asyncio.StreamReader, into=None
-                      ) -> tuple[dict, bytes | None]:
-    """``into``: optional scatter callback ``(header, plen) -> segments``
-    (writable buffers whose lengths sum to plen) — the payload then
-    streams DIRECTLY into the caller's buffers in bounded chunks instead
-    of materializing one multi-MiB bytes via readexactly (which also
-    forces the caller into slice copies); returns (header, None). A None
-    result from the callback falls back to the bytes path."""
+async def _read_frame(r) -> tuple[dict, bytes]:
+    """One whole frame off ``r``: an ``asyncio.StreamReader`` (server
+    side) or a ``BlockConn`` (the client side of a write stream) — any
+    object with ``readexactly``."""
     header, plen = await _read_header(r)
-    return header, await _read_payload(r, header, plen, into)
+    return header, await r.readexactly(plen) if plen else b""
 
 
-async def _read_header(r: asyncio.StreamReader) -> tuple[dict, int]:
+async def _read_header(r) -> tuple[dict, int]:
     hlen = _U32.unpack(await r.readexactly(4))[0]
     if hlen > _MAX_HEADER:
         raise ConnectionError(f"blockport header too large: {hlen}")
@@ -154,42 +168,6 @@ async def _read_header(r: asyncio.StreamReader) -> tuple[dict, int]:
     return header, plen
 
 
-async def _read_payload(r: asyncio.StreamReader, header: dict, plen: int,
-                        into=None) -> bytes | None:
-    if plen and into is not None:
-        segments = into(header, plen)
-        if segments is not None:
-            await _read_into(r, segments, plen)
-            return None
-    return await r.readexactly(plen) if plen else b""
-
-
-async def _read_into(r: asyncio.StreamReader, segments, plen: int) -> None:
-    total = 0
-    views = []
-    for seg in segments:
-        v = memoryview(seg).cast("B")
-        views.append(v)
-        total += len(v)
-    if total != plen:
-        # The connection is mid-payload and cannot be resynced.
-        raise ConnectionError(
-            f"scatter segments cover {total} of {plen} payload bytes")
-    for v in views:
-        off = 0
-        n = len(v)
-        while off < n:
-            chunk = await r.read(min(_READ_INTO_CHUNK, n - off))
-            if not chunk:
-                raise asyncio.IncompleteReadError(b"", plen)
-            v[off : off + len(chunk)] = chunk
-            off += len(chunk)
-
-
-#: Scatter-read chunk: big enough to amortize event-loop trips, small
-#: enough to stay within the stream buffer's high-water mark.
-_READ_INTO_CHUNK = 1 << 20
-
 #: Serve-loop backpressure watermark: an unconditional ``await
 #: w.drain()`` per response frame costs an event-loop round-trip per
 #: frame even when the kernel buffer is empty; only pay it once the
@@ -197,7 +175,7 @@ _READ_INTO_CHUNK = 1 << 20
 _DRAIN_WATERMARK = 1 << 18
 
 
-async def _drain_backpressure(w: asyncio.StreamWriter) -> None:
+async def _drain_backpressure(w) -> None:
     transport = w.transport
     if transport is None or \
             transport.get_write_buffer_size() > _DRAIN_WATERMARK:
@@ -364,6 +342,234 @@ class BlockPortServer:
             w.close()
 
 
+def _settle(fut: asyncio.Future | None, exc: BaseException | None) -> None:
+    if fut is not None and not fut.done():
+        if exc is None:
+            fut.set_result(None)
+        else:
+            fut.set_exception(exc)
+
+
+class BlockConn(asyncio.BufferedProtocol):
+    """Client side of one blockport connection: the protocol AND the small
+    stream surface its users need (``readexactly``, ``writelines``,
+    ``drain``, ``close``, ``is_closing``, ``transport``), so request/
+    response calls, write streams and a hop's relay leg share one class.
+
+    One reader at a time. While nothing larger is awaited, received bytes
+    land in the connection's own ``_RX_BUF`` bytes and ``readexactly``
+    hands them out (headers, back-to-back ack frames). While a payload is
+    awaited, ``get_buffer`` IS the destination: the kernel copies into the
+    caller's segment and nothing else touches the bytes.
+
+    The destination may be a pooled buffer of the caller's, so a receive
+    that ends early (cancellation, timeout, transport error) drops the
+    destination and aborts the transport before it returns: nothing that
+    arrives later is written anywhere but here."""
+
+    def __init__(self) -> None:
+        self.transport: asyncio.Transport | None = None
+        self._loop = asyncio.get_running_loop()
+        self._view = memoryview(bytearray(_RX_BUF))
+        #: unread bytes of the own buffer are ``_view[_r:_w]``.
+        self._r = 0
+        self._w = 0
+        #: run being received in place (its part still to fill), and the
+        #: runs after it, last first.
+        self._dst: memoryview | None = None
+        self._rest: list[memoryview] = []
+        self._waiter: asyncio.Future | None = None
+        self._exc: BaseException | None = None
+        self._rx_paused = False
+        self._tx_paused = False
+        self._drain_waiters: list[asyncio.Future] = []
+
+    # ------------------------------------------------ protocol callbacks
+
+    def connection_made(self, transport) -> None:
+        self.transport = transport
+
+    def get_buffer(self, sizehint: int) -> memoryview:
+        if self._dst is not None:
+            return self._dst
+        if self._w == _RX_BUF:
+            self._compact()  # _r > 0: a buffer full of unread bytes paused
+        return self._view[self._w:]
+
+    def buffer_updated(self, nbytes: int) -> None:
+        dst = self._dst
+        if dst is not None:
+            if nbytes < len(dst):
+                self._dst = dst[nbytes:]
+            elif self._rest:
+                self._dst = self._rest.pop()
+            else:
+                self._dst = None
+                self._wake()
+            return
+        self._w += nbytes
+        if self._w == _RX_BUF and not self._r:
+            self._rx_paused = True
+            self.transport.pause_reading()
+        self._wake()
+
+    def eof_received(self) -> None:
+        self._fail(asyncio.IncompleteReadError(b"", None))
+
+    def connection_lost(self, exc) -> None:
+        self._fail(exc or ConnectionResetError("blockport connection lost"))
+
+    def pause_writing(self) -> None:
+        self._tx_paused = True
+
+    def resume_writing(self) -> None:
+        self._tx_paused = False
+        self._wake_drains()
+
+    # ------------------------------------------------------------ receive
+
+    async def readexactly(self, n: int):
+        """``n`` bytes in stream order: out of the connection's buffer
+        when they fit there, else received into a buffer of their own."""
+        if n > _RX_BUF:
+            data = bytearray(n)
+            await self._recv_into([memoryview(data)])
+            return data
+        if n > _RX_BUF - self._r:
+            self._compact()
+        while self._w - self._r < n:
+            await self._wait()
+        data = bytes(self._view[self._r:self._r + n])
+        self._r += n
+        self._consumed()
+        return data
+
+    async def read_payload(self, header: dict, plen: int, into=None):
+        """The ``plen`` payload bytes that follow ``header``. ``into``:
+        optional scatter callback ``(header, plen) -> segments`` (writable
+        buffers whose lengths sum to ``plen``; adjacent destinations
+        should come as ONE segment, each segment is one run of
+        ``recv_into``). Returns ``(None, direct)`` when the payload went
+        into the segments, ``direct`` being the bytes the kernel put
+        there itself (all but what one recv brought along with the
+        header); ``(data, 0)`` when there were none — no callback, a None
+        result, an error frame, an empty payload."""
+        segments = None
+        if plen and into is not None and header.get("ok"):
+            segments = into(header, plen)
+        if segments is None:
+            return (await self.readexactly(plen) if plen else b""), 0
+        views = [memoryview(seg).cast("B") for seg in segments]
+        covered = sum(len(v) for v in views)
+        if covered != plen:
+            # The connection is mid-payload and cannot be resynced.
+            raise ConnectionError(
+                f"scatter segments cover {covered} of {plen} payload bytes")
+        return None, plen - await self._recv_into(views)
+
+    async def _recv_into(self, views: list[memoryview]) -> int:
+        """Fill ``views`` in order. Bytes of theirs that a recv already
+        brought into the own buffer are copied over first (their count is
+        the result); the rest arrives in place."""
+        rest = [v for v in reversed(views) if len(v)]
+        copied = 0
+        while rest and self._r < self._w:
+            dst = rest[-1]
+            k = min(len(dst), self._w - self._r)
+            dst[:k] = self._view[self._r:self._r + k]
+            self._r += k
+            copied += k
+            if k == len(dst):
+                rest.pop()
+            else:
+                rest[-1] = dst[k:]
+        self._consumed()
+        if rest:
+            self._dst = rest.pop()
+            self._rest = rest
+            try:
+                await self._wait()
+            except BaseException:
+                self._abort()
+                raise
+        return copied
+
+    async def _wait(self) -> None:
+        if self._exc is not None:
+            raise self._exc
+        if self._waiter is not None:
+            raise RuntimeError("a BlockConn has one reader at a time")
+        self._waiter = self._loop.create_future()
+        try:
+            await self._waiter
+        finally:
+            self._waiter = None
+
+    def _wake(self, exc: BaseException | None = None) -> None:
+        _settle(self._waiter, exc)
+
+    def _wake_drains(self, exc: BaseException | None = None) -> None:
+        waiters, self._drain_waiters = self._drain_waiters, []
+        for w in waiters:
+            _settle(w, exc)
+
+    def _compact(self) -> None:
+        n = self._w - self._r
+        self._view[:n] = self._view[self._r:self._w]
+        self._r, self._w = 0, n
+
+    def _consumed(self) -> None:
+        if self._r == self._w:
+            self._r = self._w = 0
+        if self._rx_paused:
+            self._rx_paused = False
+            self.transport.resume_reading()
+
+    def _fail(self, exc: BaseException) -> None:
+        self._dst = None
+        self._rest = []
+        if self._exc is None:
+            self._exc = exc
+        self._wake(exc)
+        self._wake_drains(exc)
+
+    def _abort(self) -> None:
+        """Mid-payload exit: forget the destination and remove the reader
+        synchronously (``abort`` does, for TCP and for TLS — a TLS
+        ``close`` would still flush incoming data through the protocol)."""
+        self._fail(ConnectionError("blockport receive abandoned mid-payload"))
+        if self.transport is not None:
+            self.transport.abort()
+
+    # --------------------------------------------------------------- send
+
+    def writelines(self, parts) -> None:
+        self.transport.writelines(parts)
+
+    async def drain(self) -> None:
+        if self.transport.is_closing():
+            await asyncio.sleep(0)  # let connection_lost say why
+        if self._exc is not None:
+            raise self._exc
+        if self._tx_paused:
+            waiter = self._loop.create_future()
+            self._drain_waiters.append(waiter)
+            await waiter
+
+    def is_closing(self) -> bool:
+        return self._exc is not None or self.transport.is_closing()
+
+    def idle(self) -> bool:
+        """At a frame boundary with nothing unread: fit for the pool."""
+        return (self._dst is None and self._waiter is None
+                and self._r == self._w and not self.is_closing())
+
+    def close(self) -> None:
+        self._dst = None
+        self._rest = []
+        self.transport.close()
+
+
 class BlockConnPool:
     """Per-address pooled blockport client with gRPC-probed discovery and
     transparent gRPC fallback.
@@ -379,7 +585,13 @@ class BlockConnPool:
 
     def __init__(self, tls: ClientTls | None = None):
         self._tls = tls
-        self._free: dict[str, list] = {}
+        self._free: dict[str, list[BlockConn]] = {}
+        #: response payload bytes the kernel put straight into the
+        #: caller's scatter segments / that went through a buffer of the
+        #: connection's (no segments given, or brought along with the
+        #: header). Their sum is every payload byte _call_blockport got.
+        self.rx_direct_bytes = 0
+        self.rx_buffered_bytes = 0
         #: addr -> (port | None). None = peer has no blockport (final,
         #: from an UNIMPLEMENTED probe). Transport-level probe/call
         #: failures instead open the per-address breaker below.
@@ -528,7 +740,6 @@ class BlockConnPool:
             self.breakers.record_failure(addr)
             raise RpcError(grpc.StatusCode.UNAVAILABLE,
                            f"write stream dial {hostport}: {e!r}") from None
-        r, w = conn
         header = dict(req)
         rem = remaining_budget()
         if rem is not None:
@@ -538,7 +749,7 @@ class BlockConnPool:
             header[TENANT_FRAME_KEY] = tenant
         try:
             resp = await asyncio.wait_for(
-                writestream.send_block_stream(r, w, header, data),
+                writestream.send_block_stream(conn, conn, header, data),
                 timeout=timeout,
             )
         except RpcError as e:
@@ -550,18 +761,18 @@ class BlockConnPool:
                     self._mark_stream_unsupported(addr)
                     return None
             else:
-                w.close()
+                conn.close()
             raise
         except asyncio.TimeoutError:
-            w.close()
+            conn.close()
             raise RpcError(grpc.StatusCode.DEADLINE_EXCEEDED,
                            f"write stream to {hostport} timed out") from None
         except asyncio.CancelledError:
-            w.close()
+            conn.close()
             raise
         except (OSError, ConnectionError, asyncio.IncompleteReadError,
                 ValueError, msgpack.exceptions.UnpackException) as e:
-            w.close()
+            conn.close()
             self._ports.pop(addr, None)
             self.breakers.record_failure(addr)
             raise RpcError(grpc.StatusCode.UNAVAILABLE,
@@ -571,10 +782,10 @@ class BlockConnPool:
         return resp
 
     async def stream_checkout(self, rpc: RpcClient, addr: str,
-                              service: str) -> tuple[str, tuple] | None:
+                              service: str) -> tuple[str, BlockConn] | None:
         """Checkout a (possibly pooled) blockport connection to a
         stream-capable peer for a hop's downstream relay leg. Returns
-        ``(hostport, (reader, writer))`` or None when the peer can't take
+        ``(hostport, conn)`` or None when the peer can't take
         a stream. Pair with :meth:`stream_release` (clean finish) or
         :meth:`stream_discard` (mid-stream failure)."""
         if not enabled():
@@ -590,7 +801,7 @@ class BlockConnPool:
         self._release(hostport, conn)
 
     def stream_discard(self, addr: str, conn) -> None:
-        conn[1].close()
+        conn.close()
         self._ports.pop(addr, None)
         self.breakers.record_failure(addr)
 
@@ -599,7 +810,7 @@ class BlockConnPool:
                    payload_into=None) -> dict:
         """Blockport when advertised, gRPC otherwise. ``req["data"]`` (if
         any) travels as the raw payload frame. ``payload_into``: scatter
-        callback for the RESPONSE payload (see _read_frame) — honored on
+        callback for the RESPONSE payload (see BlockConn.read_payload) — honored on
         the blockport transport only; the gRPC path (and a None callback
         result) returns the payload as ``resp["data"]`` and the caller
         copies it itself."""
@@ -650,39 +861,37 @@ class BlockConnPool:
         legitimately flip it back."""
         self._stream[addr] = False
 
-    async def _checkout(self, hostport: str):
+    async def _checkout(self, hostport: str) -> BlockConn:
         """Pop a pooled connection to ``hostport`` or open a fresh one."""
         free = self._free.setdefault(hostport, [])
         while free:
             conn = free.pop()
-            if conn[1].is_closing():
+            if conn.is_closing():
                 continue
             return conn
         host, port = hostport.rsplit(":", 1)
-        conn = await asyncio.open_connection(
-            host, int(port), ssl=self._ssl_ctx,
+        _, conn = await asyncio.get_running_loop().create_connection(
+            BlockConn, host, int(port), ssl=self._ssl_ctx,
             server_hostname=host if self._ssl_ctx is not None else None,
-            limit=_STREAM_LIMIT,
         )
-        sock = conn[1].get_extra_info("socket")
+        sock = conn.transport.get_extra_info("socket")
         if sock is not None:
             _tune_socket(sock)
         return conn
 
-    def _release(self, hostport: str, conn) -> None:
+    def _release(self, hostport: str, conn: BlockConn) -> None:
         """Return a still-framed connection to the idle pool (extras
         close). Only call when the frame boundary is intact — a torn or
         aborted stream must close the connection instead."""
         free = self._free.setdefault(hostport, [])
-        if len(free) < self.MAX_IDLE_PER_PEER and not conn[1].is_closing():
+        if len(free) < self.MAX_IDLE_PER_PEER and conn.idle():
             free.append(conn)
         else:
-            conn[1].close()
+            conn.close()
 
     async def _call_blockport(self, hostport: str, method: str,
                               req: dict, payload_into=None) -> dict:
         conn = await self._checkout(hostport)
-        r, w = conn
         try:
             header = {k: v for k, v in req.items() if k != "data"}
             header["m"] = method
@@ -692,21 +901,25 @@ class BlockConnPool:
             tenant = raw_tenant()
             if tenant is not None:
                 header[TENANT_FRAME_KEY] = tenant
-            w.writelines(_pack_frame(header, req.get("data")))
-            await w.drain()
+            conn.writelines(_pack_frame(header, req.get("data")))
+            await conn.drain()
             # Client side only: the peer sends nothing before it has the
             # whole answer, so the wait for the header is its share of the
             # call and the payload is the wire's and this loop's.
             with telemetry.span("blockport.wait_header", method=method,
                                 addr=hostport) as waited:
-                resp, plen = await _read_header(r)
+                resp, plen = await _read_header(conn)
                 waited.set(bytes=plen)
             with telemetry.span("blockport.recv_payload", method=method,
-                                addr=hostport, bytes=plen):
-                payload = await _read_payload(r, resp, plen, payload_into)
+                                addr=hostport, bytes=plen) as received:
+                payload, direct = await conn.read_payload(resp, plen,
+                                                          payload_into)
+                received.set(direct=direct)
         except BaseException:
-            w.close()
+            conn.close()
             raise
+        self.rx_direct_bytes += direct
+        self.rx_buffered_bytes += plen - direct
         self._release(hostport, conn)
         has_data = resp.pop("_d", 0)
         if not resp.pop("ok", False):
@@ -728,6 +941,6 @@ class BlockConnPool:
 
     async def close(self) -> None:
         for conns in self._free.values():
-            for _r, w in conns:
-                w.close()
+            for conn in conns:
+                conn.close()
         self._free.clear()

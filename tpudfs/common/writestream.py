@@ -115,9 +115,9 @@ def _raise_error_frame(header: dict) -> None:
     raise RpcError(code, message)
 
 
-async def send_block_stream(r: asyncio.StreamReader, w: asyncio.StreamWriter,
-                            begin: dict, data) -> dict:
-    """Client-side sender over an open blockport connection.
+async def send_block_stream(r, w, begin: dict, data) -> dict:
+    """Client-side sender over an open blockport connection: ``r`` and
+    ``w`` are both the pool's ``BlockConn`` (or a stream pair).
 
     Sends the begin frame, waits for ready, pipelines the data frames
     while a concurrent reader task folds watermark acks (max-merge), and
@@ -203,7 +203,8 @@ class ForwardStream:
     fan each verified frame out to the next chain hop before the local
     disk append — the native engine does the same in C++."""
 
-    def __init__(self, r: asyncio.StreamReader, w: asyncio.StreamWriter):
+    def __init__(self, r, w):
+        #: both the pool's BlockConn (BlockConnPool.stream_checkout).
         self.r = r
         self.w = w
         self.ok = False

@@ -477,6 +477,7 @@ class ReadCombiner:
             segs = []
             oks = []
             covered = 0
+            run_start, run_end = 0, -1  # the last segment's span of flat
             for i, r in enumerate(reqs):
                 sz = sizes[i]
                 if sz is None or sz < 0:
@@ -487,12 +488,22 @@ class ReadCombiner:
                     # Untrusted header sizes: never allocate past the
                     # framed payload (a desynced peer could claim TiB).
                     return None
-                if sz == r.size:
-                    segs.append(flat[i * stride : i * stride + sz])
-                    oks.append(True)
-                else:
+                if sz != r.size:
                     segs.append(np.empty(sz, dtype=np.uint8))  # drain
                     oks.append(False)
+                    run_end = -1
+                    continue
+                start = i * stride
+                if start == run_end:
+                    # Payload-adjacent AND buffer-adjacent (the slot
+                    # before was full): one segment, so the transport
+                    # receives the whole run with no cut at the seam.
+                    segs[-1] = flat[run_start : start + sz]
+                else:
+                    run_start = start
+                    segs.append(flat[start : start + sz])
+                run_end = start + sz
+                oks.append(True)
             if covered != plen:
                 return None  # inconsistent frame: let readexactly handle
             scatter_ok = oks
