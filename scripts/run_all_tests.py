@@ -197,13 +197,13 @@ def main() -> None:
 
     run("lint (compile gate)", [
         sys.executable, "-m", "compileall", "-q",
-        "tpudfs", "tests", "scripts", "bench.py", "__graft_entry__.py",
+        "tpudfs", "tests", "scripts", "__graft_entry__.py",
     ])
     # tpulint: the distributed-systems-aware static analysis gate. Runs
     # BEFORE pytest so an event-loop stall or unverified read path fails
     # fast, with file:line output, instead of as a flaky live-cluster tier.
     # The SARIF artifact makes lint results diffable across CI runs (and
-    # loadable in code-scanning viewers) the same way BENCH_*.json is.
+    # loadable in code-scanning viewers).
     run("lint (tpulint static analysis)",
         [sys.executable, "-m", "tpudfs.analysis"])
     run("lint (tpulint.sarif artifact)",

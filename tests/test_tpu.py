@@ -880,47 +880,62 @@ async def test_hbm_reader_retries_corrupt_local_replica_lazy(tmp_path):
         await c.stop()
 
 
-# ------------------------------------------------- warm infeed fast path
+# ------------------------------------------ sweep over cached metadata
 
 
-async def test_read_meta_blocks_fast_roundtrip(tmp_path):
-    """Cached-meta fast path: after one normal read primes the local-store
-    probes, read_meta_blocks_fast returns verified blocks with no master
-    round-trip, bit-identical to the file."""
+async def _sweep_cached_meta(client, reader, path):
+    """One sweep of ``path`` from metadata fetched beforehand, with the
+    master's metadata call forbidden while it runs."""
+    meta = await client.get_file_info(path)
+
+    async def no_master(_path):
+        raise AssertionError("a sweep over cached metadata asked the master")
+
+    real, client.get_file_info = client.get_file_info, no_master
+    try:
+        return await reader.sweep_metas_to_device([meta])
+    finally:
+        client.get_file_info = real
+
+
+async def test_sweep_metas_roundtrip(tmp_path):
+    """Cached-meta sweep: after one normal read primes the local-store
+    probes, sweep_metas_to_device returns blocks verified on arrival with
+    no master round-trip, bit-identical to the file."""
     data = _rand(6 * 64 * 1024, seed=30)
     c, client = await _cluster_with_files(tmp_path, [("/wf/a", data)])
     try:
+        client.local_reads = True
         reader = HbmReader(client, jax.devices()[:1])
-        meta = await client.get_file_info("/wf/a")
         prime = await reader.read_file_to_device_blocks("/wf/a",
                                                         verify="lazy")
         await reader.confirm(prime)
         before = client.local_read_blocks
-        blocks = await reader.read_meta_blocks_fast(meta)
-        await reader.confirm(blocks)
+        blocks = await _sweep_cached_meta(client, reader, "/wf/a")
         assert all(b.verified for b in blocks)
+        assert reader.sweep_blocks == len(blocks) == 6
         got = b"".join(device_array_to_bytes(b.array, b.size) for b in blocks)
         assert got == data
-        # the fast path bypasses client._read_local (no counter bump) but
-        # must not have gone to the master or chunkserver RPCs either
+        # the pump preads the replicas itself: no client._read_local (no
+        # counter bump), no master, no chunkserver RPC
         assert client.local_read_blocks == before
     finally:
         await c.stop()
 
 
-async def test_read_meta_blocks_fast_rot_failover(tmp_path):
-    """Bit-rot under the fast path resolves through the confirm retry."""
+async def test_sweep_metas_rot_failover(tmp_path):
+    """Bit-rot under the sweep fails the pump's CRC check and resolves
+    through the per-block fallback."""
     data = _rand(16 * 512, seed=31)
     c, client = await _cluster_with_files(tmp_path, [("/wf/b", data)])
     try:
+        client.local_reads = True
         reader = HbmReader(client, jax.devices()[:1])
-        meta = await client.get_file_info("/wf/b")
         prime = await reader.read_file_to_device_blocks("/wf/b",
                                                         verify="lazy")
         await reader.confirm(prime)
         await _corrupt_first_replica(c, client, "/wf/b")
-        blocks = await reader.read_meta_blocks_fast(meta)
-        await reader.confirm(blocks)
+        blocks = await _sweep_cached_meta(client, reader, "/wf/b")
         assert all(b.verified for b in blocks)
         got = b"".join(device_array_to_bytes(b.array, b.size) for b in blocks)
         assert got == data
@@ -928,22 +943,21 @@ async def test_read_meta_blocks_fast_rot_failover(tmp_path):
         await c.stop()
 
 
-async def test_read_meta_blocks_fast_tail_rot_failover(tmp_path):
-    """A NON-512-aligned (tail) block verifies eagerly even under lazy
-    mode; rot in the colocated replica must fall back through the general
-    path's retry instead of failing the sweep."""
+async def test_sweep_metas_tail_rot_failover(tmp_path):
+    """A NON-512-aligned (tail) block never rides the pump; rot in its
+    colocated replica must fall back through the general path's retry
+    instead of failing the sweep."""
     data = _rand(5 * 512 + 100, seed=32)  # single unaligned block
     c, client = await _cluster_with_files(tmp_path, [("/wf/c", data)])
     try:
+        client.local_reads = True
         reader = HbmReader(client, jax.devices()[:1])
-        meta = await client.get_file_info("/wf/c")
         prime = await reader.read_file_to_device_blocks("/wf/c",
                                                         verify="lazy")
         await reader.confirm(prime)
         await _corrupt_first_replica(c, client, "/wf/c")
-        blocks = await reader.read_meta_blocks_fast(meta)
-        await reader.confirm(blocks)
-        assert all(b.verified for b in blocks)
+        blocks = await _sweep_cached_meta(client, reader, "/wf/c")
+        assert all(b.verified for b in blocks) and not reader.sweep_blocks
         got = b"".join(device_array_to_bytes(b.array, b.size) for b in blocks)
         assert got == data
     finally:
@@ -1146,9 +1160,9 @@ async def test_fused_read_buffer_pool_reuse(tmp_path):
 async def test_fused_read_held_blocks_survive_buffer_recycle(tmp_path):
     """Device blocks from round 1 are HELD while round 2 refills the
     recycled host buffer, then read back — catches any backend where
-    device_put aliases (rather than copies) the pooled numpy buffer.
-    (ADVICE r4: the previous pool-reuse test never held device arrays
-    across a reuse, so zero-copy aliasing would have passed it.)"""
+    device_put aliases (rather than copies) the pooled numpy buffer (a
+    pool-reuse test that holds no device array across a reuse would pass
+    zero-copy aliasing)."""
     d1 = _rand(4 * 64 * 1024, seed=57)
     d2 = _rand(4 * 64 * 1024, seed=58)
     c, client = await _cluster_with_files(
@@ -1240,21 +1254,20 @@ async def test_fused_read_buffer_recycle_rounds_out_of_order(tmp_path,
 def test_combiner_pool_buffers_defeat_zero_copy_aliasing():
     """PJRT's CPU client zero-copy-aliases 64-byte-aligned host buffers
     (measured on this image) — an aliased device array references pooled
-    memory forever, so a recycled buffer would corrupt held blocks. The
-    combiner defends by (a) misaligning every pool buffer to ptr%64==4
-    and (b) probing that exact allocation pattern at init, disabling
-    pooling if a future jaxlib aliases anyway."""
-    from tpudfs.tpu.read_combiner import ReadCombiner
+    memory forever, so a recycled buffer would corrupt held blocks.
+    host_buffers defends by (a) misaligning every buffer to ptr%64==4 and
+    (b) probing that exact allocation pattern once, recycling nothing if a
+    future jaxlib aliases anyway."""
+    from tpudfs.tpu import host_buffers
 
     dev = jax.devices("cpu")[0]
-    comb = ReadCombiner(None, dev)
-    assert comb._cpu_copies is True and comb._pooling_ok is True
-    buf = comb._alloc_round_buf(512)
+    assert host_buffers.may_recycle(dev) is True
+    buf = host_buffers.alloc(dev, 256 << 10)
     assert buf.ctypes.data % 64 == 4, "pool buffer not misaligned"
     # The probe is live, not vacuous: mutating the misaligned source must
     # leave the device copy intact (the aligned twin aliases on this
-    # jaxlib, which is exactly why _alloc_round_buf misaligns).
-    assert comb._probe_pool_copy_semantics() is True
+    # jaxlib, which is exactly why alloc misaligns).
+    assert host_buffers._device_put_copies(dev) is True
 
 
 async def test_fused_read_host_verify_falls_back_on_rot(tmp_path):
@@ -1312,9 +1325,8 @@ async def test_fused_read_mixed_block_sizes(tmp_path):
         prime = await reader.read_file_to_device_blocks("/fu/mix",
                                                         verify="lazy")
         await reader.confirm(prime)
-        blocks = await reader.read_meta_blocks_fast(
-            await client.get_file_info("/fu/mix"), reader.devices[0])
-        await reader.confirm(blocks)
+        blocks = await reader.sweep_metas_to_device(
+            [await client.get_file_info("/fu/mix")], reader.devices[0])
         assert all(b.verified for b in blocks)
         got = b"".join(device_array_to_bytes(b.array, b.size)
                        for b in blocks)
@@ -1749,5 +1761,98 @@ async def test_sweep_pump_corruption_falls_back_and_recovers(tmp_path):
             device_array_to_bytes(b.array, m["size"])
             for b, m in zip(blocks, meta["blocks"]))
         assert got == data
+    finally:
+        await c.stop()
+
+
+# ------------------------------- one rule each, one fallback, arrows one way
+
+
+def test_read_path_leaves_import_nothing_above_them():
+    """device_block, host_buffers and read_combiner load without the module
+    above them: no import of hbm_reader, at the top or inside a function
+    that loading runs."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "import tpudfs.tpu.device_block, tpudfs.tpu.host_buffers\n"
+        "import tpudfs.tpu.read_combiner\n"
+        "assert 'tpudfs.tpu.hbm_reader' not in sys.modules, 'cycle'\n"
+    )
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120,
+                   env={**os.environ, "JAX_PLATFORMS": "cpu"})
+
+
+@pytest.mark.parametrize("block, fuses", [
+    ({"size": 4096, "checksum_crc32c": 7, "ec_data_shards": 6}, False),
+    ({"size": 4096, "checksum_crc32c": 0}, False),
+    ({"size": 0, "checksum_crc32c": 7}, False),
+    ({"size": 4096 + 100, "checksum_crc32c": 7}, False),
+    ({"size": 4096, "checksum_crc32c": 7}, True),
+], ids=["ec", "no-crc", "empty", "unaligned-tail", "aligned"])
+def test_may_fuse(block, fuses):
+    from tpudfs.tpu.read_combiner import chunk_aligned, may_fuse
+
+    assert may_fuse(block) is fuses
+    assert chunk_aligned(block["size"]) is (block["size"] % 512 == 0)
+
+
+def _without_native_library(monkeypatch):
+    from tpudfs.common import native
+
+    monkeypatch.setattr(native, "get_lib", lambda: None)
+
+
+async def test_sweep_without_pump_serves_every_block_per_block(
+        tmp_path, monkeypatch):
+    """No native library: every entry of the sweep is a fallback entry, and
+    the per-block path returns them verified, in (file, block) order."""
+    from tpudfs.common import telemetry
+
+    files = [(f"/np/f{i}", _rand(3 * 64 * 1024, seed=90 + i))
+             for i in range(3)]
+    c, client = await _cluster_with_files(tmp_path, files)
+    try:
+        client.local_reads = True
+        reader = HbmReader(client, jax.devices()[:1])
+        metas = [await client.get_file_info(p) for p, _ in files]
+        _without_native_library(monkeypatch)
+        telemetry.enable()
+        try:
+            blocks = await reader.sweep_metas_to_device(metas)
+        finally:
+            telemetry.disable()
+            records = telemetry.drain()
+        assert [b.block_id for b in blocks] == \
+            [b["block_id"] for m in metas for b in m["blocks"]]
+        assert all(b.verified for b in blocks) and reader.sweep_blocks == 0
+        fallbacks = [r.attrs for r in records if r.name == "sweep.fallback"]
+        assert fallbacks == [{"blocks": 9}]
+        got = b"".join(device_array_to_bytes(b.array, b.size) for b in blocks)
+        assert got == b"".join(d for _, d in files)
+    finally:
+        await c.stop()
+
+
+async def test_fused_local_round_without_library_falls_back(tmp_path,
+                                                            monkeypatch):
+    """A local round with no native library to pread it falls back whole,
+    as a failed remote frame does; the file still reads bit-exactly."""
+    data = _rand(4 * 64 * 1024, seed=95)
+    c, client = await _cluster_with_files(tmp_path, [("/np/fu", data)])
+    try:
+        reader, comb = await _batched_reader(client, False)
+        prime = await reader.read_file_to_device_blocks("/np/fu",
+                                                        verify="lazy")
+        await reader.confirm(prime)
+        assert comb.blocks >= 1, "combiner never engaged"
+        served = comb.blocks
+        _without_native_library(monkeypatch)
+        blocks = await reader.read_file_to_device_blocks("/np/fu",
+                                                         verify="lazy")
+        assert comb.blocks == served, "a round fused without the library"
+        assert await _confirmed_bytes(reader, blocks) == data
     finally:
         await c.stop()

@@ -121,14 +121,20 @@ ROUTES: tuple[RouteSpec, ...] = (
             r"tpudfs\.tpu\.hbm_reader\.HbmReader\.sweep_metas_to_device",
             r"tpudfs\.tpu\.read_combiner\.ReadCombiner\._fetch_remote",
             r"tpudfs\.chunkserver\.service\.ChunkServer\.rpc_read_blocks",
+            # The one per-block fallback of the sweep and of the rounds.
+            r"tpudfs\.tpu\.hbm_reader\.HbmReader\.read_block_to_device",
         ),
         modules=(
             "tpudfs/tpu/hbm_reader.py",
             "tpudfs/tpu/read_combiner.py",
+            "tpudfs/tpu/device_block.py",
+            "tpudfs/tpu/host_buffers.py",
             "tpudfs/chunkserver/service.py",
             "tpudfs/common/blocknet.py",
             "tpudfs/chunkserver/blockstore.py",
         ),
+        # As on the cache-hit route: the EC read is the EC route's budget.
+        exclude=(r".*\._ec_block_to_device(\..*)?",),
     ),
     RouteSpec(
         name="cache_hit_read",

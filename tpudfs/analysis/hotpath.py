@@ -46,10 +46,10 @@ __all__ = ["HotPaths", "hot_paths", "loop_depth_at"]
 #: loop-in-loop-in-loop chain from dominating every report.
 _DEPTH_CAP = 4
 
-#: Qualname patterns of the data-plane roots. These mirror what bench.py
-#: drives (bench itself lives outside the linted tree): every scenario
-#: enters through the client bulk API or the infeed, which fan out to
-#: the transports and chunkserver handlers below.
+#: Qualname patterns of the data-plane roots: what the benchmark's traffic
+#: (benchmarks/, outside the linted tree) enters through, the client bulk
+#: API and the readers into HBM, and the transports and chunkserver
+#: handlers those fan out to.
 _ROOT_PATTERNS = [
     # Block transport: the per-frame serve loop and the client pool call.
     r"^tpudfs\.common\.blocknet\.BlockPortServer\._handle$",
@@ -70,6 +70,8 @@ _ROOT_PATTERNS = [
     r"_ClientLoop)\.\w+$",
     r"^tpudfs\.tpu\.hbm_reader\.HbmReader\.\w+$",
     r"^tpudfs\.tpu\.read_combiner\.ReadCombiner\.\w+$",
+    r"^tpudfs\.tpu\.device_block\.(DeviceBlock|DeviceBatch)\.\w+$",
+    r"^tpudfs\.tpu\.host_buffers\.\w+$",
     r"^tpudfs\.tpu\.write_group\.IciWriteGroup\.\w+$",
 ]
 

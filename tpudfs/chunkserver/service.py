@@ -272,8 +272,8 @@ class ChunkServer:
         self.committer = GroupCommitter(store)
         #: Streamed-write per-stage occupancy (ns totals + counts) on the
         #: asyncio fallback path; the native engine keeps its own twin
-        #: (tpudfs_dataplane_stream_stats). ``bench.py --write-stages``
-        #: reads whichever plane served the stream via Stats.
+        #: (tpudfs_dataplane_stream_stats). ``Stats`` reports whichever
+        #: plane served the stream (``stream_stage_stats``).
         self._stream_stats = dict.fromkeys(
             ("net_ns", "crc_ns", "disk_ns", "fanout_ns",
              "frames", "streams", "stream_bytes", "aborts"), 0)
@@ -1384,8 +1384,8 @@ class ChunkServer:
 
     def stream_stage_stats(self) -> dict:
         """Per-stage occupancy of the streaming write pipeline (net/crc/
-        disk/fanout ns plus frame/stream/abort counts) — the localizer
-        for future write regressions (``bench.py --write-stages``).
+        disk/fanout ns plus frame/stream/abort counts), ``Stats``'
+        ``stream_stages`` — the localizer for write regressions.
         Sums the asyncio fallback's counters with the native engine's."""
         out = dict(self._stream_stats)
         if self._native_dp is not None:

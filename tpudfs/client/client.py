@@ -65,6 +65,9 @@ REFUSED_TTL = 3.0
 MASTER = "MasterService"
 CS = "ChunkServerService"
 
+#: ``Client.local_replica_nowait``'s answer when no cached verdict settles it.
+PROBE_DUE = object()
+
 
 class DfsError(Exception):
     pass
@@ -210,24 +213,30 @@ class Client:
     def _dial(self, addr: str) -> str:
         return self.host_aliases.get(addr, addr)
 
-    async def _local_store(self, addr: str):
-        """BlockStore reader for ``addr`` if it shares our filesystem, else
-        None (cached either way)."""
-        if not self.local_reads:
-            return None
+    def _local_store_nowait(self, addr: str):
+        """``addr``'s cached verdict: its BlockStore, None (it does not share
+        our filesystem, or its failed probe is not due again yet), or
+        ``PROBE_DUE``."""
         cached = self._local_stores.get(addr)
         if cached is not None:
             store, retry_at = cached
             if store is not None or retry_at is None or \
                     asyncio.get_running_loop().time() < retry_at:
                 return store
+        return PROBE_DUE
+
+    async def _local_store(self, addr: str):
+        """BlockStore reader for ``addr`` if it shares our filesystem, else
+        None (cached either way)."""
+        if not self.local_reads:
+            return None
+        store = self._local_store_nowait(addr)
+        if store is not PROBE_DUE:
+            return store
         async with self._local_probe_lock:  # no handshake stampede
-            cached = self._local_stores.get(addr)
-            if cached is not None:
-                store, retry_at = cached
-                if store is not None or retry_at is None or \
-                        asyncio.get_running_loop().time() < retry_at:
-                    return store
+            store = self._local_store_nowait(addr)
+            if store is not PROBE_DUE:
+                return store
             store = None
             retry_at = None
             try:
@@ -268,6 +277,34 @@ class Client:
             # A conclusive probe (shared or not) is cached permanently.
             self._local_stores[addr] = (store, retry_at)
             return store
+
+    def local_replica_nowait(self, block: dict):
+        """:meth:`local_replica` from verdicts already cached, without a
+        suspension point: the store, None, or :data:`PROBE_DUE` when a
+        location has to be probed first (then await ``local_replica``)."""
+        if not self.local_reads:
+            return None
+        for addr in block.get("locations") or []:
+            if addr:
+                store = self._local_store_nowait(addr)
+                if store is not None:
+                    return store
+        return None
+
+    async def local_replica(self, block: dict):
+        """The one answer to "where is this block's colocated replica":
+        the BlockStore of the first of its locations that shares our
+        filesystem, or None (no such location, or ``local_reads`` is off).
+        Probes a location once; the verdict is cached."""
+        store = self.local_replica_nowait(block)
+        if store is not PROBE_DUE:
+            return store
+        for addr in block.get("locations") or []:
+            if addr:
+                store = await self._local_store(addr)
+                if store is not None:
+                    return store
+        return None
 
     async def _read_local(self, addr: str, block_id: str, offset: int,
                           length: int, verify: bool = True) -> bytes | None:

@@ -1,7 +1,7 @@
 """Subprocess helpers for multi-process cluster harnesses.
 
-Shared by scripts/start_cluster.py and bench.py (the reference drives the
-same need with start_cluster.sh + docker-compose): spawn service entry
+Shared by scripts/start_cluster.py and chip_smoke.py (the reference drives
+the same need with start_cluster.sh + docker-compose): spawn service entry
 points as real OS processes, redirect their output to per-process logs, and
 poll for the ``READY <addr>`` line each tpudfs ``__main__`` prints once its
 sockets are bound.

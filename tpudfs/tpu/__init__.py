@@ -13,7 +13,7 @@ def on_tpu() -> bool:
 
 def place_compile_cache() -> str:
     """Give JAX's persistent compilation cache its one fixed place and
-    return it. Entry points (chip_smoke.py, bench.py, __graft_entry__.py)
+    return it. Entry points (chip_smoke.py, benchmarks/run.py, __graft_entry__.py)
     call this before their first compile.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import asyncio
 import contextvars
+import ctypes
 import logging
 from concurrent.futures import ThreadPoolExecutor
 
@@ -24,15 +25,29 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpudfs.client.client import ChecksumMismatchError, Client, DfsError
-from tpudfs.common import telemetry
+from tpudfs.client.client import (
+    PROBE_DUE,
+    ChecksumMismatchError,
+    Client,
+    DfsError,
+)
+from tpudfs.common import native, telemetry
 from tpudfs.common.checksum import CHECKSUM_CHUNK_SIZE, crc32c_combine
+from tpudfs.tpu import host_buffers
 from tpudfs.tpu.crc32c_pallas import (
     WORDS_PER_CHUNK,
     block_crc_device,
     bytes_to_words,
     crc32c_chunks_device,
 )
+from tpudfs.tpu.device_block import (
+    DeviceBatch,
+    DeviceBlock,
+    device_array_to_bytes,
+)
+from tpudfs.tpu.read_combiner import ReadCombiner, chunk_aligned, may_fuse
+
+__all__ = ["DeviceBlock", "HbmReader", "device_array_to_bytes"]
 
 logger = logging.getLogger(__name__)
 
@@ -43,68 +58,6 @@ logger = logging.getLogger(__name__)
 #: connections (on the v5e's host 4 read 0.263 GB/s, 8 0.284, 16 0.275,
 #: 32 0.19-0.21, 64 0.153: PERF.md, PR 27).
 EC_BLOCKS_IN_FLIGHT = 8
-
-
-class DeviceBlock:
-    """One block's words on one device — either its own (chunks, 128) array
-    or a slice-on-demand view into a fused :class:`~tpudfs.tpu.read_combiner.
-    DeviceBatch` (the batched read path). ``pending_crc``/``batch_pending``
-    mark lazy verification: the 0-d (or batch-vector) on-device CRC fold is
-    resolved against ``expected_crc`` by :meth:`HbmReader.confirm` with ONE
-    host sync per confirm call. The comparison happens on the HOST — an
-    eager per-block ``== expected`` would upload a scalar and sync the host
-    once per block instead of once per batch."""
-
-    def __init__(self, block_id: str, array: jax.Array | None, size: int,
-                 verified: bool, *, pending_crc: jax.Array | None = None,
-                 expected_crc: int | None = None, source: dict | None = None,
-                 device: object | None = None, batch=None,
-                 batch_index: int = 0, batch_pending: bool = False):
-        self.block_id = block_id
-        self._array = array
-        self.size = size  # unpadded byte length
-        self.verified = verified
-        self.pending_crc = pending_crc
-        self.expected_crc = expected_crc
-        #: source block metadata + target device, kept so a failed lazy
-        #: verify can be retried through the host-verified fetch path.
-        self.source = source
-        self.device = device
-        #: fused-round fields (read_combiner): the DeviceBatch this block
-        #: rides in, its row index there, and whether its verdict is still
-        #: unresolved in the batch's (n,) CRC vector.
-        self.batch = batch
-        self.batch_index = batch_index
-        self.batch_pending = batch_pending
-
-    @property
-    def array(self) -> jax.Array:
-        """(chunks, 128) uint32 words. Batched blocks materialize their
-        slice of the round lazily — slicing dispatches a device op, so the
-        hot infeed path synchronizes on :attr:`sync_arrays` instead and
-        only consumers that need per-block arrays pay for the slice."""
-        if self._array is None and self.batch is not None:
-            self._array = self.batch.block_words(self.batch_index)
-        return self._array
-
-    @array.setter
-    def array(self, value: jax.Array) -> None:
-        self._array = value
-        self.batch = None
-
-    @property
-    def sync_arrays(self) -> list:
-        """Device values a completion wait must cover for this block —
-        WITHOUT materializing per-block slices of a fused batch."""
-        if self.batch is not None and self._array is None:
-            out = [self.batch.words]
-            if self.batch.crcs is not None:
-                out.append(self.batch.crcs)
-            return out
-        out = [self._array]
-        if self.pending_crc is not None:
-            out.append(self.pending_crc)
-        return out
 
 
 class HbmReader:
@@ -133,8 +86,6 @@ class HbmReader:
     def _combiner(self, device):
         c = self._combiners.get(device)
         if c is None:
-            from tpudfs.tpu.read_combiner import ReadCombiner
-
             c = ReadCombiner(self.client, device, max_batch=self.batch_reads)
             self._combiners[device] = c
         return c
@@ -380,7 +331,7 @@ class HbmReader:
         expected: int | None = None
         if verify and block.get("checksum_crc32c"):
             expected = int(block["checksum_crc32c"])
-            if size % CHECKSUM_CHUNK_SIZE == 0:
+            if chunk_aligned(size):
                 # Device fold: whole-block CRC without any chunk readback
                 # (and no host->device scalar upload — compare on host).
                 crc = block_crc_device(words)
@@ -554,63 +505,6 @@ class HbmReader:
             crc = crc32c_combine(crc, crc32c(tail), tail_len)
         return crc == expected_crc
 
-    # ---------------------------------------------------- warm infeed sweep
-
-    async def read_meta_blocks_fast(
-        self, meta: dict, device=None, verify: bool | str = "lazy",
-    ) -> list[DeviceBlock]:
-        """Steady-state infeed fast path: CACHED file metadata (no master
-        round-trip — the immutable block layout is fetched once, exactly as
-        the grain infeed does via read_meta_range) and, where a block's
-        replica is behind an already-probed local store, fetch + upload in
-        ONE worker-thread hop (pread → bytes_to_words view → device_put)
-        instead of two. Falls back to the general path per block. Returns
-        lazy-verified DeviceBlocks; resolve with ``confirm``."""
-        device = device or self.devices[0]
-
-        async def fast_or_slow(block: dict) -> DeviceBlock:
-            db = await self._try_batched(block, device, verify)
-            if db is not None:
-                return db
-            store = None
-            if self.client.local_reads and not block.get("ec_data_shards"):
-                for addr in block.get("locations") or []:
-                    cached = self.client._local_stores.get(addr)
-                    if cached and cached[0] is not None:
-                        store = cached[0]
-                        break
-            device_verify = bool(verify) and bool(block.get("checksum_crc32c"))
-            if store is None or not device_verify:
-                return await self.read_block_to_device(block, device,
-                                                       verify=verify)
-
-            def fetch_put():
-                data = store.read(block["block_id"])
-                return jax.device_put(bytes_to_words(data), device), len(data)
-
-            try:
-                words, size = await asyncio.to_thread(fetch_put)
-                # _finish_block verifies eagerly for tail (non-512-aligned)
-                # blocks even under verify="lazy" — its DfsError must fall
-                # back too, or one rotten tail block fails the whole sweep
-                # that the general path would have recovered.
-                db = await self._finish_block(block, words, size, verify)
-            except Exception:
-                # Tiering move / stale location / rot: the general path
-                # handles probing, RPC fallback, and corruption retry.
-                logger.debug("local fast-path read of block %s failed; "
-                             "retrying via general path",
-                             block.get("block_id"), exc_info=True)
-                return await self.read_block_to_device(block, device,
-                                                       verify=verify)
-            db.source = block
-            db.device = device
-            return db
-
-        return list(await asyncio.gather(
-            *(fast_or_slow(b) for b in meta["blocks"])
-        ))
-
     # ---------------------------------------------------- native sweep pump
 
     async def sweep_metas_to_device(self, metas: list[dict], device=None, *,
@@ -625,17 +519,16 @@ class HbmReader:
         already satisfied), one vectorized verify, one device_put, one
         release. No per-block futures, no executor hops, no staging.
 
-        Blocks that don't qualify (EC, remote-only replica, unaligned
-        tail, CRC mismatch, short read) fall back to the general per-
-        block path — identical recovery semantics. Returns DeviceBlocks
+        Blocks that don't qualify (``may_fuse``; remote-only replica, CRC
+        mismatch, short read), and every block where the library has no
+        pump or the backend aliases host buffers, fall back to the general
+        per-block path — identical recovery semantics. Returns DeviceBlocks
         flattened in (file, block) order, HOST-verified (the pump checks
         the recorded whole-block CRC; nothing pending for confirm).
 
-        TPU note: round buffers are recycled, so on accelerators each
-        buffer's device_put completes (block_until_ready) before its
-        round is released — ring depth keeps the producer ahead anyway.
-        The CPU backend's copies are synchronous-by-probe (see
-        read_combiner's aliasing notes; buffers come misaligned)."""
+        The ring's buffers are recycled under host_buffers' reuse rule:
+        a round's device_put completes before its buffer is released to
+        the producer — ring depth keeps the producer ahead anyway."""
         with telemetry.span("hbm.sweep") as whole:
             out = await self._sweep_metas(metas, device, round_blocks, ring)
             whole.set(blocks=len(out))
@@ -646,44 +539,27 @@ class HbmReader:
         """The sweep itself, inside its caller's ``hbm.sweep`` span (one
         per public call, the metadata fan-out inside it when the caller
         came by paths)."""
-        import ctypes
-
-        from tpudfs.common import native
-        from tpudfs.tpu.read_combiner import DeviceBatch, alloc_misaligned_u8
-
         device = device or self.devices[0]
         lib = native.get_lib()
-        if lib is None or not hasattr(lib, "tpudfs_sweep_start"):
-            out = await asyncio.gather(
-                *(self.read_meta_blocks_fast(m, device) for m in metas))
-            return [b for bs in out for b in bs]
+        # Without a pump in the library, or on a backend whose device
+        # arrays alias the ring (no completion wait makes refilling it
+        # safe), every block is a fallback entry.
+        pump = (lib is not None and hasattr(lib, "tpudfs_sweep_start")
+                and host_buffers.may_recycle(device))
 
         # ---- eligibility + local path resolution (meta order preserved)
         entries: list = []   # (slot_index | None, block) per (file, block)
         paths: list[bytes] = []
         expected_sizes: list[int] = []
         expected_crcs: list[int] = []
-        stores: dict[str, object] = {}  # addr -> store|None, sweep-local
         resolving = telemetry.span("sweep.resolve")
         for meta in metas:
             for block in meta["blocks"]:
-                size = int(block.get("size") or 0)
                 store = None
-                if (self.client.local_reads
-                        and not block.get("ec_data_shards")
-                        and block.get("checksum_crc32c")
-                        and size > 0 and size % CHECKSUM_CHUNK_SIZE == 0):
-                    for addr in block.get("locations") or []:
-                        if not addr:
-                            continue
-                        if addr in stores:
-                            s = stores[addr]
-                        else:
-                            s = await self.client._local_store(addr)
-                            stores[addr] = s
-                        if s is not None:
-                            store = s
-                            break
+                if pump and may_fuse(block):
+                    store = self.client.local_replica_nowait(block)
+                    if store is PROBE_DUE:
+                        store = await self.client.local_replica(block)
                 if store is None:
                     entries.append((None, block))
                     continue
@@ -696,7 +572,7 @@ class HbmReader:
                     continue
                 entries.append((len(paths), block))
                 paths.append(bpath.encode())
-                expected_sizes.append(size)
+                expected_sizes.append(int(block["size"]))
                 expected_crcs.append(int(block["checksum_crc32c"]))
         resolving.end(blocks=len(entries))
 
@@ -708,25 +584,8 @@ class HbmReader:
             stride = max(expected_sizes)
             stride = -(-stride // CHECKSUM_CHUNK_SIZE) * CHECKSUM_CHUNK_SIZE
             spb = stride // CHECKSUM_CHUNK_SIZE  # slot rows
-            is_cpu = getattr(device, "platform", "cpu") == "cpu"
-            cpu_copies = is_cpu and self._cpu_copies(device)
-            if is_cpu and not cpu_copies:
-                # Same defense as the combiner's pool: if the probe says
-                # this CPU backend may ALIAS our (misaligned) buffers, no
-                # completion wait makes ring recycling safe — an aliased
-                # device array references the buffer forever. Serve the
-                # whole sweep through the per-block path instead.
-                out = await asyncio.gather(
-                    *(self.read_meta_blocks_fast(m, device)
-                      for m in metas))
-                return [b for bs in out for b in bs]
-            round_bytes = round_blocks * stride
-            if is_cpu:
-                bufs = [alloc_misaligned_u8(round_bytes)
-                        for _ in range(ring)]
-            else:
-                bufs = [np.empty(round_bytes, dtype=np.uint8)
-                        for _ in range(ring)]
+            bufs = [host_buffers.alloc(device, round_blocks * stride)
+                    for _ in range(ring)]
             buf_words = [b.view("<u4").reshape(-1, WORDS_PER_CHUNK)
                          for b in bufs]
             sizes = np.zeros(n, dtype=np.int64)
@@ -747,11 +606,8 @@ class HbmReader:
                 for r in range(nrounds):
                     if r >= ring:
                         # Recycled buffer: its device copy must COMPLETE
-                        # before the producer may refill it — on EVERY
-                        # backend. The CPU client copies by completion,
-                        # not at dispatch (measured: mutating the source
-                        # right after device_put corrupts ~15% of 4 MiB
-                        # transfers without this wait).
+                        # before the producer may refill it (host_buffers,
+                        # rule 2).
                         with telemetry.span("sweep.gate", round=r):
                             prev = outstanding[r - ring]
                             if prev is not None:
@@ -809,19 +665,6 @@ class HbmReader:
             with telemetry.span("sweep.fallback", blocks=len(fallback_idx)):
                 await asyncio.gather(*(fb(i) for i in fallback_idx))
         return results
-
-    def _cpu_copies(self, device) -> bool:
-        """Whether device_put COPIES (vs zero-copy-aliases) our misaligned
-        host buffers on this CPU backend — cached probe, shared with the
-        combiner's pool logic. Copy semantics hold by COMPLETION, not at
-        dispatch: recycling still requires block_until_ready first."""
-        cached = getattr(self, "_cpu_copies_probe", None)
-        if cached is None:
-            from tpudfs.tpu.read_combiner import ReadCombiner
-
-            cached = ReadCombiner(None, device)._cpu_copies
-            self._cpu_copies_probe = cached
-        return cached
 
     async def sweep_paths_to_device(self, paths: list[str], device=None, *,
                                     round_blocks: int = 16,
@@ -909,8 +752,3 @@ class HbmReader:
         return jax.make_array_from_single_device_arrays(
             (ndev * per_group * max_chunks, WORDS_PER_CHUNK), sharding, shards
         )
-
-
-def device_array_to_bytes(arr: jax.Array, size: int) -> bytes:
-    """Host copy-out (for tests / CLI): unpad the device words."""
-    return np.asarray(arr).astype("<u4").tobytes()[:size]

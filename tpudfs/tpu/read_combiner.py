@@ -1,13 +1,10 @@
 """Batched DFS→HBM reads: a read-side group commit for the infeed hot path.
 
-Profiling on the CPU backend (scripts/read_profile.py) put the read
-ceiling at per-block host overhead, not device bandwidth: every 1 MiB block
-paid its own ``asyncio.to_thread`` hops, its own ``jax.device_put`` dispatch,
-and its own CRC-kernel launch (per-block cost not measured on the chip
-yet). This module amortizes all three the same way
-``GroupCommitter`` amortizes fsyncs on the write side: concurrent per-file
-readers STAGE block requests, and a two-stage drain pipeline fuses each
-round into
+On the per-block path every 1 MiB block pays its own ``asyncio.to_thread``
+hops, its own ``jax.device_put`` dispatch and its own CRC-kernel launch.
+This module amortizes all three the same way ``GroupCommitter`` amortizes
+fsyncs on the write side: concurrent per-file readers STAGE block requests,
+and a two-stage drain pipeline fuses each round into
 
 1. ONE native multi-block pread into one contiguous host buffer
    (``tpudfs_blocks_read``, native/blockio.cc — GIL released for the whole
@@ -30,9 +27,10 @@ CRC program compiles a handful of times, not once per arrival pattern —
 an unbounded shape family would put a fresh XLA compile (0.5-1 s each on
 the v5e) on the hot path. ``warm()`` pre-compiles every bucket with H2D-only traffic.
 
-Blocks that don't fit the fused path — EC-striped, unchecksummed,
-non-chunk-aligned, no colocated replica, or a short/failed pread (tiering
-move, truncation) — fall back to the caller's general per-block path, which
+Blocks that don't fit the fused path (``may_fuse``: EC-striped,
+unchecksummed, non-chunk-aligned), or whose round failed (no native
+library, a short or failed pread after a tiering move or truncation, a
+failed frame), fall back to the caller's general per-block path, which
 handles RPC fan-out, degraded EC reads, and corruption retry.
 
 Reference parity note: this accelerates the concurrent block fan-out of
@@ -53,7 +51,9 @@ import numpy as np
 
 from tpudfs.common import native, telemetry
 from tpudfs.common.checksum import CHECKSUM_CHUNK_SIZE
+from tpudfs.tpu import host_buffers
 from tpudfs.tpu.crc32c_pallas import WORDS_PER_CHUNK, batch_block_crc_device
+from tpudfs.tpu.device_block import DeviceBatch, DeviceBlock
 
 logger = logging.getLogger(__name__)
 
@@ -70,22 +70,20 @@ MAX_ROUNDS_IN_FLIGHT = 3
 REMOTE_ROUND_BYTES = 48 << 20
 
 
-@dataclass
-class DeviceBatch:
-    """One fused round living on device: ``words`` holds ``nblocks``
-    consecutive blocks of ``cpb`` chunks each; ``crcs`` is the (nblocks,)
-    on-device whole-block CRC fold, resolved lazily (``resolved``) by the
-    reader's batched confirm with one device→host transfer per confirm
-    call covering every batch."""
+def chunk_aligned(size: int) -> bool:
+    """Whole 512-byte checksum chunks: only then does the device fold of
+    the (zero-padded) chunk grid equal the block's recorded CRC."""
+    return size % CHECKSUM_CHUNK_SIZE == 0
 
-    words: jax.Array  # (nblocks * cpb, 128) uint32
-    crcs: jax.Array | None  # (nblocks,) uint32, on device
-    cpb: int
-    nblocks: int
-    resolved: np.ndarray | None = None
 
-    def block_words(self, i: int) -> jax.Array:
-        return self.words[i * self.cpb : (i + 1) * self.cpb]
+def may_fuse(block: dict) -> bool:
+    """The one rule for what may ride a fused round (the combiner's, the
+    sweep's): a replicated block with a recorded whole-block CRC and a
+    non-empty, chunk-aligned size. Everything else reads per block."""
+    size = int(block.get("size") or 0)
+    return bool(not block.get("ec_data_shards")
+                and block.get("checksum_crc32c")
+                and size > 0 and chunk_aligned(size))
 
 
 @dataclass
@@ -110,30 +108,16 @@ def _bucket(n: int, cap: int) -> int:
     return 1 << (n.bit_length() - 1)
 
 
-def alloc_misaligned_u8(nbytes: int) -> np.ndarray:
-    """A uint8 buffer whose data pointer is 64-byte-MISaligned (ptr%64==4)
-    so PJRT's CPU client must COPY it on device_put instead of zero-copy
-    aliasing (which it does for 64-aligned hosts buffers — see
-    ReadCombiner's pool notes). Required for any host buffer that is
-    mutated/recycled after device_put on the CPU backend."""
-    raw = np.empty(nbytes + 68, dtype=np.uint8)
-    off = (4 - raw.ctypes.data) % 64
-    return raw[off : off + nbytes]
-
-
 class ReadCombiner:
     def __init__(self, client, device, *, max_batch: int = DEFAULT_MAX_BATCH,
                  host_verify: bool | None = None):
         self.client = client
         self.device = device
         self.max_batch = max_batch
-        #: Where the whole-block CRC runs. On a real TPU the device fold is
-        #: free for the host (the chip computes it; one batched sync at
-        #: confirm). On the CPU backend "the device" IS the single host
-        #: core, and XLA's 32-pass GF(2) formulation measures ~0.27 GB/s
-        #: there — so the CPU fallback verifies INSIDE the fused native
-        #: read (tpudfs_blocks_read_crc, hardware CRC32C) and blocks arrive
-        #: already verified.
+        #: Where the whole-block CRC runs: on the chip the device folds it
+        #: (one batched sync at confirm); on any other backend the fused
+        #: native read computes it (tpudfs_blocks_read_crc, hardware
+        #: CRC32C) and blocks arrive already verified.
         if host_verify is None:
             host_verify = getattr(device, "platform", "cpu") != "tpu"
         self.host_verify = host_verify
@@ -142,32 +126,8 @@ class ReadCombiner:
         self._upload_task: asyncio.Task | None = None
         self._queue: asyncio.Queue | None = None
         #: Reusable round buffers, keyed by row count (each round's pread
-        #: target is (n*cpb, 128) u32). Fresh 16-32 MiB allocations every
-        #: round cost ~4-8 ms of page faults on a one-core host and keep
-        #: the allocator churning; a recycled buffer's pages stay mapped.
-        #:
-        #: Pooling is only sound if device_put COPIES the host buffer: an
-        #: ALIASED device array references the pooled memory forever, so
-        #: refilling the buffer next round corrupts still-held blocks —
-        #: and no completion wait can help. This image's PJRT CPU client
-        #: really does zero-copy-alias host numpy buffers whose data
-        #: pointer is 64-byte aligned (measured: page+0/+64 alias,
-        #: page+4..+32 copy; allocator luck decided which rounds were
-        #: safe). Defense: on the CPU backend every pool buffer is
-        #: allocated deliberately 64-byte-MISaligned (ptr % 64 == 4) so
-        #: device_put must copy, and an init-time probe of that exact
-        #: allocation pattern disables pooling outright if a future
-        #: jaxlib aliases anyway. Accelerators genuinely copy H2D; their
-        #: release additionally gates on transfer completion.
+        #: target is (n*cpb, 128) u32), under host_buffers' reuse rule.
         self._buf_pool: dict[int, list[np.ndarray]] = {}
-        is_cpu_backend = getattr(device, "platform", "cpu") == "cpu"
-        self._misalign_bufs = is_cpu_backend
-        #: Probe verdict: device_put copies OUR pool buffers. Gates both
-        #: the skip-completion-wait fast path and pooling itself on CPU.
-        self._cpu_copies = (
-            self._probe_pool_copy_semantics() if is_cpu_backend else False
-        )
-        self._pooling_ok = self._cpu_copies if is_cpu_backend else True
         #: rounds fused / blocks served (observability + tests).
         self.rounds = 0
         self.blocks = 0
@@ -175,36 +135,6 @@ class ReadCombiner:
         self.overlapped = 0
         #: rounds the read stage took; the ``round`` of every stage span.
         self._round_seq = 0
-
-    def _alloc_round_buf(self, nrows: int) -> np.ndarray:
-        """One round's pread target. On the CPU backend the data pointer
-        is forced to ptr % 64 == 4 — off PJRT's zero-copy alignment — so
-        device_put copies deterministically. Row stride is 512 bytes, so
-        every sub-round slice stays misaligned too."""
-        nbytes = nrows * WORDS_PER_CHUNK * 4
-        if not self._misalign_bufs:
-            return np.empty((nrows, WORDS_PER_CHUNK), dtype="<u4")
-        return alloc_misaligned_u8(nbytes).view("<u4").reshape(
-            nrows, WORDS_PER_CHUNK
-        )
-
-    def _probe_pool_copy_semantics(self) -> bool:
-        """device_put a real pool-pattern buffer, mutate it, and check the
-        device array kept the original values. False (disables pooling
-        and the skip-wait fast path) if the backend aliased it — or if
-        the probe itself fails."""
-        try:
-            buf = self._alloc_round_buf(512)  # 256 KiB: a real round shape
-            buf[:] = 7
-            dev = jax.device_put(buf, self.device)
-            jax.block_until_ready(dev)
-            buf.reshape(-1)[:] = 0
-            flat = np.asarray(dev).reshape(-1)
-            return bool(flat[0] == 7 and flat[-1] == 7)
-        except Exception:
-            logger.debug("device round-buffer pooling probe failed; "
-                         "falling back to per-read allocs", exc_info=True)
-            return False
 
     #: Every buffer that can be out at once: no round allocates afresh in
     #: steady state.
@@ -214,10 +144,14 @@ class ReadCombiner:
         free = self._buf_pool.get(nrows)
         if free:
             return free.pop()
-        return self._alloc_round_buf(nrows)
+        # Row stride is 512 bytes: every sub-round slice of the buffer
+        # keeps the alignment host_buffers gave it.
+        return host_buffers.alloc(
+            self.device, nrows * CHECKSUM_CHUNK_SIZE
+        ).view("<u4").reshape(nrows, WORDS_PER_CHUNK)
 
     def _put_buf(self, buf: np.ndarray | None) -> None:
-        if buf is None or not self._pooling_ok:
+        if buf is None or not host_buffers.may_recycle(self.device):
             return
         free = self._buf_pool.setdefault(buf.shape[0], [])
         if len(free) < self._POOL_PER_SHAPE:
@@ -232,23 +166,10 @@ class ReadCombiner:
     async def read(self, block: dict):
         """Stage one block; returns a lazily-verified DeviceBlock riding a
         DeviceBatch, or None when the block must take the general path."""
-        size = int(block.get("size") or 0)
-        if (
-            block.get("ec_data_shards")
-            or not block.get("checksum_crc32c")
-            or size <= 0
-            or size % CHECKSUM_CHUNK_SIZE != 0
-        ):
+        if not may_fuse(block):
             return None
-        store = None
-        if self.client.local_reads:
-            for addr in block.get("locations") or []:
-                if not addr:
-                    continue
-                s = await self.client._local_store(addr)
-                if s is not None:
-                    store = s
-                    break
+        size = int(block["size"])
+        store = await self.client.local_replica(block)
         path, remote = "", None
         if store is not None:
             try:
@@ -565,57 +486,34 @@ class ReadCombiner:
         self, reqs: list[_Req], buf: np.ndarray,
     ) -> tuple[list[bool], np.ndarray | None]:
         """Worker thread: pread every request's file into the caller's
-        pooled contiguous (n*cpb, 128) uint32 buffer — native engine when
-        available (one GIL-free call for the whole round), per-file Python
-        otherwise. In ``host_verify`` mode also returns each slot's
-        whole-block CRC (fused into the same native call)."""
+        pooled contiguous (n*cpb, 128) uint32 buffer, one GIL-free native
+        call for the whole round. In ``host_verify`` mode also returns each
+        slot's whole-block CRC (fused into the same call). Without the
+        native library the whole round falls back, as a failed remote
+        frame does."""
         import ctypes
 
-        cpb = reqs[0].cpb
-        stride = cpb * CHECKSUM_CHUNK_SIZE
         lib = native.get_lib()
-        if lib is not None and hasattr(lib, "tpudfs_blocks_read"):
-            paths = (ctypes.c_char_p * len(reqs))(
-                *(r.path.encode() for r in reqs)
+        if lib is None or not hasattr(lib, "tpudfs_blocks_read"):
+            return [False] * len(reqs), None
+        stride = reqs[0].cpb * CHECKSUM_CHUNK_SIZE
+        paths = (ctypes.c_char_p * len(reqs))(
+            *(r.path.encode() for r in reqs)
+        )
+        sizes = np.empty(len(reqs), dtype=np.int64)
+        crcs = None
+        if self.host_verify and hasattr(lib, "tpudfs_blocks_read_crc"):
+            crcs = np.empty(len(reqs), dtype=np.uint32)
+            lib.tpudfs_blocks_read_crc(
+                paths, len(reqs), stride,
+                buf.ctypes.data, sizes.ctypes.data, crcs.ctypes.data,
             )
-            sizes = np.empty(len(reqs), dtype=np.int64)
-            crcs = None
-            if self.host_verify and hasattr(lib, "tpudfs_blocks_read_crc"):
-                crcs = np.empty(len(reqs), dtype=np.uint32)
-                lib.tpudfs_blocks_read_crc(
-                    paths, len(reqs), stride,
-                    buf.ctypes.data, sizes.ctypes.data, crcs.ctypes.data,
-                )
-            else:
-                lib.tpudfs_blocks_read(
-                    paths, len(reqs), stride,
-                    buf.ctypes.data, sizes.ctypes.data,
-                )
-            return ([int(s) == r.size for s, r in zip(sizes, reqs)],
-                    crcs)
-        from tpudfs.common.checksum import crc32c
-
-        ok = []
-        crcs = np.zeros(len(reqs), dtype=np.uint32) if self.host_verify \
-            else None
-        flat = buf.reshape(-1).view(np.uint8)
-        for i, r in enumerate(reqs):
-            try:
-                with open(r.path, "rb") as f:
-                    data = f.read(stride)
-            except OSError:
-                ok.append(False)
-                continue
-            if len(data) != r.size:
-                ok.append(False)
-                continue
-            flat[i * stride : (i + 1) * stride] = np.frombuffer(
-                data, dtype=np.uint8
+        else:
+            lib.tpudfs_blocks_read(
+                paths, len(reqs), stride,
+                buf.ctypes.data, sizes.ctypes.data,
             )
-            if crcs is not None:
-                crcs[i] = crc32c(data)
-            ok.append(True)
-        return ok, crcs
+        return [int(s) == r.size for s, r in zip(sizes, reqs)], crcs
 
     # ----------------------------------------------------- stage 2: device
 
@@ -636,14 +534,7 @@ class ReadCombiner:
         bucket is one sub-round.
 
         A pooled ``rows`` returns to the pool only once every transfer out
-        of it COMPLETED, on every backend: the CPU client copies by
-        completion, not at dispatch (measured: mutating the source right
-        after device_put corrupts ~15% of 4 MiB transfers), and an
-        accelerator may still be reading the host buffer until the device
-        array is ready. (_cpu_copies still gates POOLING itself — an
-        ALIASING backend is unsafe no matter how long we wait.)"""
-        from tpudfs.tpu.hbm_reader import DeviceBlock
-
+        of it COMPLETED (host_buffers, rule 2)."""
         #: words of the sub-rounds already shipped out of ``rows``
         shipped: list = []
         off = 0
