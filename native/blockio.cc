@@ -398,22 +398,29 @@ int64_t tpudfs_block_read_verify(const char* data_path, const char* meta_path,
 //
 // The steady-state infeed loop, native end-to-end (round-4 verdict: the
 // per-round Python between tpudfs_blocks_read and device_put was 30-50%
-// of the read window on the one-core bench host). Python hands the WHOLE
-// sweep over once — block paths, a ring of round-sized buffers, and the
-// per-block sizes/crcs result arrays — and a producer thread fills round
-// after round (fused pread+CRC, same slices as tpudfs_blocks_read_crc)
-// ahead of the consumer. Python's per-round work shrinks to: one
-// (usually already-satisfied) wait, one device_put of the filled buffer,
-// one release. All waits release the GIL (ctypes), so the producer
-// overlaps the device copies even on one core — no executor hops, no
-// futures, no per-block staging.
+// of the read window). Python hands the WHOLE sweep over once — block
+// paths, a ring of round-sized buffers, and the per-block sizes/crcs
+// result arrays — and a small fixed team of producer threads fills round
+// after round ahead of the consumer, each thread one BLOCK at a time
+// (fused pread+CRC, same slices as tpudfs_blocks_read_crc): reads of block
+// files scale with the number of readers where one thread's pread + CRC
+// is what a sweep waits for (PERF.md §5, ha_colocated_sweep).
+// Python's per-round work shrinks to: one wait, one device_put of the
+// filled buffer, one release. All waits
+// release the GIL (ctypes), so the producers overlap the device copies —
+// no executor hops, no futures, no per-block staging.
 
-#include <atomic>
+#include <algorithm>
 #include <condition_variable>
 #include <mutex>
 #include <thread>
 
 namespace {
+
+// Producer threads of one sweep, where the machine has the cores and a
+// round the blocks for them. From a chip sweep of 2 / 4 / 8 (PERF.md §6,
+// PR 30): 2.5-2.7 / 3.7-3.9 / 4.4-4.6 GB/s into HBM.
+constexpr uint64_t kSweepProducers = 8;
 
 struct SweepPump {
   std::vector<std::string> paths;
@@ -424,40 +431,45 @@ struct SweepPump {
   uint32_t* crcs = nullptr;   // n entries (caller-owned)
   uint64_t n = 0;
   int64_t nrounds = 0;
-  int64_t produced = 0;   // rounds fully filled
+  uint64_t next_block = 0;    // the shared cursor: lowest block not claimed
+  std::vector<uint64_t> open_blocks;  // per round: blocks not yet filled
+  int64_t produced = 0;   // rounds fully filled (contiguous prefix)
   int64_t released = 0;   // lowest round whose buffer is NOT yet released
+  int64_t ready_waits = 0;  // tpudfs_sweep_wait calls that did not block
   std::vector<bool> release_flags;
   bool stopping = false;
   std::mutex mu;
   std::condition_variable cv_producer, cv_consumer;
-  std::thread worker;
+  std::vector<std::thread> team;
 
+  // One producer: claim the next block, fill its slot, count it into its
+  // round. A thread may start on round r+1 while a straggler finishes r.
   void run() {
-    for (int64_t r = 0; r < nrounds; r++) {
-      {
-        // Wait until round r's ring buffer is free again (the consumer
-        // released round r - nbufs).
-        std::unique_lock<std::mutex> lk(mu);
-        cv_producer.wait(lk, [&] {
-          return stopping ||
-                 r - released < static_cast<int64_t>(bufs.size());
-        });
-        if (stopping) return;
+    std::unique_lock<std::mutex> lk(mu);
+    for (;;) {
+      // The next block's ring buffer must be free again (the consumer
+      // released round r - nbufs).
+      cv_producer.wait(lk, [&] {
+        return stopping || next_block >= n ||
+               static_cast<int64_t>(next_block / round_blocks) - released <
+                   static_cast<int64_t>(bufs.size());
+      });
+      if (stopping || next_block >= n) return;
+      uint64_t i = next_block++;
+      lk.unlock();
+      uint64_t r = i / round_blocks;
+      // Same fused pread+CRC as tpudfs_blocks_read_crc, by construction.
+      read_block_crc_fused(paths[i].c_str(),
+                           bufs[r % bufs.size()] +
+                               (i - r * round_blocks) * stride,
+                           stride, &sizes[i], &crcs[i]);
+      // Publishing under mu orders the slot, sizes[i] and crcs[i] before
+      // any tpudfs_sweep_wait that sees the round produced.
+      lk.lock();
+      if (--open_blocks[r] == 0 && static_cast<int64_t>(r) == produced) {
+        while (produced < nrounds && open_blocks[produced] == 0) produced++;
+        cv_consumer.notify_all();
       }
-      uint8_t* buf = bufs[r % bufs.size()];
-      uint64_t lo = static_cast<uint64_t>(r) * round_blocks;
-      uint64_t hi = lo + round_blocks;
-      if (hi > n) hi = n;
-      for (uint64_t i = lo; i < hi; i++) {
-        // Same fused pread+CRC as tpudfs_blocks_read_crc, by construction.
-        read_block_crc_fused(paths[i].c_str(), buf + (i - lo) * stride,
-                             stride, &sizes[i], &crcs[i]);
-      }
-      {
-        std::lock_guard<std::mutex> lk(mu);
-        produced = r + 1;
-      }
-      cv_consumer.notify_all();
     }
   }
 };
@@ -486,15 +498,24 @@ int64_t tpudfs_sweep_start(const char** paths, uint64_t n, uint64_t stride,
   p->n = n;
   p->nrounds = static_cast<int64_t>((n + round_blocks - 1) / round_blocks);
   p->release_flags.assign(static_cast<size_t>(p->nrounds), false);
-  p->worker = std::thread([p] { p->run(); });
+  p->open_blocks.assign(static_cast<size_t>(p->nrounds), round_blocks);
+  p->open_blocks.back() = n - (p->nrounds - 1) * round_blocks;
+  // One core stays with the consumer; a one-core machine runs one producer.
+  uint64_t cores = std::thread::hardware_concurrency();
+  uint64_t k = std::min({kSweepProducers, round_blocks,
+                         cores > 1 ? cores - 1 : uint64_t{1}});
+  p->team.reserve(k);
+  for (uint64_t t = 0; t < k; t++) p->team.emplace_back([p] { p->run(); });
   return reinterpret_cast<int64_t>(p);
 }
 
-// Blocks (GIL released by ctypes) until round_idx is filled. Returns the
-// number of slots in that round, or -1 if the pump is stopping.
+// Blocks (GIL released by ctypes) until round_idx is filled: every slot of
+// the round and its sizes/crcs are then final. Returns the number of slots
+// in that round, or -1 if the pump is stopping.
 int64_t tpudfs_sweep_wait(int64_t handle, int64_t round_idx) {
   auto* p = reinterpret_cast<SweepPump*>(handle);
   std::unique_lock<std::mutex> lk(p->mu);
+  if (p->produced > round_idx) p->ready_waits++;
   p->cv_consumer.wait(lk, [&] {
     return p->stopping || p->produced > round_idx;
   });
@@ -505,7 +526,7 @@ int64_t tpudfs_sweep_wait(int64_t handle, int64_t round_idx) {
   return static_cast<int64_t>(hi - lo);
 }
 
-// Consumer is done with round_idx's buffer; the producer may refill it.
+// Consumer is done with round_idx's buffer; the producers may refill it.
 // Rounds may be released out of order; the producer gate advances over
 // the contiguous released prefix.
 void tpudfs_sweep_release(int64_t handle, int64_t round_idx) {
@@ -521,6 +542,17 @@ void tpudfs_sweep_release(int64_t handle, int64_t round_idx) {
   p->cv_producer.notify_all();
 }
 
+// out[0] = producer threads started; out[1] = tpudfs_sweep_wait calls so
+// far that found their round already produced (the team ran ahead).
+void tpudfs_sweep_info(int64_t handle, int64_t* out) {
+  auto* p = reinterpret_cast<SweepPump*>(handle);
+  std::lock_guard<std::mutex> lk(p->mu);
+  out[0] = static_cast<int64_t>(p->team.size());
+  out[1] = p->ready_waits;
+}
+
+// Returns once no producer can write a ring buffer or a result array
+// again: parked ones wake and leave, one inside a pread finishes its block.
 void tpudfs_sweep_stop(int64_t handle) {
   auto* p = reinterpret_cast<SweepPump*>(handle);
   {
@@ -529,7 +561,7 @@ void tpudfs_sweep_stop(int64_t handle) {
   }
   p->cv_producer.notify_all();
   p->cv_consumer.notify_all();
-  if (p->worker.joinable()) p->worker.join();
+  for (auto& t : p->team) t.join();
   delete p;
 }
 

@@ -1742,6 +1742,46 @@ async def test_sweep_pump_roundtrip(tmp_path):
         await c.stop()
 
 
+@pytest.mark.parametrize("by", ["paths", "metas"])
+async def test_sweep_pump_span_and_totals_say_the_team_ran(tmp_path, by):
+    """``hbm.sweep`` carries how many producers the pump started and how
+    many of its rounds were produced when asked for; the reader keeps the
+    running totals beside ``sweep_blocks``."""
+    from tpudfs.common import telemetry
+
+    files = [(f"/sw/t{i}", _rand(3 * 64 * 1024, seed=75 + i))
+             for i in range(3)]
+    c, client = await _cluster_with_files(tmp_path, files)
+    try:
+        client.local_reads = True
+        reader = HbmReader(client, jax.devices()[:1], batch_reads=8)
+        paths = [p for p, _ in files]
+        metas = [await client.get_file_info(p) for p in paths]
+        seen = []
+        for _ in range(2):
+            telemetry.enable()
+            try:
+                if by == "paths":
+                    blocks = await reader.sweep_paths_to_device(
+                        paths, round_blocks=4, ring=2)
+                else:
+                    blocks = await reader.sweep_metas_to_device(
+                        metas, round_blocks=4, ring=2)
+            finally:
+                telemetry.disable()
+                records = telemetry.drain()
+            assert len(blocks) == 9 and all(b.verified for b in blocks)
+            (attrs,) = [r.attrs for r in records if r.name == "hbm.sweep"]
+            assert attrs["blocks"] == 9 and attrs["rounds"] == 3
+            assert 1 <= attrs["producers"] <= 4  # round_blocks
+            assert 0 <= attrs["rounds_ready"] <= 3
+            seen.append(attrs["rounds_ready"])
+        assert reader.sweep_blocks == 18 and reader.sweep_rounds == 6
+        assert reader.sweep_rounds_ready == sum(seen)
+    finally:
+        await c.stop()
+
+
 async def test_sweep_pump_corruption_falls_back_and_recovers(tmp_path):
     """A corrupt local replica fails the pump's CRC check for that slot
     only; the per-block fallback excludes it and serves verified bytes
@@ -1830,6 +1870,9 @@ async def test_sweep_without_pump_serves_every_block_per_block(
         assert all(b.verified for b in blocks) and reader.sweep_blocks == 0
         fallbacks = [r.attrs for r in records if r.name == "sweep.fallback"]
         assert fallbacks == [{"blocks": 9}]
+        assert [r.attrs for r in records if r.name == "hbm.sweep"] == [
+            {"blocks": 9, "producers": 0, "rounds": 0, "rounds_ready": 0}]
+        assert reader.sweep_rounds == reader.sweep_rounds_ready == 0
         got = b"".join(device_array_to_bytes(b.array, b.size) for b in blocks)
         assert got == b"".join(d for _, d in files)
     finally:

@@ -188,8 +188,14 @@ def _locked_load() -> ctypes.CDLL | None:
         lib.tpudfs_sweep_release.argtypes = [ctypes.c_int64, ctypes.c_int64]
         lib.tpudfs_sweep_stop.restype = None
         lib.tpudfs_sweep_stop.argtypes = [ctypes.c_int64]
+        lib.tpudfs_sweep_info.restype = None
+        lib.tpudfs_sweep_info.argtypes = [
+            ctypes.c_int64,
+            ctypes.c_void_p,                  # out (int64[2])
+        ]
     except AttributeError:
-        # Prebuilt library predating the sweep pump.
+        # Prebuilt library predating the sweep pump (or its producer team:
+        # the sweep asks for tpudfs_sweep_info and falls back without it).
         pass
     try:
         lib.tpudfs_dataplane_stage_stats.restype = None

@@ -70,8 +70,11 @@ class HbmReader:
         #: reads; 0 keeps every block on the per-block path.
         self.batch_reads = batch_reads
         self._combiners: dict = {}
-        #: blocks served by the native sweep pump (observability/bench).
+        #: blocks served by the native sweep pump (observability/bench);
+        #: its rounds, and those already produced when the sweep asked.
         self.sweep_blocks = 0
+        self.sweep_rounds = 0
+        self.sweep_rounds_ready = 0
         #: erasure-coded blocks read, those of them that lost a data shard
         #: and were reconstructed on the device, the data shards they
         #: lacked, and the bytes of shards fetched for all of them.
@@ -513,11 +516,14 @@ class HbmReader:
         """Steady-state SWEEP infeed, native end-to-end (the round-4
         verdict's 'push the round loop out of Python'): every eligible
         block of every file is handed to the native sweep pump
-        (native/blockio.cc tpudfs_sweep_*) ONCE — a producer thread
-        drives fused pread+3-lane-CRC into a ring of round buffers ahead
-        of this coroutine, whose only per-round work is one wait (usually
-        already satisfied), one vectorized verify, one device_put, one
-        release. No per-block futures, no executor hops, no staging.
+        (native/blockio.cc tpudfs_sweep_*) ONCE — a small team of
+        producer threads (as many as the machine and a round allow, at
+        most eight) drives fused pread+3-lane-CRC, a block each at a time,
+        into a ring of round buffers, and this coroutine's only per-round
+        work is one wait, one vectorized verify, one device_put, one
+        release. No per-block futures, no executor hops, no staging. The
+        ``hbm.sweep`` span says how many ``producers`` ran and how many
+        rounds were ready when asked for (``rounds_ready`` of ``rounds``).
 
         Blocks that don't qualify (``may_fuse``; remote-only replica, CRC
         mismatch, short read), and every block where the library has no
@@ -528,23 +534,24 @@ class HbmReader:
 
         The ring's buffers are recycled under host_buffers' reuse rule:
         a round's device_put completes before its buffer is released to
-        the producer — ring depth keeps the producer ahead anyway."""
+        the producers. Round r - ring is released just before the wait
+        for round r, whose buffer it is: the team fills a round while the
+        transfers of the ``ring - 1`` rounds before it are in flight."""
         with telemetry.span("hbm.sweep") as whole:
-            out = await self._sweep_metas(metas, device, round_blocks, ring)
-            whole.set(blocks=len(out))
-            return out
+            return await self._sweep_metas(whole, metas, device,
+                                           round_blocks, ring)
 
-    async def _sweep_metas(self, metas: list[dict], device,
+    async def _sweep_metas(self, whole, metas: list[dict], device,
                            round_blocks: int, ring: int) -> list[DeviceBlock]:
-        """The sweep itself, inside its caller's ``hbm.sweep`` span (one
-        per public call, the metadata fan-out inside it when the caller
-        came by paths)."""
+        """The sweep itself, inside its caller's ``hbm.sweep`` span
+        ``whole`` (one per public call, the metadata fan-out inside it when
+        the caller came by paths), which it gives its attributes."""
         device = device or self.devices[0]
         lib = native.get_lib()
         # Without a pump in the library, or on a backend whose device
         # arrays alias the ring (no completion wait makes refilling it
         # safe), every block is a fallback entry.
-        pump = (lib is not None and hasattr(lib, "tpudfs_sweep_start")
+        pump = (lib is not None and hasattr(lib, "tpudfs_sweep_info")
                 and host_buffers.may_recycle(device))
 
         # ---- eligibility + local path resolution (meta order preserved)
@@ -580,6 +587,8 @@ class HbmReader:
                         if slot is None]
         results: list = [None] * len(entries)
         n = len(paths)
+        pump_info = np.zeros(2, dtype=np.int64)  # producers, rounds ready
+        nrounds = -(-n // round_blocks)
         if n:
             stride = max(expected_sizes)
             stride = -(-stride // CHECKSUM_CHUNK_SIZE) * CHECKSUM_CHUNK_SIZE
@@ -600,7 +609,6 @@ class HbmReader:
             handle = lib.tpudfs_sweep_start(
                 cpaths, n, stride, round_blocks, cbufs, ring,
                 sizes.ctypes.data, crcs.ctypes.data)
-            nrounds = -(-n // round_blocks)
             outstanding: list = [None] * nrounds  # round words awaiting H2D
             try:
                 for r in range(nrounds):
@@ -654,7 +662,10 @@ class HbmReader:
                     pend = [w for w in outstanding if w is not None]
                     if pend:
                         await asyncio.to_thread(jax.block_until_ready, pend)
+                    lib.tpudfs_sweep_info(handle, pump_info.ctypes.data)
                     lib.tpudfs_sweep_stop(handle)
+            self.sweep_rounds += nrounds
+            self.sweep_rounds_ready += int(pump_info[1])
 
         if fallback_idx:
             async def fb(eidx: int):
@@ -664,6 +675,8 @@ class HbmReader:
 
             with telemetry.span("sweep.fallback", blocks=len(fallback_idx)):
                 await asyncio.gather(*(fb(i) for i in fallback_idx))
+        whole.set(blocks=len(results), producers=int(pump_info[0]),
+                  rounds=nrounds, rounds_ready=int(pump_info[1]))
         return results
 
     async def sweep_paths_to_device(self, paths: list[str], device=None, *,
@@ -679,9 +692,8 @@ class HbmReader:
             missing = [p for p, m in zip(paths, metas) if m is None]
             if missing:
                 raise DfsError(f"file not found: {missing[0]}")
-            out = await self._sweep_metas(metas, device, round_blocks, ring)
-            whole.set(blocks=len(out))
-            return out
+            return await self._sweep_metas(whole, metas, device,
+                                           round_blocks, ring)
 
     # ------------------------------------------------------------- per file
 
