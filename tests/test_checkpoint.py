@@ -331,3 +331,304 @@ def test_corrupt_sidecar_quarantined_not_returned(tmp_path):
     (store.hot_dir / "blk.meta").write_bytes(b"JUNKJUNKJUNK")
     with pytest.raises(BlockCorruptionError):
         store.read_verified("blk")
+
+
+# ------------------------------- mixed precision, device assembly (PR 31)
+
+KIB = 1024
+_DTYPES = ("bfloat16", "float16", "float32", "int32", "uint8")
+
+
+def _bits(arr) -> np.ndarray:
+    return np.ascontiguousarray(np.asarray(arr)).reshape(-1).view(np.uint8)
+
+
+def _mixed_trees(dtype: str, seed: int = 7) -> dict[int, dict]:
+    """Two shards of ``dtype`` tensors made of random BYTES (NaN patterns
+    included) in 64 KiB blocks: ``b`` and ``c`` straddle block boundaries
+    (and, with rounds of 4 blocks, a round's), the odd-sized ``d`` leaves
+    the last block short and not chunk-aligned."""
+    import jax.numpy as jnp
+
+    dt = np.dtype(jnp.dtype(dtype))
+    rng = np.random.default_rng([seed, len(dtype)])
+
+    def of(nbytes: int, shape=None) -> np.ndarray:
+        arr = np.frombuffer(rng.bytes(nbytes), dtype=dt)
+        return arr.reshape(shape) if shape else arr
+
+    return {0: {"a": of(40 * KIB), "b": of(100 * KIB, (-1, 64)),
+                "c": of(300 * KIB, (4, -1)), "d": of(777 * dt.itemsize),
+                "step": np.int32(41)},
+            1: {"e": of(70 * KIB, (2, 5, -1)), "f": of(dt.itemsize)}}
+
+
+def _same(restored: dict, trees: dict) -> None:
+    assert sorted(restored) == sorted(trees)
+    for shard, tree in trees.items():
+        assert sorted(restored[shard]) == sorted(tree)
+        for name, want in tree.items():
+            got = restored[shard][name]
+            assert np.dtype(got.dtype) == np.asarray(want).dtype, name
+            assert tuple(got.shape) == np.asarray(want).shape, name
+            assert np.array_equal(_bits(got), _bits(want)), name
+
+
+@pytest.mark.parametrize("path", ["host", "device"])
+@pytest.mark.parametrize("dtype", _DTYPES)
+async def test_mixed_precision_roundtrip_is_bit_identical(tmp_path, dtype,
+                                                          path):
+    import jax
+    from tpudfs.tpu.hbm_reader import HbmReader
+
+    c, client, _ = await _ready(tmp_path)
+    try:
+        device = jax.devices()[0]
+        reader = HbmReader(client, [device], batch_reads=4)
+        mgr = CheckpointManager(client, "/ckpt/mixed", num_shards=2, ec=None,
+                                reader=reader)
+        trees = _mixed_trees(dtype)
+        manifest = await mgr.save(3, trees)
+        by_name = {t["name"]: t for s in manifest["shards"]
+                   for t in s["tensors"]}
+        assert by_name["a"]["dtype"] == dtype  # the name, never "<V2"
+        assert manifest["shards"][0]["size"] % 512  # a short last block
+        if path == "host":
+            _same(await mgr.restore(), trees)
+            return
+        restored = await mgr.restore(device=device)
+        for tree in restored.values():
+            assert all(isinstance(v, jax.Array) for v in tree.values())
+        _same(restored, trees)
+        # Through the fused rounds, and no byte of a 2- or 4-byte tensor
+        # through the host.
+        combiner = reader._combiners[device]
+        assert combiner.rounds > 0 and combiner.blocks >= 7  # 6 + 1 whole
+        state = sum(np.asarray(v).nbytes for t in trees.values()
+                    for v in t.values())
+        bounced = state - 4 if dtype == "uint8" else 0  # "step" is int32
+        assert mgr.stats["tensor_bytes_host_bounce"] == bounced
+        assert mgr.stats["tensor_bytes_device"] == state - bounced
+    finally:
+        await c.stop()
+
+
+def test_assembly_across_rounds_of_mixed_shards_and_a_short_block():
+    """The deterministic half of the above: blocks of two files arrive in
+    fused rounds out of order and mixed; a tensor straddles the seam of
+    two rounds; the last block is short and stands alone."""
+    import jax
+    import jax.numpy as jnp
+    from tpudfs.tpu import ckpt_assemble
+    from tpudfs.tpu.device_block import DeviceBatch, DeviceBlock
+
+    device = jax.devices()[0]
+    rows = 8  # block = 8 rows of 512 B
+    bb = rows * 512
+    tree = {"w": np.frombuffer(np.random.default_rng(5).bytes(3 * bb + 1024),
+                               dtype=jnp.dtype("bfloat16")).reshape(-1, 128),
+            "x": np.frombuffer(np.random.default_rng(6).bytes(2 * bb + 516),
+                               dtype=np.float32),
+            "z": np.arange(3, dtype=np.int16)}
+    payload, specs = pack_shard(tree)
+    other = np.random.default_rng(9).bytes(4 * bb)  # another shard's blocks
+    nblocks = -(-len(payload) // bb)
+    assert nblocks == 6 and len(payload) % 512
+
+    def grid(data: bytes) -> np.ndarray:
+        pad = -len(data) % 512
+        return np.frombuffer(data + b"\0" * pad, "<u4").reshape(-1, 128)
+
+    mine = [payload[j * bb:(j + 1) * bb] for j in range(nblocks)]
+    foreign = [other[j * bb:(j + 1) * bb] for j in range(4)]
+    # round A: [mine 2, foreign 0, mine 0, foreign 1]; round B: [foreign 2,
+    # mine 4, mine 1, mine 3]; block 5 (short) alone.
+    rounds = {"A": [mine[2], foreign[0], mine[0], foreign[1]],
+              "B": [foreign[2], mine[4], mine[1], mine[3]]}
+    batches = {k: DeviceBatch(
+        words=jax.device_put(np.concatenate([grid(b) for b in v]), device),
+        crcs=None, cpb=rows, nblocks=4) for k, v in rounds.items()}
+    where = {0: ("A", 2), 1: ("B", 2), 2: ("A", 0), 3: ("B", 3), 4: ("B", 1)}
+    blocks = [DeviceBlock(f"b{j}", None, bb, True, batch=batches[k],
+                          batch_index=i) for j, (k, i) in where.items()]
+    blocks.append(DeviceBlock("b5", jax.device_put(grid(mine[5]), device),
+                              len(mine[5]), True))
+    tensors = [s.to_dict() for s in specs]
+    got, on_dev, bounced = ckpt_assemble.assemble_shard(
+        tensors, [np.dtype(t["dtype"]) for t in tensors], len(payload),
+        blocks, device, rows)
+    _same({0: got}, {0: tree})
+    assert (on_dev, bounced) == (sum(v.nbytes for v in tree.values()), 0)
+    with pytest.raises(ValueError, match="do not make a file"):
+        ckpt_assemble.assemble_shard(
+            tensors, [np.dtype(t["dtype"]) for t in tensors], len(payload),
+            blocks[:-1], device, rows)
+
+
+async def test_device_restore_skips_a_staged_uncommitted_newer_step(tmp_path):
+    import jax
+    from tpudfs.tpu.hbm_reader import HbmReader
+
+    c, client, _ = await _ready(tmp_path)
+    try:
+        device = jax.devices()[0]
+        mgr = CheckpointManager(
+            client, "/ckpt/torn", num_shards=2, ec=None,
+            reader=HbmReader(client, [device], batch_reads=4))
+        trees = _mixed_trees("bfloat16")
+        await mgr.save(100, trees)
+        newer = _mixed_trees("bfloat16", seed=8)
+        await mgr.save_shard(200, 0, newer[0])  # staged, never committed
+        assert await mgr.latest_step() == 100
+        _same(await mgr.restore(device=device), trees)
+        with pytest.raises(CheckpointNotFoundError):
+            await mgr.restore(200, device=device)
+    finally:
+        await c.stop()
+
+
+async def test_manifest_with_old_dtype_strings_still_loads(tmp_path):
+    import jax
+    from tpudfs.tpu.hbm_reader import HbmReader
+
+    payload, specs = pack_shard({"w": np.arange(300, dtype=np.float32),
+                                 "i": np.arange(7, dtype=np.int32)})
+    old = [{**s.to_dict(), "dtype": np.dtype(s.dtype).str} for s in specs]
+    assert {t["dtype"] for t in old} == {"<f4", "<i4"}
+    out = unpack_shard(payload, old)
+    assert out["w"].dtype == np.float32 and out["i"].dtype == np.int32
+    c, client, _ = await _ready(tmp_path)
+    try:
+        device = jax.devices()[0]
+        mgr = CheckpointManager(
+            client, "/ckpt/old", num_shards=2, ec=None,
+            reader=HbmReader(client, [device], batch_reads=4))
+        trees = _mixed_trees("float32")
+        manifest = await mgr.save(1, trees)
+        for spec in manifest["shards"]:
+            for t in spec["tensors"]:
+                t["dtype"] = np.dtype(t["dtype"]).str  # as PR 7 wrote them
+        for shard, tree in trees.items():
+            _same({shard: await mgr.restore_shard(manifest, shard,
+                                                  device=device)},
+                  {shard: tree})
+            _same({shard: await mgr.restore_shard(manifest, shard)},
+                  {shard: tree})
+    finally:
+        await c.stop()
+
+
+async def test_rot_under_every_replica_fails_the_restore_before_any_tensor(
+        tmp_path, monkeypatch):
+    import jax
+    from tpudfs.tpu import ckpt_assemble
+    from tpudfs.tpu.checkpoint import DegradedRestoreError
+    from tpudfs.tpu.hbm_reader import HbmReader
+
+    c, client, _ = await _ready(tmp_path)
+    try:
+        device = jax.devices()[0]
+        mgr = CheckpointManager(
+            client, "/ckpt/rot", num_shards=2, ec=None,
+            reader=HbmReader(client, [device], batch_reads=4))
+        manifest = await mgr.save(5, _mixed_trees("bfloat16"))
+        meta = await client.get_file_info(manifest["shards"][0]["path"])
+        bid = meta["blocks"][3]["block_id"]
+        for cs in c.chunkservers:
+            if cs.store.exists(bid):
+                raw = bytearray(cs.store.read(bid))
+                raw[1000] ^= 0x40
+                cs.store.write(bid, bytes(raw))  # sidecar follows: silent
+        handed = []
+        real = ckpt_assemble.assemble_shard
+        monkeypatch.setattr(
+            ckpt_assemble, "assemble_shard",
+            lambda *a, **kw: handed.append(a) or real(*a, **kw))
+        with pytest.raises(DegradedRestoreError):
+            await mgr.restore_shard(manifest, 0, device=device)
+        assert handed == []  # the error, not a tensor
+        with pytest.raises(DegradedRestoreError):
+            await mgr.restore(device=device)
+        # The healthy shard still restores; the rotten one never reached
+        # the assembly.
+        await mgr.restore_shard(manifest, 1, device=device)
+        assert handed and all(a[0][0]["name"] == "e" for a in handed)
+    finally:
+        await c.stop()
+
+
+def test_reference_ckpt_payload_equals_pack_shard_byte_for_byte():
+    """The benchmark's plain reference against the program, seeded, small:
+    the shard file the reference lays out is the one ``pack_shard`` makes
+    of the same tensors."""
+    import jax.numpy as jnp
+    from benchmarks import reference_ckpt
+
+    cfg = {"block_bytes": 64 * KIB, "assumed": {"num_shards": 3},
+           "dataset": {
+               "layer": "model.layers.1",
+               "states": {"params": "bfloat16", "master": "float32"},
+               "scalars": {"step": "int32"},
+               "parameters": {"a.weight": [33, 7], "norm.weight": [5]},
+               "experts_held": 2,
+               "expert_parameters": {"experts.{e}.up.weight": [9, 11]}}}
+    seed = 2**31 + 31
+    assert len(reference_ckpt.table(cfg)) == 2 * 4 + 1
+    for shard, names in enumerate(reference_ckpt.deal(cfg)):
+        tree = {}
+        for name in names:
+            dtype, shape, data = reference_ckpt.tensor(seed, cfg, name)
+            tree[name] = np.frombuffer(data, jnp.dtype(dtype)).reshape(shape)
+        payload, specs = pack_shard(tree)
+        assert payload == reference_ckpt.shard_payload(seed, cfg, shard)
+        assert [(s.name, s.offset, s.size) for s in specs] \
+            == reference_ckpt.layout(cfg, shard)[0]
+        assert [s.dtype for s in specs] \
+            == [reference_ckpt.table(cfg)[n][0] for n in names]
+
+
+def test_what_a_tpu_cannot_make_bit_for_bit_bounces_through_the_host(
+        monkeypatch):
+    """A v5e packs float32 registers whenever XLA writes bfloat16 (NaN
+    payloads and denormals go): bf16 gets its dtype in a Mosaic kernel run
+    in the tensor's own shape, and what has no such shape, and float16,
+    take the host bounce there; off the TPU everything stays on the
+    device (the parametrised round trips above)."""
+    import jax
+    from tpudfs.tpu import ckpt_assemble
+    from tpudfs.tpu.device_block import DeviceBlock
+
+    bf16, f16 = np.dtype("bfloat16"), np.dtype(np.float16)
+    assert ckpt_assemble.on_device(bf16, ()) and \
+        ckpt_assemble.on_device(f16, (5,))
+    monkeypatch.setattr(ckpt_assemble, "on_tpu", lambda: True)
+    for shape, view in [((3072, 2048), (3072, 2048)), ((3, 100), (3, 100)),
+                        ((2048,), (16, 128)), ((8, 64, 512), (512, 512)),
+                        ((2, 3, 32, 7), (192, 7)), ((2, 5, 7168), None),
+                        ((777,), None), ((), None)]:
+        assert ckpt_assemble._relabel_shape(shape) == view
+        assert ckpt_assemble.on_device(bf16, shape) is (view is not None)
+    assert not ckpt_assemble.on_device(f16, (4, 4))
+    for dtype in (np.float32, np.int32, np.uint32, np.int16, np.uint16):
+        assert ckpt_assemble.on_device(np.dtype(dtype), (3,))
+    assert not ckpt_assemble.on_device(np.dtype(np.uint8), (4,))
+    assert not ckpt_assemble.on_device(np.dtype(np.float64), (4,))
+    # float16 through the assembly as a TPU would run it: bounced, counted,
+    # still bit for bit (no bf16 here, so no Mosaic kernel is called).
+    device = jax.devices()[0]
+    tree = {"h": np.frombuffer(np.random.default_rng(3).bytes(3000),
+                               dtype=np.float16).reshape(3, -1),
+            "w": np.frombuffer(np.random.default_rng(4).bytes(4000),
+                               dtype=np.float32)}
+    payload, specs = pack_shard(tree)
+    rows = -(-len(payload) // 512)
+    grid = np.frombuffer(payload + b"\0" * (-len(payload) % 512),
+                         "<u4").reshape(-1, 128)
+    block = DeviceBlock("b0", jax.device_put(grid, device), len(payload),
+                        True)
+    tensors = [s.to_dict() for s in specs]
+    got, on_dev, bounced = ckpt_assemble.assemble_shard(
+        tensors, [np.dtype(t["dtype"]) for t in tensors], len(payload),
+        [block], device, rows)
+    _same({0: got}, {0: tree})
+    assert (on_dev, bounced) == (4000, 3000)

@@ -67,10 +67,10 @@ def chip(monkeypatch):
     the traces made under it out of every other test: jitted functions
     cache their trace per shape, interpret flag included."""
     import tpudfs.tpu
-    from tpudfs.tpu import crc32c_pallas, rs_pallas
+    from tpudfs.tpu import ckpt_assemble, crc32c_pallas, rs_pallas
 
     jax.clear_caches()
-    for mod in (tpudfs.tpu, crc32c_pallas, rs_pallas):
+    for mod in (tpudfs.tpu, ckpt_assemble, crc32c_pallas, rs_pallas):
         monkeypatch.setattr(mod, "on_tpu", lambda: True)
     yield
     jax.clear_caches()
@@ -237,3 +237,88 @@ def test_compiles_for_v5e(case, topo, one_chip, chip):
     assert mem.argument_size_in_bytes > 0
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         + mem.output_size_in_bytes < HBM_BYTES, f"{case}: does not fit HBM"
+
+
+# --------------------------------------------- checkpoint assembly (PR 31)
+
+
+def _ckpt_shard(shard: int):
+    """One shard of ``ckpt-3m5cs-r3`` as the manifest would spell it."""
+    import json
+    from pathlib import Path
+
+    from benchmarks import reference_ckpt
+
+    cfg = json.loads((Path(__file__).resolve().parent.parent / "benchmarks"
+                      / "configs" / "ckpt-3m5cs-r3.json").read_text())
+    table = reference_ckpt.table(cfg)
+    placed, size = reference_ckpt.layout(cfg, shard)
+    tensors = [{"name": n, "offset": off, "size": nbytes,
+                "dtype": table[n][0], "shape": list(table[n][1])}
+               for n, off, nbytes in placed]
+    return tensors, size, cfg["block_bytes"]
+
+
+@pytest.mark.parametrize("shard", [0, 3])
+def test_checkpoint_assembly_compiles_for_v5e_within_a_shard_of_temp(
+        shard, topo, one_chip, chip):
+    """336 blocks of 1 MiB into a shard's 31-38 bf16 and f32 tensors: one
+    program, the bf16 tensors relabelled by the Mosaic kernel (XLA's own
+    bitcast packs floats on a v5e and loses NaN payloads and denormals),
+    and less HBM in temporaries than the shard itself (a bitcast to
+    ``(n, 2)`` bf16 took 64x the tensor). The gather of a 16-block round
+    updates the donated buffer in place."""
+    from tpudfs.tpu import ckpt_assemble
+
+    tensors, size, block_bytes = _ckpt_shard(shard)
+    dtypes = [jnp.dtype(t["dtype"]) for t in tensors]
+    assert {d.name for d in dtypes} == {"bfloat16", "float32"} \
+        | ({"int32"} if shard == 3 else set())
+    rows = block_bytes // 512
+    nblocks = -(-size // block_bytes)
+    assert nblocks == 336
+    buf = _words((nblocks + 1) * rows, one_chip)
+    program = ckpt_assemble.assembler(ckpt_assemble._layout(tensors, dtypes))
+    compiled = program.lower(buf).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= sum(
+        d.name == "bfloat16" for d in dtypes)
+    # Nothing but the kernel makes bf16, and nothing but a free bitcast
+    # follows it: no fusion, copy, reshape or slice touches the bits.
+    made = [line for line in text.splitlines()[1:] if " = bf16[" in line]
+    assert made and all(
+        any(op in line for op in ("custom-call(", "get-tuple-element(",
+                                  " bitcast("))
+        for line in made), [m[:120] for m in made][:5]
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= sum(t["size"] for t in tensors)
+    assert mem.temp_size_in_bytes < size, mem.temp_size_in_bytes
+    dest = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    gather = ckpt_assemble.ckpt_assemble_gather.lower(
+        buf, _words(16 * rows, one_chip), dest, nblocks=16).compile()
+    mem = gather.memory_analysis()
+    assert mem.alias_size_in_bytes == mem.output_size_in_bytes > size
+    assert mem.temp_size_in_bytes < block_bytes, mem.temp_size_in_bytes
+
+
+def test_bf16_views_of_vectors_and_stacked_tensors_are_free_bitcasts(
+        topo, one_chip, chip):
+    """What follows the relabelling kernel for a vector of whole rows and
+    for tensors of three and four dimensions whose second-minor one is
+    whole tiles is a bitcast: no copy, reshape or reduce runs on bf16."""
+    from tpudfs.tpu import ckpt_assemble
+
+    shapes = [(2048,), (512,), (8, 64, 512), (2, 3, 32, 256), (300, 100)]
+    layout, row = [], 0
+    for shape in shapes:
+        assert ckpt_assemble.on_device(np.dtype(jnp.bfloat16), shape)
+        count = int(np.prod(shape))
+        layout.append((row, count, "bfloat16", shape))
+        row += -(-count * 2 // 512)
+    text = ckpt_assemble.assembler(tuple(layout)).lower(
+        _words(row + 8, one_chip)).compile().as_text()
+    made = [line for line in text.splitlines()[1:] if " = bf16[" in line]
+    assert len([m for m in made if "custom-call(" in m]) == len(shapes)
+    assert all(any(op in line for op in ("custom-call(", " bitcast(",
+                                         "get-tuple-element("))
+               for line in made), [m[:140] for m in made]

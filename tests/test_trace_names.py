@@ -1,8 +1,9 @@
 """The names the trace's readers find the device programs by, pinned where
 the programs are made: each lowers on the CPU under the module name the
 benchmark's readers match (``crc_verify_roofline_pct``,
-``rs_decode_roofline_pct``, ``ici_round_ms``) and carries its ``tpudfs.*``
-scope in the lowered text."""
+``rs_decode_roofline_pct``, ``ici_round_ms``, ``ckpt_assemble_roofline_pct``)
+and carries its ``tpudfs.*`` scope in the lowered text. The restore's
+``ckpt.*`` span names, which four readers match, are pinned beside them."""
 
 from __future__ import annotations
 
@@ -64,6 +65,23 @@ def _rs_decode_block():
         slen=slen, size=65536)
 
 
+def _ckpt_assemble():
+    """One shard layout: a bf16, an f32 and a bounced uint8 tensor."""
+    from tpudfs.tpu import ckpt_assemble
+
+    layout = ((0, 300, "bfloat16", (3, 100)), (2, 128, "float32", (128,)),
+              (3, 5, "uint8", (5,)))
+    return ckpt_assemble.assembler(layout).lower(_words(4 * CHUNKS))
+
+
+def _ckpt_gather():
+    from tpudfs.tpu.ckpt_assemble import ckpt_assemble_gather
+
+    return ckpt_assemble_gather.lower(
+        _words(5 * CHUNKS), _words(4 * CHUNKS),
+        jax.ShapeDtypeStruct((4,), jnp.int32), nblocks=4)
+
+
 def _ici_replicate():
     mesh = make_mesh(jax.devices()[:4])
     return IciReplicator(mesh, replication=3)._fn.lower(
@@ -89,6 +107,8 @@ def _ec_gather():
     (_rs_encode, None, "tpudfs.rs_encode"),
     (_rs_decode, None, "tpudfs.rs_decode"),
     (_rs_decode_block, "jit_rs_decode_block", "tpudfs.rs_decode"),
+    (_ckpt_assemble, "jit_ckpt_assemble", "tpudfs.ckpt_assemble"),
+    (_ckpt_gather, "jit_ckpt_assemble_gather", "tpudfs.ckpt_assemble"),
     (_ici_replicate, "jit_step", "tpudfs.ici_replicate"),
     (_ec_scatter, "jit_step", "tpudfs.ec_scatter"),
     (_ec_gather, "jit_step", "tpudfs.ec_gather"),
@@ -99,3 +119,62 @@ def test_program_lowers_under_its_module_name_with_its_scope(
     if module is not None:
         assert f"module @{module} " in text, text[:200]
     assert scope in text
+
+
+async def test_a_device_restore_records_its_ckpt_spans_under_one_restore(
+        tmp_path):
+    """The names ``ckpt_meta_ms_per_restore``, ``ckpt_read_ms_per_restore``,
+    ``ckpt_assemble_ms_per_restore`` and ``ckpt_assemble_roofline_pct``
+    match, their attrs, and what hangs under what."""
+    import numpy as np
+
+    from tests.test_checkpoint import _ready
+    from tpudfs.common import telemetry
+    from tpudfs.tpu.checkpoint import CheckpointManager
+    from tpudfs.tpu.hbm_reader import HbmReader
+
+    c, client, _ = await _ready(tmp_path)
+    try:
+        device = jax.devices()[0]
+        mgr = CheckpointManager(
+            client, "/ckpt/spans", num_shards=2, ec=None,
+            reader=HbmReader(client, [device], batch_reads=4))
+        trees = {s: {"w": np.arange(40_000, dtype=np.float32) + s,
+                     "h": np.arange(999, dtype=np.float16)}
+                 for s in range(2)}
+        manifest = await mgr.save(9, trees)
+        telemetry.enable()
+        try:
+            await mgr.restore(device=device)
+        finally:
+            records = telemetry.drain()
+            telemetry.disable()
+    finally:
+        await c.stop()
+    by_name: dict = {}
+    for r in records:
+        by_name.setdefault(r.name, []).append(r)
+    (whole,) = by_name["ckpt.restore"]
+    assert whole.attrs == {"step": 9, "shards": 2}
+    children = {"ckpt.latest_step": 1, "ckpt.manifest": 1,
+                "ckpt.read_shard": 2, "ckpt.confirm": 2,
+                "ckpt.combined_crc": 2, "ckpt.assemble": 2}
+    assert {n for n in by_name if n.startswith("ckpt.")} \
+        == {"ckpt.restore", *children}
+    for name, count in children.items():
+        assert len(by_name[name]) == count, name
+        assert all(r.parent_id == whole.span_id
+                   and r.request_id == whole.request_id
+                   for r in by_name[name]), name
+    assert by_name["ckpt.manifest"][0].attrs == {"step": 9}
+    sizes = {s["shard"]: s["size"] for s in manifest["shards"]}
+    for r in by_name["ckpt.read_shard"]:
+        assert r.attrs["source"] == "hot" and r.attrs["blocks"] == 3
+    for r in by_name["ckpt.assemble"]:
+        assert r.attrs == {"shard": r.attrs["shard"], "tensors": 2,
+                           "bytes": sizes[r.attrs["shard"]]}
+    # The reader's and the combiner's spans come for free underneath.
+    reads = {r.span_id for r in by_name["ckpt.read_shard"]}
+    assert len(by_name["hbm.read_file"]) == 2
+    assert {r.parent_id for r in by_name["hbm.read_file"]} == reads
+    assert by_name["combiner.fetch"] and by_name["client.get_file_info"]

@@ -25,19 +25,45 @@ contract this module provides under all of that:
   publish; a zombie writer replaying an OLD step is rejected by the
   monotonic-step fence at apply time.
 - **Gracefully degrading restore.** Shards restore in parallel, optionally
-  straight into device HBM via :class:`~tpudfs.tpu.hbm_reader.HbmReader`
-  (per-block on-device CRC verification before any tensor reaches JAX).
-  Per shard the read falls back: hot 3x-replicated copy (replica failover
-  inside the client/reader) → erasure-coded cold copy (RS reconstruction
-  when chunkservers are dead) → :class:`DegradedRestoreError`. Every path
-  is CRC-verified end-to-end against the manifest.
+  straight into device HBM via :class:`~tpudfs.tpu.hbm_reader.HbmReader`:
+  blocks are read lazily-verified (on a reader with ``batch_reads`` they
+  ride the read combiner's fused rounds), ONE ``confirm`` a shard resolves
+  every block's on-device CRC before any tensor reaches JAX, and the
+  tensors are assembled on the device (below). Per shard the read falls
+  back: hot 3x-replicated copy (replica failover inside the client/reader)
+  → erasure-coded cold copy (RS reconstruction when chunkservers are dead)
+  → :class:`DegradedRestoreError`. Every path is CRC-verified end-to-end
+  against the manifest.
 
 Shard payload format: tensors sorted by name, each serialized raw
-(C-order) at a 512-byte-aligned offset (``_ALIGN`` = the CRC chunk size,
-so every tensor starts word- and chunk-aligned — device restore slices the
-word stream without byte shuffling). The per-shard spec records
+(C-order, little-endian) at a 512-byte-aligned offset (``_ALIGN`` = the
+CRC chunk size = one 128-word row of a block's device grid, so every
+tensor starts on a row). The per-shard spec records
 name/dtype/shape/offset/size/crc32c per tensor plus the whole-payload
-CRC; the manifest aggregates the specs of all shards.
+CRC; the manifest aggregates the specs of all shards. ``dtype`` is the
+numpy NAME (``"bfloat16"``, ``"float32"``): ``dtype.str`` of an
+``ml_dtypes`` type is a void (``"<V2"``). Manifests that carry ``.str``
+codes (``"<f4"``) still load.
+
+Device assembly (:mod:`tpudfs.tpu.ckpt_assemble`): the rounds (and single
+blocks) a shard's read left in HBM are gathered into one row buffer in
+file order (``ckpt_assemble_gather``, in place, one call a round) and ONE
+program per shard layout (``ckpt_assemble``) cuts that buffer into typed
+tensors. Every move is made on unsigned integers and the dtype comes last:
+a 4-byte dtype is a bitcast, a 2-byte one splits each word into its halves
+(little-endian: the low half first); on a TPU, whose vector unit cannot
+write bfloat16 without flushing denormals and quieting NaNs, a bf16 tensor
+is relabelled by a Mosaic kernel instead. 1- and 8-byte dtypes (and, on a
+TPU, float16 and bf16 scalars or ragged vectors) bounce through the host
+(``stats["tensor_bytes_host_bounce"]``). Rounds and buffer are dropped when
+the program has them: during a shard's restore HBM holds at most twice the
+shard beside the rounds in flight.
+
+Spans (``tpudfs.common.telemetry``; sites and attrs in
+``docs/operations.md``): ``ckpt.restore`` with children
+``ckpt.latest_step``, ``ckpt.manifest`` and, per shard,
+``ckpt.read_shard``, ``ckpt.confirm``, ``ckpt.combined_crc``,
+``ckpt.assemble``.
 """
 
 from __future__ import annotations
@@ -56,7 +82,7 @@ from tpudfs.client.client import (
     Client,
     DfsError,
 )
-from tpudfs.common import ckptpaths
+from tpudfs.common import ckptpaths, telemetry
 from tpudfs.common.checksum import crc32c, crc32c_combine
 from tpudfs.common.resilience import (
     BudgetExhausted,
@@ -99,12 +125,24 @@ def _align(n: int) -> int:
     return (n + _ALIGN - 1) // _ALIGN * _ALIGN
 
 
+def _dtype_of(name: str) -> np.dtype:
+    """A spec's dtype: the numpy name (an ``ml_dtypes`` one included), or
+    the ``.str`` code of a manifest written before names were."""
+    try:
+        return np.dtype(name)
+    except TypeError:
+        # numpy learns "bfloat16" and the float8s when ml_dtypes is imported.
+        import ml_dtypes
+
+        return np.dtype(getattr(ml_dtypes, name))
+
+
 @dataclasses.dataclass
 class TensorSpec:
     """One tensor's placement inside a shard payload."""
 
     name: str
-    dtype: str  # numpy dtype .str, e.g. "<f4"
+    dtype: str  # numpy dtype name, e.g. "bfloat16" (old manifests: "<f4")
     shape: tuple[int, ...]
     offset: int
     size: int
@@ -133,10 +171,12 @@ def pack_shard(tree: dict) -> tuple[bytes, list[TensorSpec]]:
     specs: list[TensorSpec] = []
     for name in sorted(tree):
         arr = np.asarray(tree[name])
+        if arr.dtype.byteorder == ">":
+            arr = arr.astype(arr.dtype.newbyteorder("<"))
         raw = arr.tobytes()
         offset = _align(len(buf))
         buf.extend(b"\x00" * (offset - len(buf)))
-        specs.append(TensorSpec(name=name, dtype=arr.dtype.str,
+        specs.append(TensorSpec(name=name, dtype=arr.dtype.name,
                                 shape=tuple(arr.shape), offset=offset,
                                 size=len(raw), crc32c=crc32c(raw)))
         buf.extend(raw)
@@ -156,7 +196,7 @@ def unpack_shard(payload: bytes, tensors: list[dict]) -> dict:
             raise ChecksumMismatchError(
                 f"tensor {spec.name!r} failed CRC inside its shard payload"
             )
-        out[spec.name] = np.frombuffer(raw, dtype=np.dtype(spec.dtype)) \
+        out[spec.name] = np.frombuffer(raw, dtype=_dtype_of(spec.dtype)) \
             .reshape(spec.shape)
     return out
 
@@ -185,8 +225,10 @@ class CheckpointManager:
     ``hot_copies=False`` drops the replicated hot copy and saves the EC
     copy only (the archival/bench-degraded configuration). ``reader`` is
     an optional :class:`~tpudfs.tpu.hbm_reader.HbmReader` used when
-    ``restore(..., device=...)`` asks for tensors in HBM; without it (or
-    without a device) restore assembles host numpy arrays.
+    ``restore(..., device=...)`` asks for tensors in HBM (build it with
+    ``batch_reads=16`` and the shards' blocks ride the read combiner's
+    fused rounds); without it (or without a device) restore assembles
+    host numpy arrays.
 
     Budgets: ``save_budget_s``/``restore_budget_s`` install a resilience
     deadline scope around each public op unless an outer scope is already
@@ -207,9 +249,9 @@ class CheckpointManager:
         self.ec = tuple(ec) if ec else None
         self.hot_copies = hot_copies
         if reader is not None and client.block_size % _ALIGN:
-            # The HBM restore path slices the concatenated per-block word
-            # stream by payload offset, which is only sound when every
-            # non-final block is a whole number of 512-byte CRC chunks.
+            # The HBM restore path lays the blocks out by rows of 512 bytes
+            # and finds a tensor by its payload offset, which is only sound
+            # when every non-final block is a whole number of rows.
             raise ValueError(
                 f"block_size {client.block_size} must be a multiple of "
                 f"{_ALIGN} for device restore")
@@ -230,6 +272,10 @@ class CheckpointManager:
             "restored_shards": 0,
             "degraded_shard_reads": 0,  # hot copy dead -> EC cold copy
             "gc_deleted": 0,
+            # device restore: tensor bytes cut out of the blocks on the
+            # device, and those that went through the host on the way
+            "tensor_bytes_device": 0,
+            "tensor_bytes_host_bounce": 0,
         }
 
     @contextlib.contextmanager
@@ -413,14 +459,24 @@ class CheckpointManager:
         """Parallel shard-wise restore of ``step`` (default: latest).
         Returns ``{shard: {name: array}}``; arrays are host numpy unless
         ``device`` (and a reader) put them in HBM."""
-        manifest = await self.read_manifest(step)
-        by_id = {s["shard"]: s for s in manifest["shards"]}
-        want = sorted(by_id) if shards is None else list(shards)
-        with self._op_scope(self.restore_budget_s):
-            trees = await asyncio.gather(*(
-                self.restore_shard(manifest, s, device=device) for s in want
-            ))
-        return dict(zip(want, trees))
+        with telemetry.span("ckpt.restore") as whole:
+            if step is None:
+                with telemetry.span("ckpt.latest_step"):
+                    step = await self.latest_step()
+                if step is None:
+                    raise CheckpointNotFoundError(
+                        f"no published checkpoints under {self.base}")
+            with telemetry.span("ckpt.manifest", step=step):
+                manifest = await self.read_manifest(step)
+            by_id = {s["shard"]: s for s in manifest["shards"]}
+            want = sorted(by_id) if shards is None else list(shards)
+            whole.set(step=step, shards=len(want))
+            with self._op_scope(self.restore_budget_s):
+                trees = await asyncio.gather(*(
+                    self.restore_shard(manifest, s, device=device)
+                    for s in want
+                ))
+            return dict(zip(want, trees))
 
     async def restore_shard(self, manifest: dict, shard: int, *,
                             device=None) -> dict:
@@ -468,70 +524,84 @@ class CheckpointManager:
             f"({last})")
 
     async def _restore_shard_device(self, spec: dict, device) -> dict:
-        """HBM restore: blocks land on ``device`` with on-device per-block
-        CRC verification (hbm_reader), the whole-shard CRC is reconciled
-        from the per-block checksums via the GF(2) combine — no host byte
-        pass — and tensors are aligned word-slices of the block stream
-        (bitcast for 4-byte dtypes, host bounce otherwise)."""
-        import jax
-        import jax.numpy as jnp
-        from tpudfs.tpu.hbm_reader import device_array_to_bytes
+        """HBM restore: blocks land on ``device`` lazily verified (fused
+        rounds where the reader batches), ONE ``confirm`` resolves every
+        block's on-device CRC, the whole-shard CRC is reconciled from the
+        per-block checksums via the GF(2) combine (no host byte pass), and
+        only then are the tensors cut out of the blocks, on the device
+        (:mod:`tpudfs.tpu.ckpt_assemble`)."""
+        from tpudfs.tpu import ckpt_assemble
 
-        sources = [p for p in (spec.get("path"), spec.get("ec_path"))
+        shard = spec["shard"]
+        sources = [(p, kind) for p, kind in ((spec.get("path"), "hot"),
+                                             (spec.get("ec_path"), "ec"))
                    if p is not None]
         blocks = None
         last: Exception | None = None
-        for i, path in enumerate(sources):
+        for i, (path, kind) in enumerate(sources):
             if i > 0:
                 self.stats["degraded_shard_reads"] += 1
                 logger.warning(
                     "shard %s: hot copy unreadable in HBM path (%s); "
-                    "reconstructing from EC cold copy %s",
-                    spec["shard"], last, path)
+                    "reconstructing from EC cold copy %s", shard, last, path)
             try:
-                blocks = await self.reader.read_file_to_device_blocks(
-                    path, verify=True)
-                await self._check_combined_crc(path, spec)
+                with telemetry.span("ckpt.read_shard", shard=shard,
+                                    source=kind) as reading:
+                    blocks = await self.reader.read_file_to_device_blocks(
+                        path, verify="lazy")
+                    reading.set(blocks=len(blocks))
+                with telemetry.span("ckpt.confirm", shard=shard,
+                                    blocks=len(blocks)):
+                    await self.reader.confirm(blocks)
+                with telemetry.span("ckpt.combined_crc", shard=shard):
+                    self._check_combined_crc(
+                        path, spec, [b.source for b in blocks])
                 break
             except _READ_ERRORS as e:
                 blocks, last = None, e
         if blocks is None:
             raise DegradedRestoreError(
-                f"shard {spec['shard']} unrestorable into HBM: every copy "
+                f"shard {shard} unrestorable into HBM: every copy "
                 f"failed ({last})")
-        flat = [b.array.reshape(-1) for b in blocks]
-        words = flat[0] if len(flat) == 1 else jnp.concatenate(flat)
-        out: dict[str, jax.Array] = {}
-        for t in spec["tensors"]:
-            dt = np.dtype(t["dtype"])
-            lo = t["offset"] // 4
-            if dt.itemsize == 4 and t["size"] % 4 == 0:
-                seg = words[lo:lo + t["size"] // 4]
-                arr = jax.lax.bitcast_convert_type(seg, dt) \
-                    .reshape(t["shape"])
-                out[t["name"]] = jax.device_put(arr, device)
-                continue
-            # Non-word dtype: bounce this tensor through the host (rare —
-            # training state is overwhelmingly f32/bf16-pairs/i32).
-            hi = lo + (_align(t["size"]) // 4)
-            raw = device_array_to_bytes(words[lo:hi], t["size"])
-            if crc32c(raw) != t["crc32c"]:
-                raise ChecksumMismatchError(
-                    f"tensor {t['name']!r} failed CRC on host bounce")
-            out[t["name"]] = jax.device_put(
-                np.frombuffer(raw, dtype=dt).reshape(t["shape"]), device)
-        return out
+        tensors = spec["tensors"]
+        with telemetry.span("ckpt.assemble", shard=shard,
+                            tensors=len(tensors), bytes=spec["size"]):
+            tree, on_device, bounced = ckpt_assemble.assemble_shard(
+                tensors, [_dtype_of(t["dtype"]) for t in tensors],
+                spec["size"], blocks, device,
+                self.client.block_size // _ALIGN)
+        self.stats["tensor_bytes_device"] += on_device
+        self.stats["tensor_bytes_host_bounce"] += bounced
+        return tree
 
-    async def _check_combined_crc(self, path: str, spec: dict) -> None:
+    async def warm_restore(self, device, step: int | None = None) -> None:
+        """Pre-compile what ``restore(step, device=device)`` dispatches on
+        the device after its blocks are in: per shard, the gather at every
+        round size the reader's combiner ships and the assembly of the
+        shard's layout (H2D-free, on zeros). The read's own programs are
+        the reader's to warm (``HbmReader.warm_batches``)."""
+        from tpudfs.tpu import ckpt_assemble
+
+        manifest = await self.read_manifest(step)
+        for spec in manifest["shards"]:
+            tensors = spec["tensors"]
+            await asyncio.to_thread(
+                ckpt_assemble.warm_shard, tensors,
+                [_dtype_of(t["dtype"]) for t in tensors], spec["size"],
+                device, self.client.block_size // _ALIGN,
+                getattr(self.reader, "batch_reads", 0))
+
+    @staticmethod
+    def _check_combined_crc(path: str, spec: dict,
+                            blocks: list[dict]) -> None:
         """Whole-shard CRC from the master-recorded per-block checksums via
-        ``crc32c_combine`` — metadata math only, no byte reread. Applies
-        when the block metadata reconciles to the payload length (the hot
-        copy always does; EC block records may carry coded sizes)."""
-        meta = await self.client.get_file_info(path)
-        if meta is None:
-            raise DfsError(f"file not found: {path}")
+        ``crc32c_combine`` — metadata math only, no byte reread. ``blocks``
+        is the master's block list the read itself went by (each
+        ``DeviceBlock.source``). Applies when the block metadata reconciles
+        to the payload length (the hot copy always does; EC block records
+        may carry coded sizes)."""
         crc, total = 0, 0
-        for b in meta.get("blocks", []):
+        for b in blocks:
             size = int(b.get("original_size") or b.get("size") or 0)
             if not size or not b.get("checksum_crc32c"):
                 return  # pre-checksum metadata: per-block verify covers it
