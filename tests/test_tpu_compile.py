@@ -157,6 +157,26 @@ def _rs_decode_block_case(block_bytes: int):
     return build
 
 
+def _ec_round_unstack(topo, one_chip):
+    """A fused round of 16 degraded RS(6,3) blocks of 1 MiB: its stack of
+    survivors as the decode program's 16 operands."""
+    from tpudfs.tpu.read_combiner import _unstack
+    from tpudfs.tpu.rs_pallas import decode_rows
+
+    stack = jax.ShapeDtypeStruct(
+        (16, 6, decode_rows(-(-MIB // 6)), 128), jnp.uint32,
+        sharding=one_chip)
+    return _unstack, (stack,), False, ()
+
+
+def _ec_round_restack(topo, one_chip):
+    """... and its 16 decoded chunk grids as the one array the batched CRC
+    (``batch_block_crc_device_32x1MiB`` above) and a DeviceBatch take."""
+    from tpudfs.tpu.read_combiner import _restack
+
+    return _restack, (_words(CHUNKS_1MIB, one_chip),) * 16, False, ()
+
+
 def _gf_matmul_runtime(topo, one_chip):
     from tpudfs.tpu.rs_pallas import gf_matmul_runtime
 
@@ -211,6 +231,8 @@ CASES = {
     "rs_decode_device_6_3_64MiB_block": _rs_decode,
     "rs_decode_block_6_3_1MiB_block": _rs_decode_block_case(MIB),
     "rs_decode_block_6_3_64MiB_block": _rs_decode_block_case(64 * MIB),
+    "ec_round_unstack_16x6_shards_of_1MiB_blocks": _ec_round_unstack,
+    "ec_round_restack_16x1MiB": _ec_round_restack,
     "gf_matmul_runtime": _gf_matmul_runtime,
     "replicated_write_step_1_device": _write_step_case(
         1, ("collective-permute",)),

@@ -170,6 +170,10 @@ ROUTES: tuple[RouteSpec, ...] = (
             r"tpudfs\.client\.client\.Client\._write_ec_block",
             r"tpudfs\.client\.client\.Client\._read_ec_shards",
             r"tpudfs\.client\.client\.Client\._read_ec_block",
+            # The degraded read into HBM: a fused round's shard fetch and
+            # its upload, and the per-block path it falls back to.
+            r"tpudfs\.tpu\.read_combiner\.ReadCombiner\._fetch_ec",
+            r"tpudfs\.tpu\.read_combiner\.ReadCombiner\._reconstruct",
             r"tpudfs\.tpu\.hbm_reader\.HbmReader\._ec_block_to_device",
             r"tpudfs\.common\.erasure\.encode",
         ),
@@ -178,6 +182,7 @@ ROUTES: tuple[RouteSpec, ...] = (
             "tpudfs/common/erasure.py",
             "tpudfs/common/blocknet.py",
             "tpudfs/tpu/hbm_reader.py",
+            "tpudfs/tpu/read_combiner.py",
         ),
     ),
     RouteSpec(

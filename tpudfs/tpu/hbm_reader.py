@@ -45,18 +45,27 @@ from tpudfs.tpu.device_block import (
     DeviceBlock,
     device_array_to_bytes,
 )
-from tpudfs.tpu.read_combiner import ReadCombiner, chunk_aligned, may_fuse
+from tpudfs.tpu.read_combiner import (
+    ReadCombiner,
+    block_size,
+    chunk_aligned,
+    decode_matrix_on,
+    may_fuse,
+)
 
 __all__ = ["DeviceBlock", "HbmReader", "device_array_to_bytes"]
 
 logger = logging.getLogger(__name__)
 
 #: erasure-coded blocks one reader holds between shard fetch and decode
-#: dispatch (k+m connections and k+m shards on the host each). No more
-#: than the blockport pool keeps idle connections a peer
-#: (``BlockConnPool.MAX_IDLE_PER_PEER``): past that every block reopens
-#: connections (on the v5e's host 4 read 0.263 GB/s, 8 0.284, 16 0.275,
-#: 32 0.19-0.21, 64 0.153: PERF.md, PR 27).
+#: dispatch on the PER-BLOCK path (k+m connections and k+m shards on the
+#: host each), which serves what no fused round takes: a block whose data
+#: shards are all reachable, an eager or unaligned read, a round's
+#: fall-backs and ``confirm``'s re-reads. No more than the blockport pool
+#: keeps idle connections a peer (``BlockConnPool.MAX_IDLE_PER_PEER``):
+#: past that every block reopens connections (on the v5e's host, when this
+#: path carried every degraded block, 4 read 0.263 GB/s, 8 0.284, 16
+#: 0.275, 32 0.19-0.21, 64 0.153: PERF.md, PR 27).
 EC_BLOCKS_IN_FLIGHT = 8
 
 
@@ -75,13 +84,15 @@ class HbmReader:
         self.sweep_blocks = 0
         self.sweep_rounds = 0
         self.sweep_rounds_ready = 0
-        #: erasure-coded blocks read, those of them that lost a data shard
-        #: and were reconstructed on the device, the data shards they
-        #: lacked, and the bytes of shards fetched for all of them.
-        self.ec_blocks = 0
-        self.ec_degraded_blocks = 0
-        self.ec_missing_data_shards = 0
-        self.ec_shard_bytes = 0
+        #: erasure-coded blocks the per-block path read, those of them that
+        #: lost a data shard and were reconstructed on the device, the data
+        #: shards they lacked, and the bytes of shards fetched for all of
+        #: them. The public counts of the same names add what the
+        #: combiners' rounds reconstructed.
+        self._ec_blocks = 0
+        self._ec_degraded_blocks = 0
+        self._ec_missing_data_shards = 0
+        self._ec_shard_bytes = 0
         self._decode_matrices: dict = {}
         self._ec_gate: asyncio.Semaphore | None = None
         self._ec_pool: ThreadPoolExecutor | None = None
@@ -93,11 +104,47 @@ class HbmReader:
             self._combiners[device] = c
         return c
 
+    def _of_rounds(self, counter: str) -> int:
+        return sum(getattr(c, counter) for c in self._combiners.values())
+
+    @property
+    def ec_rounds(self) -> int:
+        """Fused (sub-)rounds of degraded erasure-coded blocks."""
+        return self._of_rounds("ec_rounds")
+
+    @property
+    def ec_round_blocks(self) -> int:
+        """Erasure-coded blocks that came through a fused round: each
+        lost a data shard and was reconstructed on the device."""
+        return self._of_rounds("ec_round_blocks")
+
+    @property
+    def ec_blocks(self) -> int:
+        """Erasure-coded blocks read, in rounds or per block."""
+        return self._ec_blocks + self.ec_round_blocks
+
+    @property
+    def ec_degraded_blocks(self) -> int:
+        """Those of ``ec_blocks`` reconstructed on the device."""
+        return self._ec_degraded_blocks + self.ec_round_blocks
+
+    @property
+    def ec_missing_data_shards(self) -> int:
+        """Data shards the ``ec_degraded_blocks`` lacked."""
+        return self._ec_missing_data_shards \
+            + self._of_rounds("ec_missing_data_shards")
+
+    @property
+    def ec_shard_bytes(self) -> int:
+        """Bytes of shards fetched for the ``ec_blocks``."""
+        return self._ec_shard_bytes + self._of_rounds("ec_shard_bytes")
+
     async def _try_batched(self, block: dict, device,
                            verify: bool | str) -> DeviceBlock | None:
         """Fused-round read when enabled and the block qualifies (lazy
         verify, chunk-aligned; colocated replica OR a remote peer's
-        batched ReadBlocks frame). None -> per-block path."""
+        batched ReadBlocks frame OR, degraded and erasure-coded, its
+        surviving holders' frames). None -> per-block path."""
         if not self.batch_reads or verify != "lazy":
             return None
         return await self._combiner(device).read(block)
@@ -194,8 +241,11 @@ class HbmReader:
     async def _ec_block_to_device(self, block: dict, device,
                                   verify: bool | str = True,
                                   safe_local: bool = False):
-        """EC block → device words. All data shards present: host concat +
-        one upload (the fast path). Degraded: upload the k surviving shards
+        """EC block → device words, the per-block way: what a degraded
+        block that no fused round took falls back to (the combiner's
+        rounds carry the rest), and the only way of a block whose data
+        shards are all reachable. All data shards present: host concat +
+        one upload. Degraded: upload the k surviving shards
         as the uint32 words they already are and reconstruct ON DEVICE
         with ``rs_pallas.rs_decode_block``: one compiled program whatever
         the failure pattern (the inverse matrix is an operand), straight to
@@ -203,13 +253,15 @@ class HbmReader:
         stacking, the upload and the dispatch.
 
         Every shard of a block is a call of its own on a connection of its
-        own, so a file's blocks are not all fetched at once: one 64 MiB
-        RS(6,3) file would hold 576 sockets (16 readers: 9 216) and every
-        shard of every block on the host. ``EC_BLOCKS_IN_FLIGHT`` blocks
-        are between their fetch and their dispatch at a time."""
+        own here (server-verified ``ReadBlock``: this is the path that
+        finds a rotten shard), so a file's blocks are not all fetched at
+        once: one 64 MiB RS(6,3) file would hold 576 sockets (16 readers:
+        9 216) and every shard of every block on the host.
+        ``EC_BLOCKS_IN_FLIGHT`` blocks are between their fetch and their
+        dispatch at a time."""
         k = int(block["ec_data_shards"])
         m = int(block["ec_parity_shards"])
-        size = int(block.get("original_size") or block.get("size") or 0)
+        size = block_size(block)
         device_verify = bool(verify) and bool(block.get("checksum_crc32c"))
         if self._ec_gate is None:
             self._ec_gate = asyncio.Semaphore(EC_BLOCKS_IN_FLIGHT)
@@ -230,8 +282,8 @@ class HbmReader:
             present = tuple(i for i, s in enumerate(shards) if s is not None)
             missing_data = k - sum(i < k for i in present)
             fetching.set(present=len(present), missing_data=missing_data)
-        self.ec_blocks += 1
-        self.ec_shard_bytes += sum(len(shards[i]) for i in present)
+        self._ec_blocks += 1
+        self._ec_shard_bytes += sum(len(shards[i]) for i in present)
         if not missing_data:
             def assemble():
                 # Scatter the shards straight into the padded chunk grid
@@ -267,8 +319,8 @@ class HbmReader:
             raise ChecksumMismatchError(
                 f"EC block {block['block_id']}: shard length mismatch"
             )
-        self.ec_degraded_blocks += 1
-        self.ec_missing_data_shards += missing_data
+        self._ec_degraded_blocks += 1
+        self._ec_missing_data_shards += missing_data
 
         def reconstruct():
             with telemetry.span("ec.assemble", degraded=True):
@@ -296,21 +348,16 @@ class HbmReader:
 
     def _decode_matrix_on(self, device, k: int, m: int, use: tuple):
         """The (k, k) inverse for survivor set ``use`` as a device value,
-        uploaded once per set (84 sets at most for RS(6,3))."""
-        from tpudfs.tpu.rs_pallas import decode_matrix
-
-        key = (device, k, m, use)
-        mat = self._decode_matrices.get(key)
-        if mat is None:
-            mat = self._decode_matrices[key] = jax.device_put(
-                decode_matrix(k, m, use), device)
-        return mat
+        uploaded once per set (the rounds keep theirs the same way)."""
+        return decode_matrix_on(self._decode_matrices, device, k, m, use)
 
     def warm_ec(self, k: int, m: int, block_bytes: int) -> None:
         """Pre-compile the degraded read of RS(k, m) blocks of
         ``block_bytes`` on every device: the decode program (ONE per shard
         length; which servers are down does not matter) and the CRC fold
-        of its output, so no XLA compile lands in a timed window."""
+        of its output for the per-block path, and what a fused round of
+        such blocks dispatches at every bucket up to ``batch_reads``, so
+        no XLA compile lands in a timed window."""
         from tpudfs.common.erasure import shard_len
         from tpudfs.tpu import rs_pallas
 
@@ -324,6 +371,8 @@ class HbmReader:
                 self._decode_matrix_on(device, k, m, use),
                 slen=slen, size=block_bytes)
             jax.block_until_ready(block_crc_device(words))
+            if self.batch_reads and chunk_aligned(block_bytes):
+                self._combiner(device).warm_ec(k, m, block_bytes)
 
     async def _finish_block(self, block: dict, words: jax.Array, size: int,
                             verify: bool | str) -> DeviceBlock:
