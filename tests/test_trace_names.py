@@ -3,7 +3,8 @@ the programs are made: each lowers on the CPU under the module name the
 benchmark's readers match (``crc_verify_roofline_pct``,
 ``rs_decode_roofline_pct``, ``ici_round_ms``, ``ckpt_assemble_roofline_pct``)
 and carries its ``tpudfs.*`` scope in the lowered text. The restore's
-``ckpt.*`` span names, which four readers match, are pinned beside them."""
+``ckpt.*`` span names, which four readers match, and the Grain infeed's six
+``infeed.*`` spans, which four more match, are pinned beside them."""
 
 from __future__ import annotations
 
@@ -178,3 +179,64 @@ async def test_a_device_restore_records_its_ckpt_spans_under_one_restore(
     assert len(by_name["hbm.read_file"]) == 2
     assert {r.parent_id for r in by_name["hbm.read_file"]} == reads
     assert by_name["combiner.fetch"] and by_name["client.get_file_info"]
+
+
+async def test_an_infeed_epoch_records_its_six_spans(tmp_path):
+    """The names ``infeed_fetch_ms_per_record``, ``infeed_gate_wait_pct``,
+    ``infeed_collate_ms_per_batch`` and ``infeed_device_put_ms_per_batch``
+    match, their attrs, and what hangs under what."""
+    import asyncio
+
+    from tests.test_infeed_wds import (N, dataset_on_cluster, decode,
+                                       infeed_threads)
+    from tpudfs.common import telemetry
+    from tpudfs.tpu import grain_infeed as gi
+    from tpudfs.tpu.wds import DfsWdsSource
+
+    async with dataset_on_cluster(tmp_path) as (c, _client, shards):
+        def epoch():
+            telemetry.enable()
+            try:
+                source = DfsWdsSource(list(c.masters), shards)
+                try:
+                    ds = gi.make_dataset(source, batch_size=8,
+                                         shuffle_seed=1, decode=decode)
+                    return len(list(gi.device_iterator(ds))), source.stats()
+                finally:
+                    source.close()
+            finally:
+                telemetry.disable()
+
+        batches, stats = await asyncio.to_thread(epoch)
+    records = telemetry.drain()
+    by_name: dict = {}
+    for r in records:
+        by_name.setdefault(r.name, []).append(r)
+    assert {n for n in by_name if n.startswith("infeed.")} == {
+        "infeed.index", "infeed.fetch", "infeed.gate_wait",
+        "infeed.collate", "infeed.device_put", "infeed.next_wait"}
+    (index,) = by_name["infeed.index"]
+    assert index.attrs["shards"] == 3 and index.attrs["samples"] == N
+    assert index.attrs["range_reads"] >= 3
+    fetches = by_name["infeed.fetch"]
+    assert len(fetches) == N == stats["records"]
+    assert sum(r.attrs["bytes"] for r in fetches) == stats["bytes"]
+    assert len({r.request_id for r in fetches}) == N  # a request each
+    by_id = {r.span_id: r for r in fetches}
+    # The index's metadata fetch waits at the gate too, outside any fetch.
+    waits = [r for r in by_name["infeed.gate_wait"] if r.parent_id in by_id]
+    assert len(waits) == N
+    assert all(by_id[w.parent_id].request_id == w.request_id
+               and by_id[w.parent_id].start_ns <= w.start_ns
+               and w.end_ns <= by_id[w.parent_id].end_ns for w in waits)
+    # A fetch's blockport calls, made on the client's loop, hang under it.
+    assert any(r.parent_id in by_id for r in records
+               if r.name.startswith("blockport."))
+    assert batches == N // 8
+    for name in ("infeed.collate", "infeed.device_put"):
+        assert len(by_name[name]) == batches, name
+        assert all(r.request_id is None for r in by_name[name])
+    assert all(r.attrs == {"records": 8, "bytes": 8 * (10_000 + 4 + 4)}
+               for r in by_name["infeed.collate"])
+    assert len(by_name["infeed.next_wait"]) == batches + 1  # and the end
+    assert infeed_threads() == []  # nothing outlives the pipeline

@@ -1,8 +1,8 @@
 """WebDataset-on-DFS training loop (BASELINE config 5, the WDS half).
 
 DFS tar shards -> DfsWdsSource (tar-header index, per-member range reads)
--> grain shuffle/batch with a decode map -> sharded device batches ->
-pjit'd SGD on a small MLP classifier. Asserts the model actually LEARNS
+-> ``grain_infeed.make_dataset`` (shuffle, decode map, prefetch, batch) ->
+sharded device batches -> pjit'd SGD on a small MLP classifier. Asserts the model actually LEARNS
 (train accuracy) — the bytes reaching the accelerators are the right
 samples with the right labels, through tar framing, DFS striping, and
 3x replication.
@@ -82,11 +82,6 @@ async def test_wds_training_loop_learns(tmp_path):
         def run_training():
             # Built and driven in a worker thread: the in-process cluster
             # serves on the MAIN event loop, which must stay unblocked.
-            import grain
-
-            if not hasattr(grain, "MapDataset"):
-                import grain.python as grain  # namespace-package install
-
             source = DfsWdsSource(list(c.masters), shards)
             try:
                 assert len(source) == SAMPLES
@@ -96,12 +91,10 @@ async def test_wds_training_loop_learns(tmp_path):
                 x0, y0 = decode_sample(s0, image_shape=(FEATURES,))
                 assert x0.shape == (FEATURES,) and 0 <= int(y0) < CLASSES
 
-                ds = (
-                    grain.MapDataset.source(source)
-                    .shuffle(seed=7)
-                    .map(lambda s: decode_sample(s, image_shape=(FEATURES,)))
-                    .batch(BATCH)
-                )
+                ds = gi.make_dataset(
+                    source, batch_size=BATCH, shuffle_seed=7,
+                    decode=lambda s: decode_sample(
+                        s, image_shape=(FEATURES,)))
 
                 k1, k2 = jax.random.split(jax.random.PRNGKey(0))
                 params = {

@@ -200,6 +200,10 @@ class Client:
         self._local_probe_lock = asyncio.Lock()
         #: Blocks served via the short-circuit path (observability/tests).
         self.local_read_blocks = 0
+        #: ``ReadBlock`` calls ``_read_block_range`` sent: one a replica
+        #: tried, so a hedge and every fallback count beside the primary
+        #: (the infeed's ``stats()["range_reads"]`` reads it).
+        self.read_block_calls = 0
         #: Transparent coalescing of concurrent get_file_info calls into
         #: BatchGetFileInfo RPCs (see get_file_info).
         self.meta_coalescing = True
@@ -1144,6 +1148,7 @@ class Client:
             # caller-provided buffer, so the winner's result is its own
             # allocation even when a cancelled hedge raced it.
             sink = None
+            self.read_block_calls += 1
 
             def _scatter(header: dict, plen: int):
                 nonlocal sink

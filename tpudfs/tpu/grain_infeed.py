@@ -11,12 +11,27 @@ buffers. Here the DFS is a first-class `grain` random-access data source:
   reads, EC degraded reads all apply). Grain calls ``__getitem__`` from its
   prefetch workers/threads; the asyncio client runs on a dedicated event-loop
   thread and calls bridge via ``run_coroutine_threadsafe``.
-- ``make_dataset`` — the standard grain pipeline: source -> (shard by JAX
-  process) -> shuffle -> batch, yielding numpy batches ready for
-  ``jax.device_put`` / sharded placement in the training loop.
-- ``device_iterator`` — wraps the dataset iterator and lands every batch on
-  device (optionally a sharded jax.Array over a mesh axis) so the training
-  step consumes HBM-resident arrays.
+- ``make_dataset`` — the one supported way to build the pipeline, for
+  either source (``DfsRecordSource``, or ``wds.DfsWdsSource`` + a decode
+  map): source -> (shard by JAX process) -> shuffle -> repeat -> decode ->
+  ``to_iter_dataset()`` -> batch. Grain's prefetch threads (its default
+  ``ReadOptions``: 16 threads, 500 records) fetch RECORDS
+  concurrently under the overload governor's gate; batches are stacked
+  after the prefetch, in the thread that iterates the dataset.
+- ``device_iterator`` — lands every batch on the device (optionally a
+  sharded jax.Array over a mesh axis) from a thread of its own, so the
+  ``device_put`` of batch n overlaps the fetch and stacking of batch n+1;
+  the training step takes HBM-resident arrays from a bounded hand-off.
+
+The fetches run on threads of the caller's process, and a full pass of
+Python's collector holds them all with the GIL: a trainer should
+``gc.collect(); gc.freeze()`` once its set-up is over (docs/operations.md
+"A trainer's heap").
+
+Spans (``infeed.index``, ``infeed.fetch`` with its child
+``infeed.gate_wait``, ``infeed.collate``, ``infeed.device_put``,
+``infeed.next_wait``) and the sources' ``stats()`` are listed in
+docs/operations.md "Tracing".
 
 ``tpudfs.tpu.infeed.DfsInfeed`` remains as the grain-free fallback prefetcher.
 """
@@ -25,6 +40,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import queue
 import threading
 import time
 from typing import Any, Callable, Sequence
@@ -48,6 +64,11 @@ except Exception as e:  # pragma: no cover - grain is installed in this image
     _HAVE_GRAIN = False
 
 from tpudfs.client.client import Client, OverloadedError
+from tpudfs.common import telemetry
+
+#: Batches ``device_iterator`` holds on the device, put and not yet taken by
+#: the consumer: the transfer of one overlaps the stacking of the next.
+HANDOFF_DEPTH = 2
 
 
 class _AdaptiveGate:
@@ -59,20 +80,22 @@ class _AdaptiveGate:
         self._cond = threading.Condition()
         self._limit = limit
         self._active = 0
+        #: most fetches ever inside the gate at once
+        self.max_active = 0
 
     def set_limit(self, n: int) -> None:
         with self._cond:
             self._limit = max(1, n)
             self._cond.notify_all()
 
-    def __enter__(self):
+    def acquire(self) -> None:
         with self._cond:
             while self._active >= self._limit:
                 self._cond.wait()
             self._active += 1
-        return self
+            self.max_active = max(self.max_active, self._active)
 
-    def __exit__(self, *exc):
+    def release(self) -> None:
         with self._cond:
             self._active -= 1
             self._cond.notify_all()
@@ -98,6 +121,9 @@ class _OverloadGovernor:
         self.max_concurrency = max_concurrency
         self.gate = _AdaptiveGate(max_concurrency)
         self.level = 0
+        #: fetches the cluster shed (each stepped the ladder or found it at
+        #: its last rung)
+        self.sheds = 0
         self._streak = 0
         self._saved_hedge: float | None = None
 
@@ -117,6 +143,7 @@ class _OverloadGovernor:
         """Step down one level; returns the backoff to sleep before retry."""
         with self._lock:
             self._streak = 0
+            self.sheds += 1
             if self.level < self.MAX_LEVEL:
                 self.level += 1
                 self._apply(client)
@@ -150,6 +177,10 @@ class _ClientLoop:
 
     def __init__(self, master_addrs: Sequence[str], client_kwargs: dict):
         self._loop = asyncio.new_event_loop()
+        # Calls in flight, so that ``close`` can end them: a prefetch
+        # thread must not wait out its timeout on a loop that has stopped.
+        self._pending: set = set()
+        self._pending_lock = threading.Lock()
         self._thread = threading.Thread(
             target=self._loop.run_forever, daemon=True,
             name="tpudfs-grain-client",
@@ -168,7 +199,13 @@ class _ClientLoop:
         return Client(addrs, **kwargs)
 
     def run(self, coro, timeout: float = 120.0) -> Any:
-        fut = asyncio.run_coroutine_threadsafe(coro, self._loop)
+        try:
+            fut = asyncio.run_coroutine_threadsafe(coro, self._loop)
+        except RuntimeError:  # the loop is closed: nobody will await it
+            coro.close()
+            raise
+        with self._pending_lock:
+            self._pending.add(fut)
         try:
             return fut.result(timeout)
         except TimeoutError:
@@ -176,8 +213,15 @@ class _ClientLoop:
             # RPCs in flight) after the caller has given up on it.
             fut.cancel()
             raise
+        finally:
+            with self._pending_lock:
+                self._pending.discard(fut)
 
     def close(self) -> None:
+        with self._pending_lock:
+            in_flight = list(self._pending)
+        for fut in in_flight:
+            fut.cancel()
         try:
             self.run(self.client.close(), timeout=10.0)
         except Exception:
@@ -227,10 +271,25 @@ class DfsSourceBase:
         # Held only on sync grain-worker threads; see class docstring.
         self._lock = threading.Lock()
         self._cl: _ClientLoop | None = None
+        self._closed = False
         self._governor = _OverloadGovernor()
+        # Immutable block layout per path, cached so record fetches skip the
+        # per-read master GetFileInfo round-trip (read_meta_range fast path).
+        self._metas: dict[str, dict] = {}
+        self._counts = dict.fromkeys(("records", "bytes"), 0)
+        # Leaf lock of ``stats()``'s two sums: held for the adds alone,
+        # on sync threads only, like ``_lock``.
+        self._counts_lock = threading.Lock()
+        # Block reads the client had issued when the index was built: the
+        # tar-header walk's.
+        self._index_block_reads = 0
 
     def _client_loop(self) -> _ClientLoop:
         with self._lock:
+            if self._closed:
+                # A prefetch thread that outlived its pipeline: no new
+                # client for it.
+                raise RuntimeError(f"{self!r} is closed")
             if self._cl is None:
                 self._cl = _ClientLoop(self.master_addrs, self.client_kwargs)
             return self._cl
@@ -242,7 +301,10 @@ class DfsSourceBase:
         """Run a fetch under the overload governor: gate concurrency, and on
         a shed fetch degrade (hedges off, then narrower gate), back off and
         retry — a training job should ride out overload, not crash on it."""
-        with self._governor.gate:
+        gate = self._governor.gate
+        with telemetry.span("infeed.gate_wait"):
+            gate.acquire()
+        try:
             for _ in range(self._OVERLOAD_RETRIES):
                 try:
                     result = cl.run(coro_factory())
@@ -254,9 +316,54 @@ class DfsSourceBase:
                     self._governor.on_success(cl.client)
                     return result
             raise last
+        finally:
+            gate.release()
+
+    def _fetch_range(self, path: str, offset: int, length: int) -> bytes:
+        """One record: ``length`` bytes at ``offset`` of ``path``, through
+        the governed fetch (gate wait, the hop onto the client's loop, one
+        ranged ``ReadBlock`` a block touched) against the block layout
+        cached at index time (``read_meta_range``: no master round-trip).
+        Both sources' ``__getitem__`` come through here, on Grain's
+        prefetch threads."""
+        cl = self._client_loop()
+        meta = self._metas[path]
+        with telemetry.span("infeed.fetch", bytes=length):
+            data = self._governed_run(
+                cl, lambda: cl.client.read_meta_range(meta, offset, length))
+        with self._counts_lock:
+            self._counts["records"] += 1
+            self._counts["bytes"] += len(data)
+        return data
+
+    def _issued(self) -> int:
+        """Block reads the source's client has issued, counted where each
+        is issued: every ``ReadBlock`` it sent (a hedge, and every replica
+        tried after a failure, beside the primary) and every block it read
+        off a colocated replica's disk."""
+        cl = self._cl  # kept by ``close``: a closed source still says
+        if cl is None:
+            return 0
+        return cl.client.read_block_calls + cl.client.local_read_blocks
+
+    def stats(self) -> dict:
+        """What the source did so far, in this process: ``records`` and
+        ``bytes`` fetched, ``range_reads`` (the block reads its client
+        issued for them, ``_issued`` less the index walk's), the most
+        fetches ever in flight at once (Grain's prefetch threads under the
+        governor's gate), fetches the cluster shed and the governor's
+        level now (0: hedges on, the whole gate)."""
+        with self._counts_lock:
+            out = dict(self._counts)
+        out["range_reads"] = self._issued() - self._index_block_reads
+        out["max_in_flight"] = self._governor.gate.max_active
+        out["sheds"] = self._governor.sheds
+        out["governor_level"] = self._governor.level
+        return out
 
     def _fetch_metas(self, paths: Sequence[str]) -> list[dict]:
-        """File metadata for every path, failing on missing files."""
+        """File metadata for every path, failing on missing files; kept
+        for ``_fetch_range``."""
         cl = self._client_loop()
 
         async def metas(client: Client) -> list[dict]:
@@ -268,18 +375,22 @@ class DfsSourceBase:
                     raise FileNotFoundError(f"DFS file not found: {p}")
             return out
 
-        return self._governed_run(cl, lambda: metas(cl.client))
+        found = self._governed_run(cl, lambda: metas(cl.client))
+        self._metas.update(zip(paths, found))
+        return found
 
     def close(self) -> None:
+        """Final: fetches in flight are cancelled, later ones raise."""
         with self._lock:
-            if self._cl is not None:
+            if self._cl is not None and not self._closed:
                 self._cl.close()
-                self._cl = None
+            self._closed = True
 
     def __getstate__(self):
         state = self.__dict__.copy()
         state["_cl"] = None
         state["_lock"] = None
+        state["_counts_lock"] = None
         state["_governor"] = None  # holds a Condition; rebuilt per process
         return state
 
@@ -288,7 +399,10 @@ class DfsSourceBase:
         # Fresh lock per unpickled worker process — same sync-only
         # discipline as the one dropped in __getstate__.
         self._lock = threading.Lock()
+        self._counts_lock = threading.Lock()
         self._governor = _OverloadGovernor()
+        # This process's client starts at 0: the walk was the parent's.
+        self._index_block_reads = 0
 
 
 class DfsRecordSource(DfsSourceBase):
@@ -323,9 +437,6 @@ class DfsRecordSource(DfsSourceBase):
         self.dtype = dtype
         # (path, base_offset) per record, built once from file metadata.
         self._index: list[tuple[str, int]] = []
-        # Immutable block layout per path, cached so record fetches skip the
-        # per-read master GetFileInfo round-trip (read_meta_range fast path).
-        self._metas: dict[str, dict] = {}
         try:
             self._build_index()
         except BaseException:
@@ -335,11 +446,13 @@ class DfsRecordSource(DfsSourceBase):
             raise
 
     def _build_index(self) -> None:
-        for path, meta in zip(self.paths, self._fetch_metas(self.paths)):
-            self._metas[path] = meta
-            for off in range(0, int(meta["size"]) - self.record_bytes + 1,
-                             self.record_bytes):
-                self._index.append((path, off))
+        with telemetry.span("infeed.index", shards=len(self.paths)) as sp:
+            for path, meta in zip(self.paths,
+                                  self._fetch_metas(self.paths)):
+                for off in range(0, int(meta["size"]) - self.record_bytes
+                                 + 1, self.record_bytes):
+                    self._index.append((path, off))
+            sp.set(samples=len(self._index), range_reads=0)
 
     # ------------------------------------------------------- grain protocol
 
@@ -348,13 +461,7 @@ class DfsRecordSource(DfsSourceBase):
 
     def __getitem__(self, record_key: int) -> np.ndarray:
         path, off = self._index[record_key]
-        cl = self._client_loop()
-        data = self._governed_run(
-            cl,
-            lambda: cl.client.read_meta_range(
-                self._metas[path], off, self.record_bytes
-            ),
-        )
+        data = self._fetch_range(path, off, self.record_bytes)
         return np.frombuffer(data, dtype=self.dtype)
 
     def __repr__(self) -> str:
@@ -364,18 +471,43 @@ class DfsRecordSource(DfsSourceBase):
         )
 
 
+def _collate(samples: Sequence[Any]) -> Any:
+    """``batch``'s stacking, as Grain's default does it (every leaf of the
+    samples stacked along a new first dimension), under a span: the host
+    staging copy of a batch."""
+    import jax
+
+    with telemetry.span("infeed.collate", request=None,
+                        records=len(samples)) as sp:
+        batch = jax.tree.map(lambda *leaves: np.stack(leaves), *samples)
+        sp.set(bytes=sum(leaf.nbytes for leaf in jax.tree.leaves(batch)))
+    return batch
+
+
 def make_dataset(
-    source: DfsRecordSource,
+    source: DfsSourceBase,
     *,
     batch_size: int,
     shuffle_seed: int | None = None,
     shard_by_process: bool = True,
     num_epochs: int | None = 1,
+    decode: Callable[[Any], Any] | None = None,
 ):
-    """Build the grain pipeline: source -> shard -> shuffle -> batch.
+    """Build the grain pipeline for either source: source -> shard ->
+    shuffle -> repeat -> decode -> prefetch -> batch.
 
-    Returns a ``grain.MapDataset``/``IterDataset`` yielding numpy batches of
-    shape (batch_size, record_bytes // dtype.itemsize)."""
+    ``source`` is a ``DfsRecordSource`` (elements are arrays already) or a
+    ``wds.DfsWdsSource`` with ``decode`` the per-sample map from its member
+    dicts to arrays (e.g. ``wds.decode_sample``). The prefetch is Grain's
+    own (its default ``ReadOptions``: 16 threads, the governor's gate, and
+    a buffer of 500 records) and fetches RECORDS concurrently under the
+    gate; batches are stacked after it, so 16 records, not 16 batches, are
+    in flight. ``repeat`` comes before ``batch``: batches cross epoch ends,
+    every epoch is a fresh permutation (``reseed_each_epoch``) and the
+    order is a function of the seed alone.
+
+    Returns a ``grain.IterDataset`` yielding numpy batches of
+    ``batch_size`` elements (a partial last batch is dropped)."""
     if not _HAVE_GRAIN:
         raise RuntimeError("grain is not installed; use tpudfs.tpu.infeed")
     ds = grain.MapDataset.source(source)
@@ -389,7 +521,10 @@ def make_dataset(
         ds = ds.repeat()
     elif num_epochs > 1:
         ds = ds.repeat(num_epochs)
-    return ds.batch(batch_size, drop_remainder=True)
+    if decode is not None:
+        ds = ds.map(decode)
+    return ds.to_iter_dataset().batch(
+        batch_size, drop_remainder=True, batch_fn=_collate)
 
 
 def device_iterator(dataset, devices=None, mesh=None, axis: str | None = None):
@@ -399,16 +534,61 @@ def device_iterator(dataset, devices=None, mesh=None, axis: str | None = None):
     - with ``mesh``+``axis``: batches become jax.Arrays sharded over that
       mesh axis (batch dim split across devices) — the data-parallel infeed
       layout for a pjit training step.
-    """
+
+    A thread of its own takes batches from ``dataset``, puts each on the
+    device and waits for the transfer, and hands it over through a queue of
+    ``HANDOFF_DEPTH``: the transfer of batch n overlaps the fetch and
+    stacking of batch n+1, and what the caller takes is on the device."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     if mesh is not None:
-        axis = axis or mesh.axis_names[0]
-        sharding = NamedSharding(mesh, P(axis))
-        for batch in dataset:
-            yield jax.device_put(batch, sharding)
+        where = NamedSharding(mesh, P(axis or mesh.axis_names[0]))
     else:
-        device = (devices or jax.devices())[0]
-        for batch in dataset:
-            yield jax.device_put(batch, device)
+        where = (devices or jax.devices())[0]
+    handoff: queue.Queue = queue.Queue(maxsize=HANDOFF_DEPTH)
+    stop = threading.Event()
+    end = object()
+
+    def offer(item) -> bool:
+        while not stop.is_set():
+            try:
+                handoff.put(item, timeout=0.1)
+                return True
+            except queue.Full:
+                pass
+        return False
+
+    def produce() -> None:
+        batches = iter(dataset)
+        try:
+            for batch in batches:
+                with telemetry.span("infeed.device_put", request=None):
+                    landed = jax.block_until_ready(
+                        jax.device_put(batch, where))
+                if not offer(landed):
+                    return
+            offer(end)
+        except BaseException as e:  # handed to the consumer, which raises
+            offer(e)
+        finally:
+            # Grain's prefetch threads stop with their iterator.
+            close = getattr(batches, "close", None)
+            if close is not None:
+                close()
+
+    producer = threading.Thread(target=produce, daemon=True,
+                                name="tpudfs-infeed-put")
+    producer.start()
+    try:
+        while True:
+            with telemetry.span("infeed.next_wait", request=None):
+                item = handoff.get()
+            if item is end:
+                return
+            if isinstance(item, BaseException):
+                raise item
+            yield item
+    finally:
+        stop.set()
+        producer.join(timeout=30.0)

@@ -37,6 +37,7 @@ from typing import Any, Iterable, Sequence
 import numpy as np
 
 from tpudfs.client.client import Client
+from tpudfs.common import telemetry
 from tpudfs.tpu.grain_infeed import DfsSourceBase
 
 _TAR_BLOCK = 512
@@ -107,14 +108,18 @@ class DfsWdsSource(DfsSourceBase):
     """Grain random-access source over WebDataset tar shards in DFS.
 
     ``__getitem__(i)`` returns ``{"__key__": key, <ext>: bytes, ...}`` for
-    sample ``i`` in global (shard-major, in-tar) order.
+    sample ``i`` in global (shard-major, in-tar) order, through the base's
+    governed fetch (the overload governor's gate and ladder, ``stats()``).
+    ``tenant`` is the identity the reads are charged to, as on
+    ``DfsRecordSource``. Build the pipeline with
+    ``grain_infeed.make_dataset(source, decode=decode_sample, ...)``.
     """
 
     def __init__(self, master_addrs: Sequence[str], shards: Sequence[str],
-                 client_kwargs: dict | None = None):
-        super().__init__(master_addrs, client_kwargs)
+                 client_kwargs: dict | None = None,
+                 tenant: str | None = None):
+        super().__init__(master_addrs, client_kwargs, tenant=tenant)
         self.shards = list(shards)
-        self._metas: dict[str, dict] = {}
         #: per sample: (key, [(ext, shard_path, data_off, size), ...])
         self._samples: list[tuple[str, list[tuple[str, str, int, int]]]] = []
         try:
@@ -127,8 +132,6 @@ class DfsWdsSource(DfsSourceBase):
 
     def _build_index(self) -> None:
         cl = self._client_loop()
-        for path, meta in zip(self.shards, self._fetch_metas(self.shards)):
-            self._metas[path] = meta
 
         async def index_all(client: Client) -> list[list]:
             # Shards index independently and concurrently; results are
@@ -138,8 +141,15 @@ class DfsWdsSource(DfsSourceBase):
                 for path in self.shards
             )))
 
-        for shard_samples in cl.run(index_all(cl.client)):
-            self._samples.extend(shard_samples)
+        with telemetry.span("infeed.index", shards=len(self.shards)) as sp:
+            self._fetch_metas(self.shards)
+            for shard_samples in cl.run(index_all(cl.client)):
+                self._samples.extend(shard_samples)
+            # The walk's block reads, as the client counted them: kept out
+            # of ``stats()``'s ``range_reads``, which are the records'.
+            self._index_block_reads = self._issued()
+            sp.set(samples=len(self._samples),
+                   range_reads=self._index_block_reads)
 
     #: readahead window for the tar-header walk: small members mean many
     #: headers per span (one range read covers dozens of samples).
@@ -202,16 +212,13 @@ class DfsWdsSource(DfsSourceBase):
 
     def __getitem__(self, i: int) -> dict[str, Any]:
         key, members = self._samples[i]
-        cl = self._client_loop()
         # A sample's members are CONSECUTIVE tar entries of one shard
         # (write_wds_shards never splits a sample), so one contiguous
         # range read covers them all; slice locally.
         path = members[0][1]
         lo = min(off for _e, _p, off, _s in members)
         hi = max(off + size for _e, _p, off, size in members)
-        blob = cl.run(
-            cl.client.read_meta_range(self._metas[path], lo, hi - lo)
-        )
+        blob = self._fetch_range(path, lo, hi - lo)
         out: dict[str, Any] = {"__key__": key}
         for ext, _path, off, size in members:
             out[ext] = blob[off - lo : off - lo + size]
