@@ -42,6 +42,8 @@
 //   void     tpudfs_dataplane_invalidate(handle, block_id) // cache drop
 //   void     tpudfs_dataplane_stats(handle, uint64_t out[6])
 //               // writes, reads, forwards, errors, cache_hits, cache_misses
+//   void     tpudfs_dataplane_read_stats(handle, uint64_t out[14])
+//               // the read path's stage clocks (Engine::read_stage_stats)
 //   void     tpudfs_dataplane_set_qos(handle, cfg, len)
 //               // push the QosShedder config (msgpack flat map from
 //               // resilience.qos_wire_config) — admission/fair-queue/
@@ -1490,6 +1492,35 @@ class Engine {
     out[7] = stream_aborts_.load();
   }
 
+  // The read path's stage clocks — slot order MUST match the Python
+  // service's _read_stats keys (service.py read_stage_stats zips them).
+  // ReadBlock: calls, bytes sent, read_ns (handler start to the response
+  // header built: cache lookup, stat, pread + sidecar verify, or the
+  // cache copy), send_ns (send_frame), NOT_FOUND answers, calls served
+  // from the block cache, QoS admission wait. ReadBlocks: frames, slots,
+  // bytes, read_ns (handler start through the size estimate and every
+  // slot's pread to the header built: all the client waits for before
+  // the header), send_ns (the frame's send_frame, paced by the client's
+  // receive), slots answered -1, QoS admission wait. Each connection is
+  // served by a thread of its own, so there is no queue on this side but
+  // admission (0 while QoS is off).
+  void read_stage_stats(uint64_t out[14]) const {
+    out[0] = rb_calls_.load();
+    out[1] = rb_bytes_.load();
+    out[2] = rb_read_ns_.load();
+    out[3] = rb_send_ns_.load();
+    out[4] = rb_not_found_.load();
+    out[5] = rb_cache_calls_.load();
+    out[6] = rb_admit_ns_.load();
+    out[7] = rbs_frames_.load();
+    out[8] = rbs_slots_.load();
+    out[9] = rbs_bytes_.load();
+    out[10] = rbs_read_ns_.load();
+    out[11] = rbs_send_ns_.load();
+    out[12] = rbs_missing_.load();
+    out[13] = rbs_admit_ns_.load();
+  }
+
   // ------------------------------------------------------------ qos plane
 
   // Parse + install a QoS config pushed from Python (resilience.
@@ -1699,12 +1730,17 @@ class Engine {
       if (qos_.enabled()) {
         std::string detail;
         double retry_after = 0.0;
+        const uint64_t t_queued = now_ns();
         if (!qos_.acquire(tenant, has_db, budget, &detail, &retry_after)) {
           respond_shed(s, tenant, detail, retry_after);
           continue;
         }
         admitted = true;
         t_admit = now_ns();
+        if (method == "ReadBlock")
+          rb_admit_ns_.fetch_add(t_admit - t_queued);
+        else if (method == "ReadBlocks")
+          rbs_admit_ns_.fetch_add(t_admit - t_queued);
       }
       bool keep = true;
       if (method == "WriteBlock" || method == "ReplicateBlock") {
@@ -2648,13 +2684,47 @@ class Engine {
 
   // --------------------------------------------------------------- read
 
+  // "_rt" in a read's request asks for its read_ns back as "_rns" in the
+  // response header (the client sets it while its tracing is on, and lays
+  // it on its blockport.wait_header span); without it the frames are the
+  // same bytes as ever.
   void handle_read(Stream& s, std::map<std::string, Value>& h) {
+    const uint64_t t0 = now_ns();
     reads_.fetch_add(1);
+    rb_calls_.fetch_add(1);
+    const bool timed = h.count("_rt") != 0;
+    auto fail = [&](const std::string& code, const std::string& msg) {
+      if (code == "NOT_FOUND") rb_not_found_.fetch_add(1);
+      rb_read_ns_.fetch_add(now_ns() - t0);
+      respond_err(s, code, msg);
+    };
+    auto reply = [&](const uint8_t* data, uint64_t n, uint64_t total) {
+      const uint64_t read_ns = now_ns() - t0;
+      Writer w;
+      w.map_head(timed ? 5 : 4);
+      w.str("ok");
+      w.boolean(true);
+      w.str("_d");
+      w.uint(1);
+      w.str("bytes_read");
+      w.uint(n);
+      w.str("total_size");
+      w.uint(total);
+      if (timed) {
+        w.str("_rns");
+        w.uint(read_ns);
+      }
+      rb_read_ns_.fetch_add(read_ns);
+      const uint64_t t1 = now_ns();
+      send_frame(s, w.out, data, n);
+      rb_send_ns_.fetch_add(now_ns() - t1);
+      rb_bytes_.fetch_add(n);
+    };
     const std::string block_id =
         h.count("block_id") ? h["block_id"].s : "";
     if (block_id.empty() || block_id[0] == '.' ||
         block_id.find('/') != std::string::npos) {
-      respond_err(s, "INVALID_ARGUMENT", "bad block id");
+      fail("INVALID_ARGUMENT", "bad block id");
       return;
     }
     uint64_t offset =
@@ -2665,26 +2735,17 @@ class Engine {
     // when cached; writes/corruption findings invalidate). Range reads
     // slice the cached block.
     if (CacheData cached = cache_get(block_id)) {
+      rb_cache_calls_.fetch_add(1);
       uint64_t total = cached->size();
       if (offset >= total && !(offset == 0 && total == 0)) {
-        respond_err(s, "OUT_OF_RANGE",
-                    "Offset " + std::to_string(offset) +
-                        " exceeds block size " + std::to_string(total));
+        fail("OUT_OF_RANGE", "Offset " + std::to_string(offset) +
+                                 " exceeds block size " +
+                                 std::to_string(total));
         return;
       }
       uint64_t want = length == 0 ? total - offset
                                   : std::min(length, total - offset);
-      Writer w;
-      w.map_head(4);
-      w.str("ok");
-      w.boolean(true);
-      w.str("_d");
-      w.uint(1);
-      w.str("bytes_read");
-      w.uint(want);
-      w.str("total_size");
-      w.uint(total);
-      send_frame(s, w.out, cached->data() + offset, want);
+      reply(cached->data() + offset, want, total);
       return;
     }
     const uint64_t gen = cache_gen(block_id);  // before the pread
@@ -2694,20 +2755,20 @@ class Engine {
       if (!cold_.empty()) {
         data_path = cold_ + "/" + block_id;
         if (::stat(data_path.c_str(), &st) != 0) {
-          respond_err(s, "NOT_FOUND", "Block not found");
+          fail("NOT_FOUND", "Block not found");
           return;
         }
       } else {
-        respond_err(s, "NOT_FOUND", "Block not found");
+        fail("NOT_FOUND", "Block not found");
         return;
       }
     }
     uint64_t total = static_cast<uint64_t>(st.st_size);
     if (length == 0) length = total > offset ? total - offset : 0;
     if (offset >= total && !(offset == 0 && total == 0)) {
-      respond_err(s, "OUT_OF_RANGE",
-                  "Offset " + std::to_string(offset) +
-                      " exceeds block size " + std::to_string(total));
+      fail("OUT_OF_RANGE", "Offset " + std::to_string(offset) +
+                               " exceeds block size " +
+                               std::to_string(total));
       return;
     }
     uint64_t want = std::min(length, total - offset);
@@ -2728,20 +2789,19 @@ class Engine {
       cache_invalidate(block_id);
       bool full = offset == 0 && want == total;
       if (full) {
-        respond_err(s, "DATA_LOSS",
-                    "Data corruption detected on native read");
+        fail("DATA_LOSS", "Data corruption detected on native read");
         return;
       }
       rc = tpudfs_block_read_verify(data_path.c_str(), meta_path.c_str(),
                                     offset, want, buf.data(), 0, chunk_);
       if (rc < 0) {
-        respond_err(s, "INTERNAL", "read failed after verify failure");
+        fail("INTERNAL", "read failed after verify failure");
         return;
       }
     } else if (rc < 0) {
-      respond_err(s, rc == -ENOENT ? "NOT_FOUND" : "INTERNAL",
-                  rc == -ENOENT ? "Block not found"
-                                : "native read error " + std::to_string(-rc));
+      fail(rc == -ENOENT ? "NOT_FOUND" : "INTERNAL",
+           rc == -ENOENT ? "Block not found"
+                         : "native read error " + std::to_string(-rc));
       return;
     }
     CacheData keep;
@@ -2756,18 +2816,8 @@ class Engine {
         cache_put(block_id, keep, gen);
       }
     }
-    Writer w;
-    w.map_head(4);
-    w.str("ok");
-    w.boolean(true);
-    w.str("_d");
-    w.uint(1);
-    w.str("bytes_read");
-    w.uint(static_cast<uint64_t>(rc));
-    w.str("total_size");
-    w.uint(total);
-    send_frame(s, w.out, keep ? keep->data() : buf.data(),
-               static_cast<uint64_t>(rc));
+    reply(keep ? keep->data() : buf.data(), static_cast<uint64_t>(rc),
+          total);
   }
 
   // Batched UNVERIFIED full reads: header {"block_ids": [...]}; response
@@ -2780,6 +2830,8 @@ class Engine {
   // routes mismatches to the per-block VERIFIED path, which detects the
   // rot, reports it, and triggers recovery.
   void handle_read_batch(Stream& s, std::map<std::string, Value>& h) {
+    const uint64_t t0 = now_ns();
+    const bool timed = h.count("_rt") != 0;
     const std::vector<std::string> ids =
         h.count("block_ids") ? h["block_ids"].astr
                              : std::vector<std::string>{};
@@ -2872,23 +2924,40 @@ class Engine {
       // reads that trust cache hits. (The streaming sweep shouldn't wash
       // the cache anyway.)
     }
+    const uint64_t read_ns = now_ns() - t0;
     Writer w;
-    w.map_head(3);
+    w.map_head(timed ? 4 : 3);
     w.str("ok");
     w.boolean(true);
     w.str("_d");
     w.uint(1);
     w.str("sizes");
+    uint64_t missing = 0;
     {
       // Writer::aint clamps negatives to 0; hand-encode -1 slots.
       if (sizes.size() < 16) w.raw(0x90 | sizes.size());
       else { w.raw(0xdc); w.be(sizes.size(), 2); }
       for (int64_t v : sizes) {
-        if (v < 0) w.raw(0xff);  // negative fixint -1
-        else w.uint(static_cast<uint64_t>(v));
+        if (v < 0) {
+          w.raw(0xff);  // negative fixint -1
+          missing++;
+        } else {
+          w.uint(static_cast<uint64_t>(v));
+        }
       }
     }
+    if (timed) {
+      w.str("_rns");
+      w.uint(read_ns);
+    }
+    rbs_frames_.fetch_add(1);
+    rbs_slots_.fetch_add(ids.size());
+    rbs_missing_.fetch_add(missing);
+    rbs_bytes_.fetch_add(payload.size());
+    rbs_read_ns_.fetch_add(read_ns);
+    const uint64_t t1 = now_ns();
     send_frame(s, w.out, payload.data(), payload.size());
+    rbs_send_ns_.fetch_add(now_ns() - t1);
   }
 
   std::string host_, hot_, cold_;
@@ -2906,6 +2975,10 @@ class Engine {
   std::atomic<uint64_t> stream_net_ns_{0}, stream_crc_ns_{0},
       stream_disk_ns_{0}, stream_fanout_ns_{0}, stream_frames_{0},
       streams_started_{0}, stream_bytes_{0}, stream_aborts_{0};
+  std::atomic<uint64_t> rb_calls_{0}, rb_bytes_{0}, rb_read_ns_{0},
+      rb_send_ns_{0}, rb_not_found_{0}, rb_cache_calls_{0}, rb_admit_ns_{0};
+  std::atomic<uint64_t> rbs_frames_{0}, rbs_slots_{0}, rbs_bytes_{0},
+      rbs_read_ns_{0}, rbs_send_ns_{0}, rbs_missing_{0}, rbs_admit_ns_{0};
   std::thread accept_thread_, commit_thread_;
   std::atomic<int> active_{0};
   std::mutex conns_mu_;
@@ -2945,7 +3018,7 @@ extern "C" {
 // Bumped on any signature/behavior change of the dataplane C ABI; the
 // Python loader refuses to bind mismatched prebuilt libraries
 // (TPUDFS_NATIVE_LIB) instead of calling with wrong arity.
-int64_t tpudfs_dataplane_abi(void) { return 6; }
+int64_t tpudfs_dataplane_abi(void) { return 7; }
 
 int64_t tpudfs_dataplane_start(const char* host, const char* hot_dir,
                                const char* cold_dir, uint32_t chunk_size,
@@ -3024,6 +3097,15 @@ void tpudfs_dataplane_stream_stats(int64_t h, uint64_t out[8]) {
   Engine* e = get_engine(h);
   if (e) e->stream_stage_stats(out);
   else for (int i = 0; i < 8; i++) out[i] = 0;
+}
+
+// Read path stage clocks: rb_calls, rb_bytes, rb_read_ns, rb_send_ns,
+// rb_not_found, rb_cache_calls, rb_admit_ns, rbs_frames, rbs_slots,
+// rbs_bytes, rbs_read_ns, rbs_send_ns, rbs_missing, rbs_admit_ns.
+void tpudfs_dataplane_read_stats(int64_t h, uint64_t out[14]) {
+  Engine* e = get_engine(h);
+  if (e) e->read_stage_stats(out);
+  else for (int i = 0; i < 14; i++) out[i] = 0;
 }
 
 // QoS control contract (ABI 6). Python pushes the QosShedder config in

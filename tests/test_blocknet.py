@@ -10,13 +10,14 @@ degrade replication).
 from __future__ import annotations
 
 import asyncio
+import contextlib
 
 import msgpack
 import numpy as np
 import pytest
 
 from tests.test_chunkserver import Cluster, _rand, _write
-from tpudfs.common import blocknet, native, writestream
+from tpudfs.common import blocknet, native, telemetry, writestream
 from tpudfs.common.blocknet import (
     BlockConnPool,
     BlockPortServer,
@@ -392,6 +393,19 @@ class _Loopback:
         return sent_first
 
 
+@contextlib.contextmanager
+def _received():
+    """The attributes of the ``blockport.recv_payload`` spans of the calls
+    made inside (tracing is on for those alone)."""
+    got: list[dict] = []
+    telemetry.enable(sink=lambda r: got.append(r.attrs)
+                     if r.name == "blockport.recv_payload" else None)
+    try:
+        yield got
+    finally:
+        telemetry.disable()
+
+
 def _into(buf, spans):
     """Scatter callback handing out ``buf[a:b]`` for each span."""
     view = memoryview(buf)
@@ -416,17 +430,18 @@ async def _case_direct_fills_segments(lb):
         assert plen == 570_000
         return [view[0:300_000], scratch, view[300_000:500_000]]
 
-    for _ in range(2):  # the second frame rides the pooled connection
-        resp = await lb.call("Read", payload_into=scatter)
-        assert resp["data"] is None
-        assert flat[:300_000] == parts[0]
-        assert scratch == parts[1]
-        assert flat[300_000:500_000] == parts[2]
-        assert flat[500_000:] == bytes([_SENTINEL]) * 100_000
-    pool = lb.pool
-    assert pool.rx_direct_bytes + pool.rx_buffered_bytes == 2 * 570_000
+    with _received() as received:
+        for _ in range(2):  # the second frame rides the pooled connection
+            resp = await lb.call("Read", payload_into=scatter)
+            assert resp["data"] is None
+            assert flat[:300_000] == parts[0]
+            assert scratch == parts[1]
+            assert flat[300_000:500_000] == parts[2]
+            assert flat[500_000:] == bytes([_SENTINEL]) * 100_000
+    assert [a["bytes"] for a in received] == [570_000, 570_000]
     # All but what one recv brought along with each header.
-    assert pool.rx_buffered_bytes <= 2 * blocknet._RX_BUF
+    assert all(570_000 - blocknet._RX_BUF <= a["direct"] <= 570_000
+               for a in received)
     assert lb.pooled() == 1
 
 
@@ -436,11 +451,12 @@ async def _case_zero_length_payload(lb):
 
     lb.handlers["Read"] = read
     asked = []
-    resp = await lb.call(
-        "Read", payload_into=lambda h, n: asked.append(n))
+    with _received() as received:
+        resp = await lb.call(
+            "Read", payload_into=lambda h, n: asked.append(n))
     assert resp["data"] == b"" and resp["total_size"] == 0
     assert not asked, "an empty payload has no destination to ask for"
-    assert lb.pool.rx_direct_bytes == lb.pool.rx_buffered_bytes == 0
+    assert [(a["bytes"], a["direct"]) for a in received] == [(0, 0)]
     assert lb.pooled() == 1
 
 
@@ -476,18 +492,19 @@ async def _case_buffered_path_keeps_data(lb):
 
     lb.handlers["Read"] = read
     total = 0
-    for n, data in sent.items():
-        for into in (None, lambda _h, _n: None):
-            resp = await lb.call("Read", {"n": n}, payload_into=into)
-            assert resp["data"] == data and len(resp["data"]) == n
-            total += n
-    asked = []
-    with pytest.raises(RpcError) as ei:
-        await lb.call("Read", {"n": -1},
-                      payload_into=lambda h, n: asked.append(h))
+    with _received() as received:
+        for n, data in sent.items():
+            for into in (None, lambda _h, _n: None):
+                resp = await lb.call("Read", {"n": n}, payload_into=into)
+                assert resp["data"] == data and len(resp["data"]) == n
+                total += n
+        asked = []
+        with pytest.raises(RpcError) as ei:
+            await lb.call("Read", {"n": -1},
+                          payload_into=lambda h, n: asked.append(h))
     assert ei.value.code.name == "NOT_FOUND" and not asked
-    assert lb.pool.rx_direct_bytes == 0
-    assert lb.pool.rx_buffered_bytes == total
+    assert all(a["direct"] == 0 for a in received)
+    assert sum(a["bytes"] for a in received) == total
     assert lb.pooled() == 1  # an error frame leaves the stream framed
 
 
@@ -659,3 +676,196 @@ _CASES = [
 async def test_client_receive(case, tls, pki):
     async with _Loopback(tls, pki) as lb:
         await case(lb)
+
+
+# ------------------------------------------------- the read path's clocks
+
+
+_PLANES = ["native", "python"]
+
+
+async def _plane(cluster, tmp_path, plane):
+    if plane == "native" and not native.has_dataplane():
+        pytest.skip("native dataplane unavailable")
+    await cluster.start_master()
+    cs = await cluster.add_cs(tmp_path, 0,
+                              python_data_plane=plane == "python")
+    assert (cs._native_dp is not None) == (plane == "native")
+    return cs
+
+
+def _moved(before: dict, after: dict) -> dict:
+    return {k: after[k] - before[k] for k in after if after[k] != before[k]}
+
+
+@pytest.mark.parametrize("plane", _PLANES)
+async def test_read_stages_count_each_read(cluster, tmp_path, plane):
+    """``read_stages`` on either plane: one call and its bytes for a whole,
+    a cache-served and a ranged ``ReadBlock``, a ``NOT_FOUND``, one frame
+    with its slots and a missing slot for ``ReadBlocks``; ``Stats`` carries
+    the group."""
+    from tpudfs.chunkserver.service import READ_STAGE_KEYS
+
+    cs = await _plane(cluster, tmp_path, plane)
+    pool = BlockConnPool()
+    data = _rand(70_000, 21)
+    try:
+        await pool.call(cluster.client, cs.address, SERVICE, "WriteBlock", {
+            "block_id": "rs", "data": data, "next_servers": [],
+            "expected_crc32c": crc32c(data), "master_term": 0})
+
+        async def read(method, req):
+            before = cs.read_stage_stats()
+            resp = await pool.call(cluster.client, cs.address, SERVICE,
+                                   method, req)
+            assert blocknet.READ_NS_KEY not in resp
+            return resp, _moved(before, cs.read_stage_stats())
+
+        whole = {"block_id": "rs", "offset": 0, "length": 0}
+        resp, moved = await read("ReadBlock", whole)  # a miss: from disk
+        assert resp["data"] == data
+        assert moved.pop("rb_read_ns") > 0 and moved.pop("rb_send_ns") > 0
+        assert moved == {"rb_calls": 1, "rb_bytes": 70_000}
+        resp, moved = await read("ReadBlock", whole)  # from the cache
+        assert resp["data"] == data
+        assert {"rb_read_ns", "rb_send_ns"} <= set(moved)
+        assert (moved["rb_calls"], moved["rb_bytes"],
+                moved["rb_cache_calls"]) == (1, 70_000, 1)
+        resp, moved = await read(
+            "ReadBlock", {"block_id": "rs", "offset": 100, "length": 5000})
+        assert resp["data"] == data[100:5100]
+        assert (moved["rb_calls"], moved["rb_bytes"]) == (1, 5000)
+        assert moved["rb_read_ns"] > 0
+
+        before = cs.read_stage_stats()
+        with pytest.raises(RpcError) as ei:
+            await pool.call(cluster.client, cs.address, SERVICE, "ReadBlock",
+                            {"block_id": "nope", "offset": 0, "length": 0})
+        assert ei.value.code.name == "NOT_FOUND"
+        moved = _moved(before, cs.read_stage_stats())
+        assert moved.pop("rb_read_ns") > 0
+        assert moved == {"rb_calls": 1, "rb_not_found": 1}
+
+        resp, moved = await read("ReadBlocks",
+                                 {"block_ids": ["rs", "nope", "rs"]})
+        assert resp["sizes"] == [70_000, -1, 70_000]
+        assert moved.pop("rbs_read_ns") > 0 and moved.pop("rbs_send_ns") > 0
+        assert moved == {"rbs_frames": 1, "rbs_slots": 3, "rbs_missing": 1,
+                         "rbs_bytes": 140_000}
+
+        stages = (await cs.rpc_stats({}))["read_stages"]
+        assert tuple(stages) == READ_STAGE_KEYS
+        assert stages == cs.read_stage_stats()
+        assert stages["rb_admit_ns"] == stages["rbs_admit_ns"] == 0  # no QoS
+    finally:
+        await pool.close()
+        await cluster.stop()
+
+
+@pytest.mark.parametrize("plane", _PLANES)
+async def test_engine_read_time_rides_the_wait_header_span(cluster, tmp_path,
+                                                           plane):
+    """With tracing on, each read's ``blockport.wait_header`` carries the
+    server's own read time, which is a part of the wait; the caller's
+    response does not."""
+    cs = await _plane(cluster, tmp_path, plane)
+    pool = BlockConnPool()
+    data = _rand(200_000, 22)
+    records = []
+    try:
+        await pool.call(cluster.client, cs.address, SERVICE, "WriteBlock", {
+            "block_id": "wh", "data": data, "next_servers": [],
+            "expected_crc32c": crc32c(data), "master_term": 0})
+        telemetry.enable(sink=records.append)
+        try:
+            for req in ({"block_id": "wh", "offset": 0, "length": 0},
+                        {"block_id": "wh", "offset": 512, "length": 7000}):
+                resp = await pool.call(cluster.client, cs.address, SERVICE,
+                                       "ReadBlock", req)
+                assert blocknet.READ_NS_KEY not in resp
+            resp = await pool.call(cluster.client, cs.address, SERVICE,
+                                   "ReadBlocks", {"block_ids": ["wh", "x"]})
+            assert resp["sizes"] == [200_000, -1]
+            assert blocknet.READ_NS_KEY not in resp
+        finally:
+            telemetry.disable()
+    finally:
+        await pool.close()
+        await cluster.stop()
+    waits = [r for r in records if r.name == "blockport.wait_header"]
+    assert [r.attrs["method"] for r in waits] == \
+        ["ReadBlock", "ReadBlock", "ReadBlocks"]
+    for r in waits:
+        engine_ms = r.attrs["engine_read_ms"]
+        assert 0 < engine_ms <= (r.end_ns - r.start_ns) / 1e6, r
+
+
+def _raw_call(port: int, header: dict) -> bytes:
+    """One request frame on a socket of its own; the response's header
+    bytes, as they came off the wire."""
+    import socket
+
+    with socket.create_connection(("127.0.0.1", port), timeout=10) as sock:
+        sock.sendall(b"".join(blocknet._pack_frame(header, None)))
+        f = sock.makefile("rb")
+        hlen = blocknet._U32.unpack(f.read(4))[0]
+        return f.read(hlen)
+
+
+@pytest.mark.parametrize("plane", _PLANES)
+async def test_tracing_off_leaves_every_read_frame_as_it_was(
+        cluster, tmp_path, plane, monkeypatch):
+    """With tracing off no request carries the timing flag, and the
+    responses are the bytes they always were; a request with the flag is
+    answered with the read time as one more key of the header."""
+    cs = await _plane(cluster, tmp_path, plane)
+    pool = BlockConnPool()
+    data = _rand(3000, 23)
+    sent = []
+    real_pack = blocknet._pack_frame
+
+    def pack(header, payload):
+        sent.append(dict(header))
+        return real_pack(header, payload)
+
+    monkeypatch.setattr(blocknet, "_pack_frame", pack)
+    try:
+        await pool.call(cluster.client, cs.address, SERVICE, "WriteBlock", {
+            "block_id": "bi", "data": data, "next_servers": [],
+            "expected_crc32c": crc32c(data), "master_term": 0})
+        one = {"block_id": "bi", "offset": 0, "length": 0}
+        many = {"block_ids": ["bi", "zz"]}
+        await pool.call(cluster.client, cs.address, SERVICE, "ReadBlock", one)
+        await pool.call(cluster.client, cs.address, SERVICE, "ReadBlocks",
+                        many)
+        # (the asyncio server packs its responses here too: no "m")
+        assert [h["m"] for h in sent if "m" in h] == \
+            ["WriteBlock", "ReadBlock", "ReadBlocks"]
+        assert not any(blocknet.READ_TIMING_KEY in h
+                       or blocknet.READ_NS_KEY in h for h in sent)
+
+        port = cs.data_port
+        got_one = await asyncio.to_thread(
+            _raw_call, port, {"m": "ReadBlock", **one})
+        got_many = await asyncio.to_thread(
+            _raw_call, port, {"m": "ReadBlocks", **many})
+        if plane == "native":
+            want_one = {"ok": True, "_d": 1, "bytes_read": 3000,
+                        "total_size": 3000}
+            want_many = {"ok": True, "_d": 1, "sizes": [3000, -1]}
+        else:  # the handler's keys, then the server's
+            want_one = {"bytes_read": 3000, "total_size": 3000, "ok": True,
+                        "_d": 1}
+            want_many = {"sizes": [3000, -1], "ok": True, "_d": 1}
+        assert got_one == msgpack.packb(want_one)
+        assert got_many == msgpack.packb(want_many)
+
+        timed = await asyncio.to_thread(
+            _raw_call, port,
+            {"m": "ReadBlock", **one, blocknet.READ_TIMING_KEY: 1})
+        header = msgpack.unpackb(timed)
+        assert header.pop(blocknet.READ_NS_KEY) > 0
+        assert msgpack.packb(header) == got_one  # one key more, no other
+    finally:
+        await pool.close()
+        await cluster.stop()

@@ -158,7 +158,8 @@ async def test_prefetch_is_on_and_the_gate_holds(tmp_path):
     async with dataset_on_cluster(tmp_path) as (c, _client, shards):
         def run(source):
             assert source.stats() == {
-                "records": 0, "bytes": 0, "range_reads": 0,
+                "records": 0, "bytes": 0, "hop_ns": 0, "loop_ns": 0,
+                "range_reads": 0, "range_frames": 0,
                 "max_in_flight": 1,  # the metadata fetch of the index
                 "sheds": 0, "governor_level": 0}
             epochs_of(source, 5, epochs=1)
@@ -171,6 +172,8 @@ async def test_prefetch_is_on_and_the_gate_holds(tmp_path):
     # What the client issued for the records (the index walk's reads are
     # the ``infeed.index`` span's): some straddle a block, none spans 3.
     assert N < stats["range_reads"] <= 2 * N
+    assert stats["range_frames"] == 0  # a record is ReadBlocks of its own
+    assert stats["hop_ns"] > 0 and stats["loop_ns"] > 0
     assert 1 < stats["max_in_flight"] <= 16
     assert stats["sheds"] == 0 and stats["governor_level"] == 0
 
@@ -231,6 +234,59 @@ async def test_range_reads_counts_every_read_block_the_client_sent(tmp_path):
     assert whole == 1 and failed == 1
     assert stats["range_reads"] == 3 and stats["records"] == 2
     assert source.stats() == stats  # a closed source still says it
+
+
+async def test_a_fetch_splits_into_the_hop_and_the_time_on_the_loop(
+        tmp_path):
+    """``hop_ns``: a fetch's hand-off from its thread to its first step on
+    the client's loop; ``loop_ns``: from there to the read's return. A
+    read held 50 ms on the loop shows in the second; a loop kept busy
+    50 ms before the fetch's first step shows in the first."""
+    import time
+
+    async with dataset_on_cluster(tmp_path) as (c, _client, shards):
+        def run(source):
+            cl = source._client_loop()
+            client = cl.client
+            inner = client.read_meta_range
+
+            async def slow(meta, offset, length):
+                await asyncio.sleep(0.05)
+                return await inner(meta, offset, length)
+
+            client.read_meta_range = slow
+            source[3]
+            slow_read = source.stats()
+            client.read_meta_range = inner
+            cl._loop.call_soon_threadsafe(time.sleep, 0.05)  # the loop busy
+            source[4]
+            return slow_read, source.stats()
+
+        slow_read, busy_loop = await in_thread(c, shards, run)
+    assert slow_read["records"] == 1 and slow_read["loop_ns"] >= 50e6
+    assert busy_loop["records"] == 2
+    assert busy_loop["hop_ns"] - slow_read["hop_ns"] >= 40e6
+
+
+async def test_range_frames_counts_the_read_blocks_frames_sent(tmp_path):
+    """Frames are counted where they are sent, apart from ``range_reads``:
+    the index walk's are left out of both."""
+    async with dataset_on_cluster(tmp_path) as (c, _client, shards):
+        def run(source):
+            cl = source._client_loop()
+            before = source.stats()
+            meta = source._metas[shards[0]]
+            ids = [b["block_id"] for b in meta["blocks"][:2]]
+            addr = meta["blocks"][0]["locations"][0]
+            resp = cl.run(cl.client._data_call(
+                addr, "ReadBlocks", {"block_ids": ids}, timeout=30.0))
+            return before, resp, source.stats()
+
+        before, resp, after = await in_thread(
+            c, shards, run, client_kwargs={"local_reads": False})
+    assert before["range_frames"] == 0 and after["range_frames"] == 1
+    assert after["range_reads"] == before["range_reads"]
+    assert len(resp["sizes"]) == 2
 
 
 async def test_the_tenant_reaches_the_sources_client(tmp_path):

@@ -624,16 +624,20 @@ def test_mutating_qos_shed_detail_fails_lint(tmp_path):
                for f in findings), rule_ids(findings)
 
 
-def test_abi6_bump_without_manifest_regen_fails_lint(tmp_path):
-    # The discipline the ABI 6 bump itself had to follow: bumping the
-    # version constant without regenerating native_abi.json must fail.
+def test_abi_bump_without_manifest_regen_fails_lint(tmp_path):
+    # The discipline every ABI bump has to follow: bumping the version
+    # constant without regenerating native_abi.json must fail.
     root = _copy_real_tree(tmp_path)
     dp = root / "native" / "dataplane.cc"
     src = dp.read_text()
-    needle = "int64_t tpudfs_dataplane_abi(void) { return 6; }"
+    version = json.loads(
+        (REPO / "tpudfs" / "analysis" / "native_abi.json").read_text()
+    )["abi_version"]
+    needle = f"int64_t tpudfs_dataplane_abi(void) {{ return {version}; }}"
     assert needle in src
     dp.write_text(src.replace(
-        needle, "int64_t tpudfs_dataplane_abi(void) { return 7; }"))
+        needle,
+        f"int64_t tpudfs_dataplane_abi(void) {{ return {version + 1}; }}"))
     findings = _native_findings(root)
     tpl040 = [f for f in findings if f.rule == "TPL040"]
     assert tpl040, rule_ids(findings)

@@ -143,6 +143,8 @@ ROUTES: tuple[RouteSpec, ...] = (
             r"tpudfs\.tpu\.hbm_reader\.HbmReader\._read_block_inner",
             r"tpudfs\.client\.client\.Client\._read_block_range",
             r"tpudfs\.chunkserver\.service\.ChunkServer\.rpc_read_block",
+            # the handler's body, under the read clocks' wrapper
+            r"tpudfs\.chunkserver\.service\.ChunkServer\._read_block",
         ),
         modules=(
             "tpudfs/tpu/hbm_reader.py",

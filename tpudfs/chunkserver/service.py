@@ -223,6 +223,24 @@ class GroupCommitter:
                 raise asyncio.CancelledError
 
 
+#: ``Stats`` -> ``read_stages``, in the native engine's slot order
+#: (dataplane.cc ``read_stage_stats``). ``rb_*``: ``ReadBlock`` calls,
+#: payload bytes sent, read ns (handler start to the response built: cache
+#: lookup, stat, pread + verify, or the cache copy), send ns, ``NOT_FOUND``
+#: answers, calls served from the block cache, QoS admission wait ns.
+#: ``rbs_*``: ``ReadBlocks`` frames, slots, payload bytes, read ns (handler
+#: start through every slot's pread to the response built: what the client
+#: waits for before the header), send ns, slots answered -1, admission
+#: wait ns. The engine serves each connection on a thread of its own, so
+#: admission is the only queue on the server's side (0 while QoS is off).
+READ_STAGE_KEYS = (
+    "rb_calls", "rb_bytes", "rb_read_ns", "rb_send_ns", "rb_not_found",
+    "rb_cache_calls", "rb_admit_ns",
+    "rbs_frames", "rbs_slots", "rbs_bytes", "rbs_read_ns", "rbs_send_ns",
+    "rbs_missing", "rbs_admit_ns",
+)
+
+
 class ChunkServer:
     def __init__(
         self,
@@ -277,6 +295,11 @@ class ChunkServer:
         self._stream_stats = dict.fromkeys(
             ("net_ns", "crc_ns", "disk_ns", "fanout_ns",
              "frames", "streams", "stream_bytes", "aborts"), 0)
+        #: The read path's stage clocks on this process's handlers (gRPC
+        #: plane and asyncio blockport); the native engine keeps its own
+        #: twin (tpudfs_dataplane_read_stats). ``Stats`` reports the sum
+        #: (``read_stage_stats``).
+        self._read_stats = dict.fromkeys(READ_STAGE_KEYS, 0)
         #: Inflight-bounded admission control for the DATA-path RPCs (reads,
         #: writes, chain forwards). Over the limit, requests fail fast with
         #: RESOURCE_EXHAUSTED + retry-after instead of queueing — control
@@ -341,6 +364,7 @@ class ChunkServer:
         a mismatch falls back to the per-block VERIFIED path, which
         detects the rot, reports it, and triggers recovery. The native
         engine serves the same method, same contract, on the blockport."""
+        t0 = time.perf_counter_ns()
         ids = list(req.get("block_ids") or [])
         attempt = ids[: self.READ_BATCH_MAX_SLOTS]
 
@@ -363,7 +387,17 @@ class ChunkServer:
             sizes.append(len(data))
             total += len(data)
         sizes.extend(-1 for _ in ids[self.READ_BATCH_MAX_SLOTS:])
-        return {"sizes": sizes, "data_parts": parts}
+        read_ns = time.perf_counter_ns() - t0
+        stats = self._read_stats
+        stats["rbs_frames"] += 1
+        stats["rbs_slots"] += len(ids)
+        stats["rbs_bytes"] += total
+        stats["rbs_missing"] += sizes.count(-1)
+        stats["rbs_read_ns"] += read_ns
+        resp = {"sizes": sizes, "data_parts": parts}
+        if blocknet.READ_TIMING_KEY in req:
+            resp[blocknet.READ_NS_KEY] = read_ns
+        return resp
 
     async def rpc_data_port(self, req: dict) -> dict:
         """Blockport discovery (tpudfs.common.blocknet): port 0 = none.
@@ -1260,6 +1294,25 @@ class ChunkServer:
 
     @admission_controlled
     async def rpc_read_block(self, req: dict) -> dict:
+        t0 = time.perf_counter_ns()
+        stats = self._read_stats
+        stats["rb_calls"] += 1
+        try:
+            resp = await self._read_block(req)
+        except BaseException as e:
+            stats["rb_read_ns"] += time.perf_counter_ns() - t0
+            if isinstance(e, RpcError) and \
+                    e.code == grpc.StatusCode.NOT_FOUND:
+                stats["rb_not_found"] += 1
+            raise
+        read_ns = time.perf_counter_ns() - t0
+        stats["rb_read_ns"] += read_ns
+        stats["rb_bytes"] += resp["bytes_read"]
+        if blocknet.READ_TIMING_KEY in req:
+            resp[blocknet.READ_NS_KEY] = read_ns
+        return resp
+
+    async def _read_block(self, req: dict) -> dict:
         if self.fault_delay:
             await asyncio.sleep(self.fault_delay)
         block_id = req["block_id"]
@@ -1281,6 +1334,7 @@ class ChunkServer:
                 # lose to the on-disk file it shadows. A fresh signature
                 # also pins the size: the cached buffer IS the full block.
                 if sig == self._block_sig(block_id):
+                    self._read_stats["rb_cache_calls"] += 1
                     return {"data_parts": [memoryview(data)],
                             "bytes_read": len(data),
                             "total_size": len(data)}
@@ -1400,6 +1454,34 @@ class ChunkServer:
                     out[k] += int(v)
         return out
 
+    def read_stage_stats(self) -> dict:
+        """The read path's stage clocks (``READ_STAGE_KEYS``), ``Stats``'
+        ``read_stages``: where a read's time goes on the server's side,
+        for the client's ``blockport.wait_header`` to be held against.
+        Sums this process's handlers (and the asyncio blockport's sends)
+        with the native engine's."""
+        out = dict(self._read_stats)
+        if self._blockport is not None:
+            out["rb_send_ns"] += self._blockport.send_ns["ReadBlock"]
+            out["rbs_send_ns"] += self._blockport.send_ns["ReadBlocks"]
+        if self._native_dp is not None:
+            lib = native.get_lib()
+            if lib is not None:
+                import ctypes
+
+                vals = (ctypes.c_uint64 * len(READ_STAGE_KEYS))()
+                lib.tpudfs_dataplane_read_stats(self._native_dp, vals)
+                for k, v in zip(READ_STAGE_KEYS, vals):
+                    out[k] += int(v)
+        return out
+
+    def admission_waited(self, handler: str, seconds: float) -> None:
+        """``admission_controlled``'s report of a QoS admission wait."""
+        key = {"rpc_read_block": "rb_admit_ns",
+               "rpc_read_blocks": "rbs_admit_ns"}.get(handler)
+        if key is not None:
+            self._read_stats[key] += int(seconds * 1e9)
+
     def _block_sig(self, block_id: str) -> tuple | None:
         try:
             st = os.stat(self.store.block_path(block_id))
@@ -1466,6 +1548,7 @@ class ChunkServer:
             cache_misses=self.cache.misses + dp["cache_misses"],
             write_stages=self.write_stage_stats(),
             stream_stages=self.stream_stage_stats(),
+            read_stages=self.read_stage_stats(),
         )
         return stats
 

@@ -204,6 +204,11 @@ class Client:
         #: tried, so a hedge and every fallback count beside the primary
         #: (the infeed's ``stats()["range_reads"]`` reads it).
         self.read_block_calls = 0
+        #: ``ReadBlocks`` frames sent, and the slots they asked for:
+        #: counted in ``_data_call``, which every frame goes through (the
+        #: infeed's ``stats()["range_frames"]`` reads the first).
+        self.read_blocks_frames = 0
+        self.read_blocks_slots = 0
         #: Transparent coalescing of concurrent get_file_info calls into
         #: BatchGetFileInfo RPCs (see get_file_info).
         self.meta_coalescing = True
@@ -354,6 +359,9 @@ class Client:
         blockport scatter callback for the response payload (blocknet
         BlockConn.read_payload); on the gRPC path the payload still arrives as
         ``resp["data"]`` and the caller copies."""
+        if method == "ReadBlocks":
+            self.read_blocks_frames += 1
+            self.read_blocks_slots += len(req["block_ids"])
         dialed = self._dial(addr)
         if dialed != addr or not allow_blockport:
             return await self.rpc.call(dialed, CS, method, req,

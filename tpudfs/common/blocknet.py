@@ -44,10 +44,12 @@ listeners (ServerTls/ClientTls), including mTLS client-cert requirements.
 from __future__ import annotations
 
 import asyncio
+import collections
 import logging
 import os
 import ssl
 import struct
+import time
 
 import grpc
 import msgpack
@@ -168,6 +170,13 @@ async def _read_header(r) -> tuple[dict, int]:
     return header, plen
 
 
+#: The reads whose request carries ``READ_TIMING_KEY`` while tracing is on:
+#: the server (native/dataplane.cc, or the chunkserver's handlers) answers
+#: with its own read time, ns, under ``READ_NS_KEY`` in the header.
+_TIMED_READS = frozenset({"ReadBlock", "ReadBlocks"})
+READ_TIMING_KEY = "_rt"
+READ_NS_KEY = "_rns"
+
 #: Serve-loop backpressure watermark: an unconditional ``await
 #: w.drain()`` per response frame costs an event-loop round-trip per
 #: frame even when the kernel buffer is empty; only pay it once the
@@ -199,6 +208,10 @@ class BlockPortServer:
         self._tls = tls
         self._server: asyncio.AbstractServer | None = None
         self.port: int = 0
+        #: method -> ns spent writing its response frames (and waiting for
+        #: the transport to drain them): the send stage of the chunkserver's
+        #: ``read_stages``, as the native engine's send_frame clock.
+        self.send_ns: collections.Counter[str] = collections.Counter()
         #: live connections; closed at stop() — wait_closed() would
         #: otherwise block on peers' POOLED (idle but open) connections.
         self._conns: set[asyncio.StreamWriter] = set()
@@ -333,8 +346,10 @@ class BlockPortServer:
                 if "data_parts" in out:
                     data = out.pop("data_parts")
                 out["ok"] = True
+                t_send = time.perf_counter_ns()
                 w.writelines(_pack_frame(out, data))
                 await _drain_backpressure(w)
+                self.send_ns[method] += time.perf_counter_ns() - t_send
         except (ConnectionResetError, BrokenPipeError):
             pass
         finally:
@@ -586,12 +601,6 @@ class BlockConnPool:
     def __init__(self, tls: ClientTls | None = None):
         self._tls = tls
         self._free: dict[str, list[BlockConn]] = {}
-        #: response payload bytes the kernel put straight into the
-        #: caller's scatter segments / that went through a buffer of the
-        #: connection's (no segments given, or brought along with the
-        #: header). Their sum is every payload byte _call_blockport got.
-        self.rx_direct_bytes = 0
-        self.rx_buffered_bytes = 0
         #: addr -> (port | None). None = peer has no blockport (final,
         #: from an UNIMPLEMENTED probe). Transport-level probe/call
         #: failures instead open the per-address breaker below.
@@ -901,15 +910,26 @@ class BlockConnPool:
             tenant = raw_tenant()
             if tenant is not None:
                 header[TENANT_FRAME_KEY] = tenant
+            # While tracing is on, a read asks the peer for its own read
+            # time; otherwise the frames are the same bytes as ever.
+            timed = method in _TIMED_READS and telemetry.enabled()
+            if timed:
+                header[READ_TIMING_KEY] = 1
             conn.writelines(_pack_frame(header, req.get("data")))
             await conn.drain()
-            # Client side only: the peer sends nothing before it has the
-            # whole answer, so the wait for the header is its share of the
-            # call and the payload is the wire's and this loop's.
+            # The wait for the header holds the peer's whole read (it sends
+            # nothing before it has the answer), the request's and the
+            # header's time on the wire, and this loop's lag in noticing
+            # the header. ``engine_read_ms`` is the first of those, by the
+            # peer's own clock (cache lookup, stat, pread, verify: every
+            # block of a ReadBlocks frame); the rest of the span is wire and
+            # loop. The payload is the wire's and this loop's.
             with telemetry.span("blockport.wait_header", method=method,
                                 addr=hostport) as waited:
                 resp, plen = await _read_header(conn)
                 waited.set(bytes=plen)
+                if timed and READ_NS_KEY in resp:
+                    waited.set(engine_read_ms=resp.pop(READ_NS_KEY) / 1e6)
             with telemetry.span("blockport.recv_payload", method=method,
                                 addr=hostport, bytes=plen) as received:
                 payload, direct = await conn.read_payload(resp, plen,
@@ -918,8 +938,6 @@ class BlockConnPool:
         except BaseException:
             conn.close()
             raise
-        self.rx_direct_bytes += direct
-        self.rx_buffered_bytes += plen - direct
         self._release(hostport, conn)
         has_data = resp.pop("_d", 0)
         if not resp.pop("ok", False):

@@ -640,6 +640,7 @@ def admission_controlled(fn: Any) -> Any:
         if acquire is not None:
             # Tenant-aware plane: per-tenant fair queueing + rate limits.
             tenant = current_tenant()
+            t_queued = time.monotonic()
             try:
                 await acquire(tenant)
             except QosRejected as e:
@@ -652,6 +653,9 @@ def admission_controlled(fn: Any) -> Any:
                     retry_after=e.retry_after,
                 ) from None
             t0 = time.monotonic()
+            waited = getattr(self, "admission_waited", None)
+            if waited is not None:
+                waited(fn.__name__, t0 - t_queued)
             try:
                 return await fn(self, request)
             finally:

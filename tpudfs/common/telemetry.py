@@ -216,6 +216,12 @@ def enable(sink: Callable[[SpanRecord], None] | None = None) -> None:
     _enabled = True
 
 
+def enabled() -> bool:
+    """Whether spans are being recorded: a site that asks another process
+    for a number only a span would carry asks only then."""
+    return _enabled
+
+
 def disable() -> None:
     global _enabled, _sink
     _enabled = False

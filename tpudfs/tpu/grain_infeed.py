@@ -276,13 +276,15 @@ class DfsSourceBase:
         # Immutable block layout per path, cached so record fetches skip the
         # per-read master GetFileInfo round-trip (read_meta_range fast path).
         self._metas: dict[str, dict] = {}
-        self._counts = dict.fromkeys(("records", "bytes"), 0)
-        # Leaf lock of ``stats()``'s two sums: held for the adds alone,
-        # on sync threads only, like ``_lock``.
+        self._counts = dict.fromkeys(("records", "bytes", "hop_ns",
+                                      "loop_ns"), 0)
+        # Leaf lock of ``stats()``'s sums: held for the adds alone, on sync
+        # threads only, like ``_lock``.
         self._counts_lock = threading.Lock()
-        # Block reads the client had issued when the index was built: the
-        # tar-header walk's.
+        # Block reads and ``ReadBlocks`` frames the client had issued when
+        # the index was built: the tar-header walk's.
         self._index_block_reads = 0
+        self._index_frames = 0
 
     def _client_loop(self) -> _ClientLoop:
         with self._lock:
@@ -328,12 +330,27 @@ class DfsSourceBase:
         prefetch threads."""
         cl = self._client_loop()
         meta = self._metas[path]
+        # perf_counter_ns at the hand-off to the loop, at the coroutine's
+        # first step there and at its return (the try that succeeded).
+        clocks = [0, 0, 0]
+
+        async def on_loop() -> bytes:
+            clocks[1] = time.perf_counter_ns()
+            data = await cl.client.read_meta_range(meta, offset, length)
+            clocks[2] = time.perf_counter_ns()
+            return data
+
+        def hand_off():
+            clocks[0] = time.perf_counter_ns()
+            return on_loop()
+
         with telemetry.span("infeed.fetch", bytes=length):
-            data = self._governed_run(
-                cl, lambda: cl.client.read_meta_range(meta, offset, length))
+            data = self._governed_run(cl, hand_off)
         with self._counts_lock:
             self._counts["records"] += 1
             self._counts["bytes"] += len(data)
+            self._counts["hop_ns"] += clocks[1] - clocks[0]
+            self._counts["loop_ns"] += clocks[2] - clocks[1]
         return data
 
     def _issued(self) -> int:
@@ -346,16 +363,26 @@ class DfsSourceBase:
             return 0
         return cl.client.read_block_calls + cl.client.local_read_blocks
 
+    def _frames_sent(self) -> int:
+        """``ReadBlocks`` frames the source's client has sent."""
+        cl = self._cl
+        return 0 if cl is None else cl.client.read_blocks_frames
+
     def stats(self) -> dict:
         """What the source did so far, in this process: ``records`` and
-        ``bytes`` fetched, ``range_reads`` (the block reads its client
-        issued for them, ``_issued`` less the index walk's), the most
-        fetches ever in flight at once (Grain's prefetch threads under the
-        governor's gate), fetches the cluster shed and the governor's
-        level now (0: hedges on, the whole gate)."""
+        ``bytes`` fetched; ``hop_ns``, their fetches' time from the hand-off
+        on a Grain thread to the first step on the client's loop, and
+        ``loop_ns``, from there to the read's return; ``range_reads`` (the
+        block reads its client issued for them, ``_issued`` less the index
+        walk's) and ``range_frames`` (the ``ReadBlocks`` frames it sent,
+        less the walk's); the most fetches ever in flight at once (Grain's
+        prefetch threads under the governor's gate), fetches the cluster
+        shed and the governor's level now (0: hedges on, the whole
+        gate)."""
         with self._counts_lock:
             out = dict(self._counts)
         out["range_reads"] = self._issued() - self._index_block_reads
+        out["range_frames"] = self._frames_sent() - self._index_frames
         out["max_in_flight"] = self._governor.gate.max_active
         out["sheds"] = self._governor.sheds
         out["governor_level"] = self._governor.level
@@ -403,6 +430,7 @@ class DfsSourceBase:
         self._governor = _OverloadGovernor()
         # This process's client starts at 0: the walk was the parent's.
         self._index_block_reads = 0
+        self._index_frames = 0
 
 
 class DfsRecordSource(DfsSourceBase):

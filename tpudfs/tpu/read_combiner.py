@@ -225,8 +225,6 @@ class ReadCombiner:
         self.ec_missing_data_shards = 0
         self.ec_shard_bytes = 0
         self._decode_matrices: dict = {}
-        #: rounds issued while another source's round was in flight.
-        self.overlapped = 0
         #: rounds the read stage took; the ``round`` of every stage span.
         self._round_seq = 0
 
@@ -380,8 +378,6 @@ class ReadCombiner:
                 self._round_seq += 1
                 for r in reqs:
                     r.queued.end(round=self._round_seq)
-                if in_flight:
-                    self.overlapped += 1
                 in_flight[place] = asyncio.create_task(self._round(
                     queue, reqs, self._round_seq, len(in_flight) + 1))
             aborted = False

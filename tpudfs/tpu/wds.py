@@ -145,9 +145,11 @@ class DfsWdsSource(DfsSourceBase):
             self._fetch_metas(self.shards)
             for shard_samples in cl.run(index_all(cl.client)):
                 self._samples.extend(shard_samples)
-            # The walk's block reads, as the client counted them: kept out
-            # of ``stats()``'s ``range_reads``, which are the records'.
+            # The walk's block reads and frames, as the client counted
+            # them: kept out of ``stats()``'s ``range_reads`` and
+            # ``range_frames``, which are the records'.
             self._index_block_reads = self._issued()
+            self._index_frames = self._frames_sent()
             sp.set(samples=len(self._samples),
                    range_reads=self._index_block_reads)
 
