@@ -7,6 +7,7 @@ delivery, liveness-driven healing, tiering scans, leader redirects."""
 import asyncio
 import socket
 
+import grpc
 import numpy as np
 import pytest
 
@@ -16,7 +17,8 @@ from tpudfs.chunkserver.blockstore import BlockStore
 from tpudfs.chunkserver.heartbeat import HeartbeatLoop
 from tpudfs.chunkserver.service import ChunkServer
 from tpudfs.master.service import Master
-from tpudfs.raft.core import Timings
+from tpudfs.master.state import BlockInfo, FileMetadata
+from tpudfs.raft.core import NotLeaderError, Timings
 
 FAST_RAFT = Timings(election_min=0.3, election_max=0.6, heartbeat=0.1,
                     snapshot_threshold=200)
@@ -33,11 +35,13 @@ def _free_port():
 
 
 class MiniCluster:
-    def __init__(self, tmp_path, n_masters=1, n_cs=3, cs_kw=None, **master_kw):
+    def __init__(self, tmp_path, n_masters=1, n_cs=3, cs_kw=None,
+                 hb_interval=0.5, **master_kw):
         self.tmp = tmp_path
         self.n_masters = n_masters
         self.n_cs = n_cs
         self.cs_kw = dict(cs_kw or {})
+        self.hb_interval = hb_interval
         self.master_kw = master_kw
         self.masters: dict[str, Master] = {}
         self.servers: dict[str, RpcServer] = {}
@@ -62,7 +66,7 @@ class MiniCluster:
             cs = ChunkServer(store, rack_id=f"rack-{i}", master_addrs=addrs,
                              rpc_client=self.client, **self.cs_kw)
             await cs.start(scrubber=False)
-            hb = HeartbeatLoop(cs, addrs, interval=0.5)
+            hb = HeartbeatLoop(cs, addrs, interval=self.hb_interval)
             hb.start()
             self.chunkservers.append(cs)
             self.heartbeats.append(hb)
@@ -245,6 +249,138 @@ async def test_liveness_removal_triggers_healing(tmp_path):
                 break
             await asyncio.sleep(0.1)
         assert spare.address in locs["locations"]
+    finally:
+        await c.stop()
+
+
+QUIET = {"liveness": 3600, "healer": 3600, "balancer": 3600, "tiering": 3600}
+SRC, B, C, DST = (f"127.0.0.1:{7001 + i}" for i in range(4))
+
+# name: (locations before, result reported by SRC, propose fails,
+#        locations proposed or None, DELETE queued to SRC)
+MOVE_CASES = {
+    "swap": ([SRC, B, C], "REPLICATE", False, [B, C, DST], True),
+    "failed_propose": ([SRC, B, C], "REPLICATE", True, [B, C, DST], False),
+    "re_reported": ([B, C, DST], "REPLICATE", False, None, True),
+    "would_shorten": ([SRC, B], "REPLICATE", False, [SRC, B, DST], False),
+    "delete_result": ([SRC, B, C], "DELETE", False, None, False),
+}
+
+
+@pytest.mark.parametrize("case", list(MOVE_CASES))
+async def test_balance_move_is_one_metadata_step(tmp_path, case):
+    """A balancer move reported by its source: the target goes in and the
+    source comes out in ONE committed entry, and the source's DELETE is
+    queued only after that entry committed (never on a failed propose,
+    never where dropping the source would leave the block under RF)."""
+    before, rtype, fails, proposed, deletes = MOVE_CASES[case]
+    c = MiniCluster(tmp_path, n_masters=1, n_cs=0, intervals=QUIET)
+    try:
+        await c.start()
+        m = await c.leader()
+        bid = "blk-move"
+        m.state.files["/moved"] = FileMetadata(
+            "/moved", size=1, complete=True,
+            blocks=[BlockInfo(bid, size=1, locations=list(before))])
+        real_propose = m.raft.propose
+        seen = []  # (entry, the source's queue when it went out)
+
+        async def propose(cmd, **kw):
+            seen.append((cmd, list(m.state.pending_commands.get(SRC, []))))
+            if fails:
+                raise NotLeaderError(None)
+            return await real_propose(cmd, **kw)
+
+        m.raft.propose = propose
+        res = {"type": rtype, "block_id": bid, "success": True}
+        if rtype == "REPLICATE":
+            res.update(target_chunk_server_address=DST,
+                       balance_delete_source=True)
+        assert await m._process_command_results(SRC, [res]) is not fails
+        assert [cmd["locations"] for cmd, _ in seen] == \
+            ([proposed] if proposed else [])
+        assert all(queued == [] for _, queued in seen)
+        assert m.state.pending_commands.get(SRC, []) == \
+            ([{"type": "DELETE", "block_id": bid}] if deletes else [])
+        locations = m.state.find_block(bid)[1].locations
+        assert locations == (proposed if proposed and not fails else before)
+    finally:
+        await c.stop()
+
+
+async def _read_replica(c: MiniCluster, addr: str, block_id: str):
+    """One named replica's bytes, or None where it answers NOT_FOUND."""
+    try:
+        resp = await c.client.call(addr, "ChunkServerService", "ReadBlock",
+                                   {"block_id": block_id, "offset": 0,
+                                    "length": 0})
+    except RpcError as e:
+        if e.code != grpc.StatusCode.NOT_FOUND:
+            raise
+        return None
+    if "data_parts" in resp:
+        return b"".join(bytes(p) for p in resp["data_parts"])
+    return bytes(resp["data"])
+
+
+async def test_a_balance_move_never_names_a_deleted_replica(tmp_path):
+    """A reader that polls the record and then every replica it names, for
+    the whole of a balance move on 0.1 s heartbeats: whenever the record
+    stood still across one poll, every replica it named answered with the
+    block's bytes. The move ends with the target in, the source out and
+    its copy gone."""
+    await asyncio.wait_for(_balance_move_under_a_reader(tmp_path), 20)
+
+
+async def _balance_move_under_a_reader(tmp_path):
+    c = MiniCluster(tmp_path, n_masters=1, n_cs=4, hb_interval=0.1,
+                    intervals=QUIET)
+    try:
+        await c.start()
+        leader = await c.leader()
+        await c.wait_out_of_safe_mode(leader)
+        data = _rand(200_000, 4)
+        block_id, servers = await c.put_file("/moved", data, leader)
+        source, target = servers[0], next(
+            cs.address for cs in c.chunkservers if cs.address not in servers)
+        source_store = next(cs.store for cs in c.chunkservers
+                            if cs.address == source)
+
+        async def named():
+            info = await c.call(leader.address, "GetFileInfo",
+                                {"path": "/moved"})
+            return info["metadata"]["blocks"][0]["locations"]
+
+        async def poll():
+            """The record, its replicas' answers, the record again."""
+            first = await named()
+            answers = {a: await _read_replica(c, a, block_id) for a in first}
+            return first, answers, await named()
+
+        leader.state.queue_command(source, {
+            "type": "REPLICATE", "block_id": block_id,
+            "target_chunk_server_address": target,
+            "balance_delete_source": True,
+        })
+        steady = 0
+        while True:
+            first, answers, last = await poll()
+            if first == last:
+                steady += 1
+                assert all(got == data for got in answers.values()), \
+                    (first, {a: got is not None for a, got in answers.items()})
+            if source not in last and not source_store.exists(block_id):
+                break
+            await asyncio.sleep(0.005)
+        # The source's report of its DELETE comes on its next heartbeat and
+        # must leave the record as it is.
+        for _ in range(8):
+            first, answers, last = await poll()
+            assert first == last
+            assert all(got == data for got in answers.values())
+            await asyncio.sleep(0.05)
+        assert steady > 0
+        assert sorted(last) == sorted(servers[1:] + [target])
     finally:
         await c.stop()
 

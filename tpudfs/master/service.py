@@ -851,7 +851,22 @@ class Master:
         """Commit metadata changes only after the chunkserver ACKED the data
         movement (prevents phantom locations from failed commands). Returns
         False when this master can't process them (not leader) so the CS
-        retains and re-reports them."""
+        retains and re-reports them.
+
+        A balancer move (a ``REPLICATE`` carrying ``balance_delete_source``,
+        reported by its source) is ONE metadata step: the target goes in and
+        the source comes out in the same ``mark_block_locations`` entry, and
+        the source's ``DELETE`` is queued only after that entry committed.
+        Invariant: the master orders a replica deleted only after a committed
+        record that no longer names it. A move never shortens a block: where
+        dropping the source would leave fewer than REPLICATION_FACTOR
+        distinct locations, only the target goes in and no ``DELETE`` goes
+        out. A re-reported move finds nothing to change and queues the
+        ``DELETE`` again (deleting a missing block is harmless); a ``DELETE``
+        result changes nothing. Accepted trade: command queues are leader
+        soft state, so a leadership change between the commit and the
+        source's next heartbeat loses the ``DELETE`` and the source keeps a
+        copy no record names (wasted disk, no data lost)."""
         if not results:
             return True
         if not self.raft.is_leader:
@@ -865,24 +880,21 @@ class Master:
             _, block = found
             rtype = res.get("type")
             new_locs = None
+            delete_source = False
             if rtype == "REPLICATE":
                 target = res.get("target_chunk_server_address")
-                if target and target not in block.locations:
-                    new_locs = block.locations + [target]
+                new_locs = list(block.locations)
+                if target and target not in new_locs:
+                    new_locs.append(target)
                 if res.get("balance_delete_source"):
-                    # Copy confirmed: now safe to drop the source replica.
-                    self.state.queue_command(reporter, {
-                        "type": "DELETE",
-                        "block_id": res["block_id"],
-                        "balance_remove_location": True,
-                    })
+                    moved = [l for l in new_locs if l != reporter]
+                    if len(set(moved)) >= REPLICATION_FACTOR:
+                        new_locs, delete_source = moved, True
             elif rtype == "RECONSTRUCT_EC_SHARD":
                 idx = int(res.get("shard_index", -1))
                 if 0 <= idx < len(block.locations):
                     new_locs = list(block.locations)
                     new_locs[idx] = reporter
-            elif rtype == "DELETE" and res.get("balance_remove_location"):
-                new_locs = [l for l in block.locations if l != reporter]
             if new_locs is not None and new_locs != block.locations:
                 try:
                     await self.raft.propose({
@@ -893,6 +905,10 @@ class Master:
                 except (NotLeaderError, ValueError) as e:
                     logger.warning("location update failed: %s", e)
                     return False
+            if delete_source:
+                self.state.queue_command(reporter, {
+                    "type": "DELETE", "block_id": res["block_id"],
+                })
         return True
 
     # ------------------------------------------------------- sharding RPCs
