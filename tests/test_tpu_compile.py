@@ -344,3 +344,79 @@ def test_bf16_views_of_vectors_and_stacked_tensors_are_free_bitcasts(
     assert all(any(op in line for op in ("custom-call(", " bitcast(",
                                          "get-tuple-element("))
                for line in made), [m[:140] for m in made]
+
+
+# ----------------------------- restore under another layout (ckpt_reshard)
+
+
+def _reshard_plan(topo):
+    """``ckpt-reshard-3m5cs-r3``'s manifest as the ranks save it, planned
+    onto the described 2x2 as the cell's target (``reference_reshard``)."""
+    import json
+    from pathlib import Path
+
+    from benchmarks import reference_reshard as ref
+    from tpudfs.tpu import ckpt_reshard
+    from tpudfs.tpu.checkpoint import _dtype_of
+
+    cfg = json.loads((Path(__file__).resolve().parent.parent / "benchmarks"
+                      / "configs" / "ckpt-reshard-3m5cs-r3.json").read_text())
+    table = ref.table(cfg)
+    shards = []
+    for shard, (rank, _names) in enumerate(ref.shards(cfg)):
+        placed, size = ref.layout(cfg, shard)
+        tensors = []
+        for name, offset, nbytes in placed:
+            start, shape = ref.rank_pieces(cfg, rank)[name]
+            tensors.append({"name": name, "dtype": table[name][0],
+                            "shape": list(shape), "offset": offset,
+                            "size": nbytes, "global_shape":
+                            list(table[name][1]), "start": list(start)})
+        shards.append({"shard": shard, "size": size, "tensors": tensors})
+    axes = cfg["target"]["mesh"]
+    mesh = Mesh(np.array(topo.devices).reshape(tuple(axes.values())),
+                tuple(axes))
+    target = ckpt_reshard.Target(
+        mesh, {n: P(*ref.spec_of(cfg, n)) for n in table
+               if ref.spec_of(cfg, n)},
+        {n: e[2] for n, e in table.items() if ref.host_shape(e) != e[1]})
+    return ckpt_reshard.plan({"shards": shards}, target, _dtype_of,
+                             cfg["block_bytes"])
+
+
+def test_reshard_programs_compile_for_a_2x2_at_the_cells_widths(
+        topo, chip, monkeypatch):
+    """The cell's chip-to-chip move (one program over the four chips: a
+    switch of static slices and three collective permutes) and chip 0's
+    assembly of its 57 shards: bf16 made by the relabelling kernel alone,
+    everything inside one chip's HBM."""
+    from tpudfs.tpu import ckpt_reshard
+
+    monkeypatch.setattr(ckpt_reshard, "on_tpu", lambda: True)
+    plan = _reshard_plan(topo)
+    assert plan.unique_bytes == 2_374_564_868 and len(plan.outputs) == 57
+    mesh, program = ckpt_reshard._ici_program(
+        tuple(plan.devices), plan.block_rows, plan.sends)
+    stage = jax.ShapeDtypeStruct((4 * plan.stage_rows, 128), jnp.uint32,
+                                 sharding=NamedSharding(mesh, P("ckpt")))
+    compiled = program.lower(stage).compile()
+    assert compiled.as_text().count("collective-permute") >= len(plan.sends)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < HBM_BYTES
+    one = SingleDeviceSharding(plan.devices[0])
+    inbox = sum(w for _s, _l, w in plan.sends) * plan.block_rows
+    compiled = ckpt_reshard._program(plan, 0).lower(
+        _words(plan.stage_rows, one), _words(inbox, one)).compile()
+    text = compiled.as_text()
+    bf16 = sum(o[1].name == "bfloat16" for o in plan.outputs)
+    assert text.count("tpu_custom_call") >= bf16
+    made = [line for line in text.splitlines()[1:] if " = bf16[" in line]
+    assert made and all(
+        any(op in line for op in ("custom-call(", "get-tuple-element(",
+                                  " bitcast("))
+        for line in made), [m[:120] for m in made][:5]
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= plan.resident
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < HBM_BYTES

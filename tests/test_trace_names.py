@@ -1,15 +1,18 @@
 """The names the trace's readers find the device programs by, pinned where
 the programs are made: each lowers on the CPU under the module name the
 benchmark's readers match (``crc_verify_roofline_pct``,
-``rs_decode_roofline_pct``, ``ici_round_ms``, ``ckpt_assemble_roofline_pct``)
-and carries its ``tpudfs.*`` scope in the lowered text. The restore's
-``ckpt.*`` span names, which four readers match, and the Grain infeed's six
-``infeed.*`` spans, which four more match, are pinned beside them."""
+``rs_decode_roofline_pct``, ``ici_round_ms``, ``ckpt_assemble_roofline_pct``,
+``reshard_ici_roofline_pct``, ``reshard_assemble_roofline_pct``) and
+carries its ``tpudfs.*`` scope in the lowered text. The restore's
+``ckpt.*`` span names, which five readers match (and those of a restore
+under another layout, two more), and the Grain infeed's six ``infeed.*``
+spans, which four more match, are pinned beside them."""
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from tpudfs.tpu.crc32c_pallas import (
@@ -83,6 +86,43 @@ def _ckpt_gather():
         jax.ShapeDtypeStruct((4,), jnp.int32), nblocks=4)
 
 
+def _reshard_plan():
+    """A two-rank save of one (8, 256) bf16 tensor restored as row halves
+    on a 2x2: one chip-to-chip move, one assembly a chip."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from tpudfs.tpu import ckpt_reshard
+    from tpudfs.tpu.checkpoint import _dtype_of
+
+    def piece(shard, start):
+        return {"shard": shard, "size": 2048, "tensors": [{
+            "name": "w", "dtype": "bfloat16", "shape": [4, 256],
+            "offset": 0, "size": 2048, "crc32c": 0, "global_shape": [8, 256],
+            "start": [start, 0]}]}
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("e", "m"))
+    return ckpt_reshard.plan({"shards": [piece(0, 0), piece(1, 4)]},
+                             ckpt_reshard.Target(mesh, {"w": P("m")}),
+                             _dtype_of, 4 * CHUNKS * 512)
+
+
+def _reshard_ici():
+    from tpudfs.tpu import ckpt_reshard
+
+    plan = _reshard_plan()
+    _mesh, program = ckpt_reshard._ici_program(
+        tuple(plan.devices), plan.block_rows, plan.sends)
+    return program.lower(_words(4 * plan.stage_rows))
+
+
+def _reshard_assemble():
+    from tpudfs.tpu import ckpt_reshard
+
+    plan = _reshard_plan()
+    return ckpt_reshard._assembler(plan.layouts[0], False).lower(
+        _words(plan.stage_rows), _words(plan.block_rows))
+
+
 def _ici_replicate():
     mesh = make_mesh(jax.devices()[:4])
     return IciReplicator(mesh, replication=3)._fn.lower(
@@ -110,6 +150,8 @@ def _ec_gather():
     (_rs_decode_block, "jit_rs_decode_block", "tpudfs.rs_decode"),
     (_ckpt_assemble, "jit_ckpt_assemble", "tpudfs.ckpt_assemble"),
     (_ckpt_gather, "jit_ckpt_assemble_gather", "tpudfs.ckpt_assemble"),
+    (_reshard_ici, "jit_ckpt_reshard_ici", "tpudfs.ckpt_reshard_ici"),
+    (_reshard_assemble, "jit_ckpt_reshard_assemble", "tpudfs.ckpt_reshard"),
     (_ici_replicate, "jit_step", "tpudfs.ici_replicate"),
     (_ec_scatter, "jit_step", "tpudfs.ec_scatter"),
     (_ec_gather, "jit_step", "tpudfs.ec_gather"),
@@ -240,3 +282,51 @@ async def test_an_infeed_epoch_records_its_six_spans(tmp_path):
                for r in by_name["infeed.collate"])
     assert len(by_name["infeed.next_wait"]) == batches + 1  # and the end
     assert infeed_threads() == []  # nothing outlives the pipeline
+
+
+async def test_a_restore_under_another_layout_records_its_spans(tmp_path):
+    """The names ``reshard_plan_ms_per_restore``, ``ckpt_read_ms_per_restore``
+    and ``reshard_assemble_roofline_pct`` match, their attrs, and what
+    hangs under what; the four counters."""
+    from tests.test_checkpoint_reshard import _saved, _target
+    from tests.test_checkpoint_reshard import CFG
+    from tpudfs.common import telemetry
+
+    c, _client, mgr, manifest, _trees = await _saved(tmp_path)
+    try:
+        telemetry.enable()
+        try:
+            await mgr.restore(target=_target(CFG))
+        finally:
+            records = telemetry.drain()
+            telemetry.disable()
+    finally:
+        await c.stop()
+    by_name: dict = {}
+    for r in records:
+        by_name.setdefault(r.name, []).append(r)
+    (whole,) = by_name["ckpt.restore"]
+    files = len(manifest["shards"])
+    children = {"ckpt.latest_step": 1, "ckpt.manifest": 1, "ckpt.plan": 1,
+                "ckpt.read_shard": files, "ckpt.confirm": files,
+                "ckpt.combined_crc": files, "ckpt.redistribute": 1,
+                "ckpt.assemble": 4}
+    assert {n for n in by_name if n.startswith("ckpt.")} \
+        == {"ckpt.restore", *children}
+    for name, count in children.items():
+        assert len(by_name[name]) == count, name
+        assert all(r.parent_id == whole.span_id for r in by_name[name]), name
+    (plan,) = by_name["ckpt.plan"]
+    assert plan.attrs["devices"] == 4 and plan.attrs["pieces"] > 0
+    assert plan.attrs["reads"] == sum(-(-s["size"] // (64 * 1024))
+                                      for s in manifest["shards"])
+    assert by_name["ckpt.redistribute"][0].attrs["bytes"] \
+        == mgr.stats["reshard_ici_bytes"] > 0
+    assert sorted(r.attrs["device"] for r in by_name["ckpt.assemble"]) \
+        == sorted(d.id for d in jax.devices()[:4])
+    assert all(r.attrs["bytes"] > 0 and r.attrs["tensors"] == 11
+               for r in by_name["ckpt.assemble"])
+    assert all(r.attrs["devices"] >= 1 for r in by_name["ckpt.read_shard"])
+    assert {k for k in mgr.stats if k.startswith("reshard_")} == {
+        "reshard_unique_bytes", "reshard_h2d_bytes", "reshard_ici_bytes",
+        "reshard_pieces"}

@@ -43,7 +43,11 @@ name/dtype/shape/offset/size/crc32c per tensor plus the whole-payload
 CRC; the manifest aggregates the specs of all shards. ``dtype`` is the
 numpy NAME (``"bfloat16"``, ``"float32"``): ``dtype.str`` of an
 ``ml_dtypes`` type is a void (``"<V2"``). Manifests that carry ``.str``
-codes (``"<f4"``) still load.
+codes (``"<f4"``) still load. A rank of a sharded job may save PIECES of
+global tensors (``pieces={name: Piece(global_shape, start)}``): the spec of
+a piece adds ``global_shape`` and ``start``, and ``name`` is the global
+tensor's; a tensor saved whole carries neither key, so manifests of the
+format as it was (``tpudfs-ckpt-1``, unchanged) load and restore as before.
 
 Device assembly (:mod:`tpudfs.tpu.ckpt_assemble`): the rounds (and single
 blocks) a shard's read left in HBM are gathered into one row buffer in
@@ -59,11 +63,18 @@ TPU, float16 and bf16 scalars or ragged vectors) bounce through the host
 the program has them: during a shard's restore HBM holds at most twice the
 shard beside the rounds in flight.
 
+Restore under another layout (``restore(target=...)``,
+:mod:`tpudfs.tpu.ckpt_reshard`): a mesh, a ``PartitionSpec`` per global
+tensor and this host's index range of each; the planned blocks land on the
+chips that need them, one ``confirm`` and the combined CRC a shard file,
+one chip-to-chip move and one assembly a chip, then ``{name: jax.Array}``.
+
 Spans (``tpudfs.common.telemetry``; sites and attrs in
 ``docs/operations.md``): ``ckpt.restore`` with children
 ``ckpt.latest_step``, ``ckpt.manifest`` and, per shard,
 ``ckpt.read_shard``, ``ckpt.confirm``, ``ckpt.combined_crc``,
-``ckpt.assemble``.
+``ckpt.assemble``; under another layout ``ckpt.plan``, the per-shard
+three, ``ckpt.redistribute`` and ``ckpt.assemble`` per chip.
 """
 
 from __future__ import annotations
@@ -147,26 +158,55 @@ class TensorSpec:
     offset: int
     size: int
     crc32c: int
+    #: a piece of a global tensor (``name`` is the global tensor's): its
+    #: shape and where the piece starts in it; None for a tensor saved
+    #: whole, and then not written, so such a spec is what it always was
+    global_shape: tuple[int, ...] | None = None
+    start: tuple[int, ...] | None = None
 
     def to_dict(self) -> dict:
         d = self.__dict__.copy()
         d["shape"] = list(self.shape)
+        for key in ("global_shape", "start"):
+            if d[key] is None:
+                del d[key]
+            else:
+                d[key] = list(d[key])
         return d
 
     @classmethod
     def from_dict(cls, d: dict) -> "TensorSpec":
         d = dict(d)
-        d["shape"] = tuple(d["shape"])
+        for key in ("shape", "global_shape", "start"):
+            if d.get(key) is not None:
+                d[key] = tuple(d[key])
         return cls(**d)
 
 
-def pack_shard(tree: dict) -> tuple[bytes, list[TensorSpec]]:
+@dataclasses.dataclass(frozen=True)
+class Piece:
+    """Where a saved array lies in its global tensor: the global shape and
+    the index the piece starts at (a rank's slice of a sharded tensor)."""
+
+    global_shape: tuple[int, ...]
+    start: tuple[int, ...]
+
+
+def pack_shard(tree: dict, pieces: dict | None = None
+               ) -> tuple[bytes, list[TensorSpec]]:
     """Serialize a flat ``{name: array}`` tree into one payload.
+    ``pieces[name]`` (a :class:`Piece`) marks an array as a piece of the
+    global tensor ``name``; a piece that is the whole tensor is recorded as
+    a whole tensor.
 
     Deterministic: tensors in sorted name order at aligned offsets, so the
     same tree always produces byte-identical payloads — which is what
     makes the content-ETag resume probe (and the chaos tier's bit-exact
     assertions) sound."""
+    pieces = pieces or {}
+    if set(pieces) - set(tree):
+        raise ValueError(f"pieces of tensors not in the tree: "
+                         f"{sorted(set(pieces) - set(tree))}")
     buf = bytearray()
     specs: list[TensorSpec] = []
     for name in sorted(tree):
@@ -176,9 +216,20 @@ def pack_shard(tree: dict) -> tuple[bytes, list[TensorSpec]]:
         raw = arr.tobytes()
         offset = _align(len(buf))
         buf.extend(b"\x00" * (offset - len(buf)))
-        specs.append(TensorSpec(name=name, dtype=arr.dtype.name,
-                                shape=tuple(arr.shape), offset=offset,
-                                size=len(raw), crc32c=crc32c(raw)))
+        spec = TensorSpec(name=name, dtype=arr.dtype.name,
+                          shape=tuple(arr.shape), offset=offset,
+                          size=len(raw), crc32c=crc32c(raw))
+        piece = pieces.get(name)
+        if piece is not None:
+            gshape, start = tuple(piece.global_shape), tuple(piece.start)
+            if len(gshape) != arr.ndim or len(start) != arr.ndim or any(
+                    a < 0 or a + n > g
+                    for a, n, g in zip(start, arr.shape, gshape)):
+                raise ValueError(f"{name}: a piece of {arr.shape} at "
+                                 f"{start} does not fit in {gshape}")
+            if gshape != arr.shape:
+                spec.global_shape, spec.start = gshape, start
+        specs.append(spec)
         buf.extend(raw)
     return bytes(buf), specs
 
@@ -216,6 +267,24 @@ def _validate_manifest(body: bytes) -> dict:
             f"manifest lists {len(manifest['shards'])} shard specs for "
             f"num_shards={manifest['num_shards']}")
     return manifest
+
+
+def _whole_on_one(manifest: dict, target) -> bool:
+    """Whether ``target`` is the saved layout on one chip: one device,
+    every tensor saved whole (once) and wanted whole."""
+    if target.mesh.size != 1:
+        return False
+    names = set()
+    for spec in manifest["shards"]:
+        for t in spec["tensors"]:
+            shape = tuple(t["shape"])
+            if t.get("global_shape") is not None or t["name"] in names:
+                return False
+            names.add(t["name"])
+            rng = target.host_index.get(t["name"])
+            if rng and tuple(map(tuple, rng)) != tuple((0, n) for n in shape):
+                return False
+    return True
 
 
 class CheckpointManager:
@@ -276,6 +345,14 @@ class CheckpointManager:
             # device, and those that went through the host on the way
             "tensor_bytes_device": 0,
             "tensor_bytes_host_bounce": 0,
+            # restore under another layout (``restore(target=...)``):
+            # bytes of the needed blocks, each once; bytes uploaded host to
+            # device (re-reads included); bytes moved chip to chip; pieces
+            # cut (a saved piece's part in one chip's shard)
+            "reshard_unique_bytes": 0,
+            "reshard_h2d_bytes": 0,
+            "reshard_ici_bytes": 0,
+            "reshard_pieces": 0,
         }
 
     @contextlib.contextmanager
@@ -294,14 +371,16 @@ class CheckpointManager:
         shard already durable?" is one metadata round-trip, no reread."""
         return f"ckpt-{crc:08x}-{size}"
 
-    async def save_shard(self, step: int, shard: int, tree: dict) -> dict:
+    async def save_shard(self, step: int, shard: int, tree: dict, *,
+                         pieces: dict | None = None) -> dict:
         """Durably write one shard's payload (hot + EC copies) and its
         spec. Idempotent: a payload already durable under the same content
         ETag is skipped, so a preempted replica that restarts re-puts only
-        what is incomplete. Returns the shard spec dict."""
+        what is incomplete. ``pieces`` as :func:`pack_shard` takes it.
+        Returns the shard spec dict."""
         if not 0 <= shard < self.num_shards:
             raise ValueError(f"shard {shard} out of range")
-        payload, tensors = pack_shard(tree)
+        payload, tensors = pack_shard(tree, pieces)
         crc = crc32c(payload)
         etag = self._content_etag(crc, len(payload))
         attrs = {"ckpt_step": str(step), "ckpt_shard": str(shard),
@@ -402,16 +481,19 @@ class CheckpointManager:
         specs = await asyncio.gather(*(one(s) for s in range(self.num_shards)))
         return sorted(specs, key=lambda s: s["shard"])
 
-    async def save(self, step: int, trees: dict[int, dict]) -> dict:
+    async def save(self, step: int, trees: dict[int, dict], *,
+                   pieces: dict[int, dict] | None = None) -> dict:
         """Convenience single-caller save: write every shard, then commit.
-        ``trees`` maps shard id -> tensor tree and must cover all shards."""
+        ``trees`` maps shard id -> tensor tree and must cover all shards;
+        ``pieces`` maps shard id -> that shard's ``pieces``."""
         if sorted(trees) != list(range(self.num_shards)):
             raise ValueError(
                 f"save(step={step}) needs trees for shards "
                 f"0..{self.num_shards - 1}, got {sorted(trees)}")
         with self._op_scope(self.save_budget_s):
             await asyncio.gather(*(
-                self.save_shard(step, shard, tree)
+                self.save_shard(step, shard, tree,
+                                pieces=(pieces or {}).get(shard))
                 for shard, tree in trees.items()
             ))
             return await self.commit(step)
@@ -455,10 +537,20 @@ class CheckpointManager:
 
     async def restore(self, step: int | None = None, *,
                       shards: list[int] | None = None,
-                      device=None) -> dict[int, dict]:
+                      device=None, target=None) -> dict:
         """Parallel shard-wise restore of ``step`` (default: latest).
         Returns ``{shard: {name: array}}``; arrays are host numpy unless
-        ``device`` (and a reader) put them in HBM."""
+        ``device`` (and a reader) put them in HBM.
+
+        With ``target`` (a :class:`~tpudfs.tpu.ckpt_reshard.Target`: a mesh,
+        a ``PartitionSpec`` per global tensor, this host's index range of
+        each) it returns ``{global name: jax.Array}`` sharded as the target
+        says, whatever layout the pieces were saved in: planned blocks read
+        through the reader's combiner of the chip each lands on, one
+        ``confirm`` and the combined CRC a shard file, then one chip-to-chip
+        move and one assembly a chip (:mod:`tpudfs.tpu.ckpt_reshard`). A
+        target that is the saved layout on one chip restores as
+        ``restore(device=that chip)`` does, array for array."""
         with telemetry.span("ckpt.restore") as whole:
             if step is None:
                 with telemetry.span("ckpt.latest_step"):
@@ -468,6 +560,10 @@ class CheckpointManager:
                         f"no published checkpoints under {self.base}")
             with telemetry.span("ckpt.manifest", step=step):
                 manifest = await self.read_manifest(step)
+            if target is not None:
+                whole.set(step=step, shards=len(manifest["shards"]))
+                with self._op_scope(self.restore_budget_s):
+                    return await self._restore_target(manifest, target)
             by_id = {s["shard"]: s for s in manifest["shards"]}
             want = sorted(by_id) if shards is None else list(shards)
             whole.set(step=step, shards=len(want))
@@ -533,36 +629,7 @@ class CheckpointManager:
         from tpudfs.tpu import ckpt_assemble
 
         shard = spec["shard"]
-        sources = [(p, kind) for p, kind in ((spec.get("path"), "hot"),
-                                             (spec.get("ec_path"), "ec"))
-                   if p is not None]
-        blocks = None
-        last: Exception | None = None
-        for i, (path, kind) in enumerate(sources):
-            if i > 0:
-                self.stats["degraded_shard_reads"] += 1
-                logger.warning(
-                    "shard %s: hot copy unreadable in HBM path (%s); "
-                    "reconstructing from EC cold copy %s", shard, last, path)
-            try:
-                with telemetry.span("ckpt.read_shard", shard=shard,
-                                    source=kind) as reading:
-                    blocks = await self.reader.read_file_to_device_blocks(
-                        path, verify="lazy")
-                    reading.set(blocks=len(blocks))
-                with telemetry.span("ckpt.confirm", shard=shard,
-                                    blocks=len(blocks)):
-                    await self.reader.confirm(blocks)
-                with telemetry.span("ckpt.combined_crc", shard=shard):
-                    self._check_combined_crc(
-                        path, spec, [b.source for b in blocks])
-                break
-            except _READ_ERRORS as e:
-                blocks, last = None, e
-        if blocks is None:
-            raise DegradedRestoreError(
-                f"shard {shard} unrestorable into HBM: every copy "
-                f"failed ({last})")
+        blocks, _uploaded = await self._read_confirmed(spec)
         tensors = spec["tensors"]
         with telemetry.span("ckpt.assemble", shard=shard,
                             tensors=len(tensors), bytes=spec["size"]):
@@ -574,15 +641,147 @@ class CheckpointManager:
         self.stats["tensor_bytes_host_bounce"] += bounced
         return tree
 
-    async def warm_restore(self, device, step: int | None = None) -> None:
-        """Pre-compile what ``restore(step, device=device)`` dispatches on
-        the device after its blocks are in: per shard, the gather at every
-        round size the reader's combiner ships and the assembly of the
-        shard's layout (H2D-free, on zeros). The read's own programs are
-        the reader's to warm (``HbmReader.warm_batches``)."""
+    async def _read_confirmed(self, spec: dict, device_of=None
+                              ) -> tuple[list, int]:
+        """One shard file's blocks in HBM, every one CRC-verified on the
+        device it landed on (ONE ``confirm``) and the whole-shard CRC
+        reconciled from the master's per-block checksums, degrading from
+        the hot copy to the EC cold copy. ``device_of(i)``: the device
+        block ``i`` lands on, None to leave it unread (default: the
+        reader's own placement). Returns the blocks in file order (None
+        where left) and the bytes uploaded, re-reads included."""
+        shard = spec["shard"]
+        sources = [(p, kind) for p, kind in ((spec.get("path"), "hot"),
+                                             (spec.get("ec_path"), "ec"))
+                   if p is not None]
+        uploaded = 0
+        last: Exception | None = None
+        for i, (path, kind) in enumerate(sources):
+            if i > 0:
+                self.stats["degraded_shard_reads"] += 1
+                logger.warning(
+                    "shard %s: hot copy unreadable in HBM path (%s); "
+                    "reconstructing from EC cold copy %s", shard, last, path)
+            try:
+                with telemetry.span("ckpt.read_shard", shard=shard,
+                                    source=kind) as reading:
+                    if device_of is None:
+                        blocks = await self.reader.read_file_to_device_blocks(
+                            path, verify="lazy")
+                    else:
+                        blocks = await self.reader.read_file_to_device_blocks(
+                            path, verify="lazy", placement=device_of)
+                        reading.set(devices=len({id(b.device) for b in blocks
+                                                 if b is not None}))
+                    reading.set(blocks=len(blocks))
+                held = [b for b in blocks if b is not None]
+                # what each block's bytes are now: a re-read in confirm
+                # replaces them, and is another upload
+                before = [b.batch if b.batch is not None else b.array
+                          for b in held]
+                uploaded += sum(b.size for b in held)
+                try:
+                    with telemetry.span("ckpt.confirm", shard=shard,
+                                        blocks=len(held)):
+                        await self.reader.confirm(held)
+                finally:
+                    uploaded += sum(
+                        b.size for b, was in zip(held, before)
+                        if (b.batch if b.batch is not None else b.array)
+                        is not was)
+                with telemetry.span("ckpt.combined_crc", shard=shard):
+                    if len(held) == len(blocks):
+                        listed = [b.source for b in held]
+                    else:
+                        listed = (await self.client.get_file_info(path)
+                                  or {}).get("blocks") or []
+                    self._check_combined_crc(path, spec, listed)
+                return blocks, uploaded
+            except _READ_ERRORS as e:
+                last = e
+        raise DegradedRestoreError(
+            f"shard {shard} unrestorable into HBM: every copy "
+            f"failed ({last})")
+
+    async def _restore_target(self, manifest: dict, target) -> dict:
+        """``restore(target=...)``: plan, read, move, assemble."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from tpudfs.tpu import ckpt_reshard
+
+        if self.reader is None:
+            raise ValueError("a restore onto a target needs a reader")
+        devices = list(target.mesh.devices.flat)
+        if _whole_on_one(manifest, target):
+            trees = await asyncio.gather(*(
+                self.restore_shard(manifest, s["shard"], device=devices[0])
+                for s in manifest["shards"]))
+            return {name: jax.make_array_from_single_device_arrays(
+                        arr.shape,
+                        NamedSharding(target.mesh,
+                                      target.specs.get(name,
+                                                       PartitionSpec())),
+                        [arr])
+                    for tree in trees for name, arr in tree.items()}
+        with telemetry.span("ckpt.plan") as planning:
+            plan = ckpt_reshard.plan(manifest, target, _dtype_of,
+                                     self.client.block_size)
+            planning.set(pieces=plan.pieces, reads=plan.uploads,
+                         devices=len(devices))
+
+        async def one(spec: dict):
+            where = plan.placement[spec["shard"]]
+            if all(d is None for d in where):
+                return spec["shard"], [], 0
+
+            def device_of(j: int):
+                d = where[j] if j < len(where) else None
+                return None if d is None else devices[d]
+
+            blocks, uploaded = await self._read_confirmed(spec, device_of)
+            return spec["shard"], blocks, uploaded
+
+        read = await asyncio.gather(*(one(s) for s in manifest["shards"]))
+        with telemetry.span("ckpt.redistribute", bytes=plan.ici_bytes):
+            stages = ckpt_reshard.stage(plan, {s: b for s, b, _u in read})
+            inboxes = ckpt_reshard.redistribute(plan, stages)
+        self.stats["reshard_h2d_bytes"] += sum(u for _s, _b, u in read)
+        self.stats["restored_shards"] += sum(1 for _s, b, _u in read if b)
+        del read
+        per_device = []
+        for i, device in enumerate(devices):
+            with telemetry.span("ckpt.assemble", device=device.id,
+                                tensors=len(plan.outputs),
+                                bytes=plan.resident):
+                shards, on_dev, bounced = ckpt_reshard.assemble(
+                    plan, i, stages[i], inboxes[i])
+            stages[i] = inboxes[i] = None  # the program holds them
+            per_device.append(shards)
+            self.stats["tensor_bytes_device"] += on_dev
+            self.stats["tensor_bytes_host_bounce"] += bounced
+        self.stats["reshard_unique_bytes"] += plan.unique_bytes
+        self.stats["reshard_ici_bytes"] += plan.ici_bytes
+        self.stats["reshard_pieces"] += plan.pieces
+        return ckpt_reshard.arrays(plan, per_device)
+
+    async def warm_restore(self, device=None, step: int | None = None, *,
+                           target=None) -> None:
+        """Pre-compile what ``restore(step, device=device)`` (or
+        ``restore(step, target=target)``) dispatches on the device after
+        its blocks are in: per shard, the gather at every round size the
+        reader's combiner ships and the assembly of the shard's layout
+        (H2D-free, on zeros); for a target, every chip's gathers, the
+        chip-to-chip move and every chip's assembly. The read's own
+        programs are the reader's to warm (``HbmReader.warm_batches``)."""
         from tpudfs.tpu import ckpt_assemble
 
         manifest = await self.read_manifest(step)
+        if target is not None:
+            if not _whole_on_one(manifest, target):
+                await self._warm_target(manifest, target)
+                return
+            device = next(iter(target.mesh.devices.flat))
         for spec in manifest["shards"]:
             tensors = spec["tensors"]
             await asyncio.to_thread(
@@ -590,6 +789,16 @@ class CheckpointManager:
                 [_dtype_of(t["dtype"]) for t in tensors], spec["size"],
                 device, self.client.block_size // _ALIGN,
                 getattr(self.reader, "batch_reads", 0))
+
+    async def _warm_target(self, manifest: dict, target) -> None:
+        from tpudfs.tpu import ckpt_reshard
+
+        bb = self.client.block_size
+        plan = ckpt_reshard.plan(manifest, target, _dtype_of, bb)
+        short = {-(-(s["size"] - (-(-s["size"] // bb) - 1) * bb) // _ALIGN)
+                 for s in manifest["shards"] if s["size"]}
+        await asyncio.to_thread(ckpt_reshard.warm, plan,
+                                getattr(self.reader, "batch_reads", 0), short)
 
     @staticmethod
     def _check_combined_crc(path: str, spec: dict,

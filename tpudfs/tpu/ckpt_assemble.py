@@ -125,8 +125,38 @@ def _relabel_bf16(bits):
         in_specs=[spec], out_specs=spec, name="tpudfs.ckpt_relabel")(bits)
 
 
-def _typed(bits, dtype: np.dtype, shape: tuple):
-    """``bits`` (flat, unsigned, the dtype's width) as the tensor."""
+#: (dtype name, shape, device kind) -> ``default_order``'s answer
+_ORDERS: dict = {}
+
+
+def default_order(dtype_name: str, shape: tuple, device) -> tuple | None:
+    """The dims of ``shape`` major to minor as the chip lays out such an
+    array by default, where that is not row-major (None): a v5e puts the
+    second-minor dim of ``(2048, 704)`` or ``(8, 2048, 704)`` bf16 minor,
+    to pad less. Asked of the compiler, once a shape and kind of chip."""
+    key = (dtype_name, tuple(shape), device.device_kind)
+    if len(shape) < 2:
+        return None
+    if key not in _ORDERS:
+        out = jax.jit(lambda: jnp.zeros(shape, np.dtype(dtype_name)),
+                      out_shardings=jax.sharding.SingleDeviceSharding(device)
+                      ).lower().compile().output_formats
+        order = tuple(out.layout.major_to_minor)
+        _ORDERS[key] = None if order == tuple(range(len(shape))) else order
+    return _ORDERS[key]
+
+
+def _typed(bits, dtype: np.dtype, shape: tuple, order: tuple | None = None):
+    """``bits`` (flat, unsigned, the dtype's width) as the tensor. ``order``
+    (``default_order``): the layout the tensor will have; the relabelling
+    kernel then runs on the bits moved into that order (integers: nothing
+    lost), and going back to ``shape`` is a free bitcast, where XLA would
+    otherwise move the bf16 result into that layout itself."""
+    if order is not None and dtype.name == "bfloat16" and on_tpu():
+        physical = tuple(shape[d] for d in order)
+        moved = jnp.transpose(bits.reshape(shape), order)
+        return jnp.transpose(_typed(moved, dtype, physical),
+                             tuple(np.argsort(order)))
     if dtype.name == "bfloat16" and on_tpu():
         # The reshape after the kernel is a bitcast: nothing runs.
         return _relabel_bf16(bits.reshape(_relabel_shape(shape))) \

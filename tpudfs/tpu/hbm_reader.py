@@ -748,13 +748,15 @@ class HbmReader:
 
     async def read_file_to_device_blocks(
         self, path: str, verify: bool | str = True,
-        placement: str = "round_robin",
-    ) -> list[DeviceBlock]:
+        placement="round_robin",
+    ) -> list[DeviceBlock | None]:
         """Fetch every block concurrently with per-block device placement
         (the fan-out of mod.rs:880-916 with DMA placement instead of host
         concat). ``round_robin``: block i → device i % n (spreads a stream of
         blocks). ``contiguous``: block i → device i // ceil(blocks/n) (keeps
-        file order within each device — required for read_file_sharded)."""
+        file order within each device — required for read_file_sharded).
+        A callable: block i → ``placement(i)``, any device (its own
+        combiner), or None: the block is not read and its entry is None."""
         with telemetry.span("hbm.read_file") as whole:
             meta = await self.client.get_file_info(path)
             if meta is None:
@@ -762,16 +764,20 @@ class HbmReader:
             blocks = meta["blocks"]
             whole.set(blocks=len(blocks))
             n = len(self.devices)
-            if placement == "contiguous":
+            if callable(placement):
+                device_of = placement
+            elif placement == "contiguous":
                 per = -(-len(blocks) // n) if blocks else 1
                 device_of = lambda i: self.devices[i // per]  # noqa: E731
             else:
                 device_of = lambda i: self.devices[i % n]  # noqa: E731
-            coros = [
-                self.read_block_to_device(block, device_of(i), verify=verify)
-                for i, block in enumerate(blocks)
-            ]
-            return list(await asyncio.gather(*coros))
+            where = [device_of(i) for i in range(len(blocks))]
+            got = iter(await asyncio.gather(*(
+                self.read_block_to_device(block, device, verify=verify)
+                for block, device in zip(blocks, where)
+                if device is not None)))
+            return [None if device is None else next(got)
+                    for device in where]
 
     async def read_file_sharded(self, path: str, mesh: Mesh | None = None,
                                 verify: bool | str = True) -> jax.Array:
