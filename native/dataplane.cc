@@ -521,14 +521,32 @@ bool write_all(Stream& s, const void* buf, size_t n) {
   return true;
 }
 
-bool send_frame(Stream& s, const std::string& header, const uint8_t* payload,
-                uint64_t plen) {
+// A frame's header and payload length; its plen payload bytes follow.
+bool send_frame_head(Stream& s, const std::string& header, uint64_t plen) {
   // Length prefixes are little-endian ("<I"/"<Q") — x86-64 is LE.
   uint32_t hl = static_cast<uint32_t>(header.size());
   if (!write_all(s, &hl, 4)) return false;
   if (!write_all(s, header.data(), header.size())) return false;
-  if (!write_all(s, &plen, 8)) return false;
+  return write_all(s, &plen, 8);
+}
+
+bool send_frame(Stream& s, const std::string& header, const uint8_t* payload,
+                uint64_t plen) {
+  if (!send_frame_head(s, header, plen)) return false;
   if (plen && !write_all(s, payload, plen)) return false;
+  return true;
+}
+
+// Exactly n bytes of fd at off; false on an error or an early end of file.
+bool pread_exact(int fd, uint8_t* buf, size_t n, uint64_t off) {
+  while (n) {
+    ssize_t r = ::pread(fd, buf, n, static_cast<off_t>(off));
+    if (r < 0 && errno == EINTR) continue;
+    if (r <= 0) return false;
+    buf += r;
+    off += static_cast<uint64_t>(r);
+    n -= static_cast<size_t>(r);
+  }
   return true;
 }
 
@@ -1498,13 +1516,14 @@ class Engine {
   // header built: cache lookup, stat, pread + sidecar verify, or the
   // cache copy), send_ns (send_frame), NOT_FOUND answers, calls served
   // from the block cache, QoS admission wait. ReadBlocks: frames, slots,
-  // bytes, read_ns (handler start through the size estimate and every
-  // slot's pread to the header built: all the client waits for before
-  // the header), send_ns (the frame's send_frame, paced by the client's
-  // receive), slots answered -1, QoS admission wait. Each connection is
-  // served by a thread of its own, so there is no queue on this side but
-  // admission (0 while QoS is off).
-  void read_stage_stats(uint64_t out[14]) const {
+  // bytes sent, read_ns (the engine's own read time: every slot's open and
+  // fstat up to the header, then each block's pread), send_ns (the header's
+  // and each block's write, summed: paced by the transfer to the client),
+  // slots answered -1, QoS admission wait, frames torn after their header
+  // (a pread failed or came up short).
+  // Each connection is served by a thread of its own, so there is no queue
+  // on this side but admission (0 while QoS is off).
+  void read_stage_stats(uint64_t out[15]) const {
     out[0] = rb_calls_.load();
     out[1] = rb_bytes_.load();
     out[2] = rb_read_ns_.load();
@@ -1519,6 +1538,7 @@ class Engine {
     out[11] = rbs_send_ns_.load();
     out[12] = rbs_missing_.load();
     out[13] = rbs_admit_ns_.load();
+    out[14] = rbs_torn_.load();
   }
 
   // ------------------------------------------------------------ qos plane
@@ -1696,6 +1716,8 @@ class Engine {
   void conn_loop(Stream& s) {
     // Per-connection cache of downstream chain streams.
     std::map<std::string, Stream> downstream;
+    // Per-connection buffer a ReadBlocks frame's blocks are read into.
+    std::vector<uint8_t> read_buf;
     while (running_.load()) {
       std::map<std::string, Value> h;
       std::vector<uint8_t> payload;
@@ -1753,7 +1775,9 @@ class Engine {
       } else if (method == "ReadBlock") {
         handle_read(s, h);
       } else {
-        handle_read_batch(s, h);
+        // false = the frame was cut after its header: the client's
+        // read of its payload must see the connection close.
+        keep = handle_read_batch(s, h, read_buf);
       }
       if (admitted)
         qos_.release(tenant,
@@ -2829,102 +2853,70 @@ class Engine {
   // re-verifies end-to-end against the recorded whole-block checksum and
   // routes mismatches to the per-block VERIFIED path, which detects the
   // rot, reports it, and triggers recovery.
-  void handle_read_batch(Stream& s, std::map<std::string, Value>& h) {
+  //
+  // The frame goes out as it is read: every slot is opened and sized
+  // first (the header needs the sizes), the header goes out, then each
+  // block is pread into `buf` and written before the next is read, so the
+  // client receives the first blocks while the engine reads the rest. An
+  // open fd keeps a block's bytes readable if it is unlinked or replaced
+  // meanwhile (a balancer move deletes its source while a frame may still
+  // be in flight). A pread that fails or comes up short after the header
+  // tears the frame: nothing more is sent (never a byte that was not
+  // read), and false closes the connection, so the client's round fails
+  // and falls back per block as on any transport error.
+  bool handle_read_batch(Stream& s, std::map<std::string, Value>& h,
+                         std::vector<uint8_t>& buf) {
     const uint64_t t0 = now_ns();
     const bool timed = h.count("_rt") != 0;
     const std::vector<std::string> ids =
         h.count("block_ids") ? h["block_ids"].astr
                              : std::vector<std::string>{};
-    std::vector<int64_t> sizes;
-    std::vector<uint8_t> payload;
-    sizes.reserve(ids.size());
     constexpr size_t kMaxSlots = 256;
-    constexpr size_t kMaxBatchBytes = 96ull << 20;  // < 100 MiB frame caps
-    // One allocation for the whole frame: growing block-by-block would
-    // realloc-copy the accumulated payload several times per 16-48 MiB
-    // round (round-5 remote-read budget).
-    {
-      size_t est = 0;
-      struct stat st;
-      for (const auto& block_id : ids) {
-        if (est >= kMaxBatchBytes || block_id.empty()) continue;
-        std::string p = hot_ + "/" + block_id;
-        if (::stat(p.c_str(), &st) == 0 ||
-            (!cold_.empty() &&
-             ::stat((cold_ + "/" + block_id).c_str(), &st) == 0))
-          est += static_cast<uint64_t>(st.st_size);
-      }
-      payload.reserve(est < kMaxBatchBytes ? est : kMaxBatchBytes);
-    }
-    for (const auto& block_id : ids) {
+    constexpr uint64_t kMaxBatchBytes = 96ull << 20;  // < 100 MiB frame caps
+    // A block larger than this is read and sent in pieces of it, so the
+    // buffer stays bounded whatever the block size.
+    constexpr uint64_t kMaxPiece = 4ull << 20;
+    // A slot is sent from the cache (`cached`), from its open file (`fd`),
+    // or not at all (size -1).
+    struct Slot {
+      int64_t size = -1;
+      int fd = -1;
+      CacheData cached;
+    };
+    std::vector<Slot> slots(ids.size());
+    uint64_t plen = 0;
+    for (size_t i = 0; i < ids.size(); i++) {
+      const std::string& block_id = ids[i];
+      Slot& sl = slots[i];
       reads_.fetch_add(1);
-      if (sizes.size() >= kMaxSlots || payload.size() >= kMaxBatchBytes) {
-        sizes.push_back(-1);  // over budget: caller falls back/re-requests
-        continue;
-      }
+      if (i >= kMaxSlots || plen >= kMaxBatchBytes)
+        continue;  // over budget: caller falls back/re-requests
       if (block_id.empty() || block_id[0] == '.' ||
-          block_id.find('/') != std::string::npos) {
-        sizes.push_back(-1);
+          block_id.find('/') != std::string::npos)
         continue;
-      }
       if (CacheData cached = cache_get(block_id)) {
-        if (payload.size() + cached->size() > kMaxBatchBytes) {
-          sizes.push_back(-1);
-          continue;
+        if (plen + cached->size() <= kMaxBatchBytes) {
+          sl.size = static_cast<int64_t>(cached->size());
+          sl.cached = std::move(cached);
+          plen += sl.cached->size();
         }
-        payload.insert(payload.end(), cached->begin(), cached->end());
-        sizes.push_back(static_cast<int64_t>(cached->size()));
         continue;
       }
-      std::string data_path = hot_ + "/" + block_id;
+      // The open is the existence check: hot tier, then cold.
+      int fd = ::open((hot_ + "/" + block_id).c_str(), O_RDONLY | O_CLOEXEC);
+      if (fd < 0 && !cold_.empty())
+        fd = ::open((cold_ + "/" + block_id).c_str(), O_RDONLY | O_CLOEXEC);
+      if (fd < 0) continue;
       struct stat st;
-      if (::stat(data_path.c_str(), &st) != 0) {
-        bool found = false;
-        if (!cold_.empty()) {
-          data_path = cold_ + "/" + block_id;
-          found = ::stat(data_path.c_str(), &st) == 0;
-        }
-        if (!found) {
-          sizes.push_back(-1);
-          continue;
-        }
-      }
-      uint64_t total = static_cast<uint64_t>(st.st_size);
-      size_t base = payload.size();
-      if (base + total > kMaxBatchBytes) {
-        sizes.push_back(-1);
+      if (::fstat(fd, &st) != 0 ||
+          plen + static_cast<uint64_t>(st.st_size) > kMaxBatchBytes) {
+        ::close(fd);
         continue;
       }
-      payload.resize(base + total);
-      // verify=0: every ReadBlocks consumer (the combiner's remote
-      // rounds) re-verifies END-TO-END — host CRC against the recorded
-      // whole-block checksum, or the on-device fold — and a mismatch
-      // falls back to the per-block VERIFIED path, which detects rot,
-      // reports it, and triggers recovery. A server-side sidecar verify
-      // here would be a second full CRC pass on the hot sweep path.
-      int64_t rc = tpudfs_block_read_verify(
-          data_path.c_str(), (data_path + ".meta").c_str(), 0, total,
-          payload.data() + base, 0, chunk_);
-      if (rc < 0 || static_cast<uint64_t>(rc) != total) {
-        payload.resize(base);
-        sizes.push_back(-1);
-        if (rc <= -200000) {
-          {
-            std::lock_guard<std::mutex> g(bad_mu_);
-            bad_.insert(block_id);
-          }
-          cache_invalidate(block_id);
-        }
-        continue;
-      }
-      sizes.push_back(static_cast<int64_t>(total));
-      // NOT cached: the batch read is unverified (consumers re-verify
-      // end-to-end), and the LRU must only ever hold VERIFIED bytes —
-      // caching here would let a corrupt replica poison later per-block
-      // reads that trust cache hits. (The streaming sweep shouldn't wash
-      // the cache anyway.)
+      sl.fd = fd;
+      sl.size = static_cast<int64_t>(st.st_size);
+      plen += static_cast<uint64_t>(st.st_size);
     }
-    const uint64_t read_ns = now_ns() - t0;
     Writer w;
     w.map_head(timed ? 4 : 3);
     w.str("ok");
@@ -2935,29 +2927,69 @@ class Engine {
     uint64_t missing = 0;
     {
       // Writer::aint clamps negatives to 0; hand-encode -1 slots.
-      if (sizes.size() < 16) w.raw(0x90 | sizes.size());
-      else { w.raw(0xdc); w.be(sizes.size(), 2); }
-      for (int64_t v : sizes) {
-        if (v < 0) {
+      if (slots.size() < 16) w.raw(0x90 | slots.size());
+      else { w.raw(0xdc); w.be(slots.size(), 2); }
+      for (const Slot& sl : slots) {
+        if (sl.size < 0) {
           w.raw(0xff);  // negative fixint -1
           missing++;
         } else {
-          w.uint(static_cast<uint64_t>(v));
+          w.uint(static_cast<uint64_t>(sl.size));
         }
       }
     }
+    uint64_t read_ns = now_ns() - t0;
     if (timed) {
+      // The time to the header: every slot opened and sized.
       w.str("_rns");
       w.uint(read_ns);
+    }
+    uint64_t t = now_ns();
+    bool ok = send_frame_head(s, w.out, plen);
+    uint64_t send_ns = now_ns() - t;
+    uint64_t sent = 0;
+    bool torn = false;
+    for (Slot& sl : slots) {
+      if (ok && sl.cached) {
+        t = now_ns();
+        ok = write_all(s, sl.cached->data(), sl.cached->size());
+        send_ns += now_ns() - t;
+        if (ok) sent += sl.cached->size();
+      } else if (ok && sl.fd >= 0) {
+        // NOT cached: the batch read is unverified (consumers re-verify
+        // end-to-end), and the LRU must only ever hold VERIFIED bytes —
+        // caching here would let a corrupt replica poison later per-block
+        // reads that trust cache hits. (The streaming sweep shouldn't
+        // wash the cache anyway.)
+        const uint64_t size = static_cast<uint64_t>(sl.size);
+        for (uint64_t off = 0; ok && off < size;) {
+          const size_t n =
+              static_cast<size_t>(std::min(size - off, kMaxPiece));
+          if (buf.size() < n) buf.resize(n);
+          t = now_ns();
+          torn = !pread_exact(sl.fd, buf.data(), n, off);
+          read_ns += now_ns() - t;
+          if (torn) {
+            ok = false;
+            break;
+          }
+          t = now_ns();
+          ok = write_all(s, buf.data(), n);
+          send_ns += now_ns() - t;
+          if (ok) sent += n;
+          off += n;
+        }
+      }
+      if (sl.fd >= 0) ::close(sl.fd);
     }
     rbs_frames_.fetch_add(1);
     rbs_slots_.fetch_add(ids.size());
     rbs_missing_.fetch_add(missing);
-    rbs_bytes_.fetch_add(payload.size());
+    rbs_bytes_.fetch_add(sent);
     rbs_read_ns_.fetch_add(read_ns);
-    const uint64_t t1 = now_ns();
-    send_frame(s, w.out, payload.data(), payload.size());
-    rbs_send_ns_.fetch_add(now_ns() - t1);
+    rbs_send_ns_.fetch_add(send_ns);
+    if (torn) rbs_torn_.fetch_add(1);
+    return ok;
   }
 
   std::string host_, hot_, cold_;
@@ -2978,7 +3010,8 @@ class Engine {
   std::atomic<uint64_t> rb_calls_{0}, rb_bytes_{0}, rb_read_ns_{0},
       rb_send_ns_{0}, rb_not_found_{0}, rb_cache_calls_{0}, rb_admit_ns_{0};
   std::atomic<uint64_t> rbs_frames_{0}, rbs_slots_{0}, rbs_bytes_{0},
-      rbs_read_ns_{0}, rbs_send_ns_{0}, rbs_missing_{0}, rbs_admit_ns_{0};
+      rbs_read_ns_{0}, rbs_send_ns_{0}, rbs_missing_{0}, rbs_admit_ns_{0},
+      rbs_torn_{0};
   std::thread accept_thread_, commit_thread_;
   std::atomic<int> active_{0};
   std::mutex conns_mu_;
@@ -3018,7 +3051,7 @@ extern "C" {
 // Bumped on any signature/behavior change of the dataplane C ABI; the
 // Python loader refuses to bind mismatched prebuilt libraries
 // (TPUDFS_NATIVE_LIB) instead of calling with wrong arity.
-int64_t tpudfs_dataplane_abi(void) { return 7; }
+int64_t tpudfs_dataplane_abi(void) { return 8; }
 
 int64_t tpudfs_dataplane_start(const char* host, const char* hot_dir,
                                const char* cold_dir, uint32_t chunk_size,
@@ -3101,11 +3134,12 @@ void tpudfs_dataplane_stream_stats(int64_t h, uint64_t out[8]) {
 
 // Read path stage clocks: rb_calls, rb_bytes, rb_read_ns, rb_send_ns,
 // rb_not_found, rb_cache_calls, rb_admit_ns, rbs_frames, rbs_slots,
-// rbs_bytes, rbs_read_ns, rbs_send_ns, rbs_missing, rbs_admit_ns.
-void tpudfs_dataplane_read_stats(int64_t h, uint64_t out[14]) {
+// rbs_bytes, rbs_read_ns, rbs_send_ns, rbs_missing, rbs_admit_ns,
+// rbs_torn (ABI 8).
+void tpudfs_dataplane_read_stats(int64_t h, uint64_t out[15]) {
   Engine* e = get_engine(h);
   if (e) e->read_stage_stats(out);
-  else for (int i = 0; i < 14; i++) out[i] = 0;
+  else for (int i = 0; i < 15; i++) out[i] = 0;
 }
 
 // QoS control contract (ABI 6). Python pushes the QosShedder config in

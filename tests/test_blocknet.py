@@ -869,3 +869,201 @@ async def test_tracing_off_leaves_every_read_frame_as_it_was(
     finally:
         await pool.close()
         await cluster.stop()
+
+
+# ------------------------------------- a ReadBlocks frame, sent as it is read
+
+
+def _recv_exact(sock, n: int) -> bytes:
+    """``n`` bytes off ``sock``, or fewer where the peer closes first."""
+    got = bytearray()
+    while len(got) < n:
+        part = sock.recv(min(n - len(got), 1 << 20))
+        if not part:
+            break
+        got += part
+    return bytes(got)
+
+
+def _frame_bytes(header: dict, payload: bytes) -> bytes:
+    h = msgpack.packb(header)
+    return (blocknet._U32.pack(len(h)) + h + blocknet._U64.pack(len(payload))
+            + payload)
+
+
+def _read_blocks_raw(port: int, *requests: list[str]) -> list[bytes]:
+    """Each ``ReadBlocks`` request in turn on one connection; each response
+    frame's bytes as they came off the wire."""
+    import socket
+
+    out = []
+    with socket.create_connection(("127.0.0.1", port), timeout=10) as sock:
+        for ids in requests:
+            sock.sendall(b"".join(blocknet._pack_frame(
+                {"m": "ReadBlocks", "block_ids": ids}, None)))
+            head = _recv_exact(sock, 4)
+            head += _recv_exact(sock, blocknet._U32.unpack(head)[0] + 8)
+            plen = blocknet._U64.unpack(head[-8:])[0]
+            out.append(head + _recv_exact(sock, plen))
+    return out
+
+
+async def test_read_blocks_frame_is_the_same_bytes_sent_block_by_block(
+        cluster, tmp_path):
+    """The native engine opens and sizes every slot, sends the header and
+    then each block as it reads it: the frame is byte for byte the one it
+    built whole before, for present, missing, bad, cached and short last
+    blocks and slots over the budget, and the connection stays framed."""
+    cs = await _plane(cluster, tmp_path, "native")
+    pool = BlockConnPool()
+    full, cached, short = _rand(3000, 24), _rand(2000, 25), _rand(1000, 26)
+    try:
+        for bid, data in (("sa", full), ("sc", cached), ("sl", short)):
+            cs.store.write(bid, data)
+        back = await pool.call(cluster.client, cs.address, SERVICE,
+                               "ReadBlock", {"block_id": "sc", "offset": 0,
+                                             "length": 0})
+        assert back["data"] == cached  # a verified whole read: now cached
+        # 256 slots fit a frame; the last two are over the slot budget.
+        ids = ["sa", "gone", "sc", "../sa", "sl"] + ["sa"] * 251 \
+            + ["sc", "sl"]
+        sizes = [3000, -1, 2000, -1, 1000] + [3000] * 251 + [-1, -1]
+        payload = full + cached + short + full * 251
+        before, hits = cs.read_stage_stats(), cs.data_plane_stats()
+        got = await asyncio.to_thread(_read_blocks_raw, cs.data_port, ids,
+                                      ["sl", "sa"])
+        assert got[0] == _frame_bytes(
+            {"ok": True, "_d": 1, "sizes": sizes}, payload)
+        assert got[1] == _frame_bytes(
+            {"ok": True, "_d": 1, "sizes": [1000, 3000]}, short + full)
+        moved = _moved(before, cs.read_stage_stats())
+        assert moved.pop("rbs_read_ns") > 0 and moved.pop("rbs_send_ns") > 0
+        assert moved == {"rbs_frames": 2, "rbs_slots": 260, "rbs_missing": 4,
+                         "rbs_bytes": len(payload) + 4000}
+        assert cs.data_plane_stats()["cache_hits"] - hits["cache_hits"] == 1
+    finally:
+        await pool.close()
+        await cluster.stop()
+
+
+@pytest.mark.parametrize("after_header",
+                         ["unlinked", "replaced", "truncated"])
+async def test_read_blocks_block_changed_after_the_header(cluster, tmp_path,
+                                                          after_header):
+    """Every slot is open before the header goes out. Blocks unlinked or
+    replaced (a new file renamed over it) after the header are still sent
+    whole, as they were when opened. A block cut short after the header
+    tears the frame: the engine sends nothing it did not read, closes the
+    connection mid-payload and counts the frame in ``rbs_torn``. The frame
+    is larger than both sockets' buffers together, so the last block is
+    read after the client has seen the header."""
+    import os
+    import socket
+
+    cs = await _plane(cluster, tmp_path, "native")
+    size = 4 << 20
+    blocks = {f"big{i}": _rand(size, 30 + i) for i in range(5)}
+    for bid, data in blocks.items():
+        cs.store.write(bid, data)
+    paths = {bid: cs.store.block_path(bid) for bid in blocks}
+    ids = list(blocks)
+    want = b"".join(blocks.values())
+    empty = _frame_bytes({"ok": True, "_d": 1, "sizes": []}, b"")
+
+    def exchange() -> tuple[bytes, bytes, bytes]:
+        with socket.socket() as sock:
+            # A small receive window: the engine blocks in its writes long
+            # before it reaches the last block.
+            sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 64 << 10)
+            sock.settimeout(30)
+            sock.connect(("127.0.0.1", cs.data_port))
+            sock.sendall(b"".join(blocknet._pack_frame(
+                {"m": "ReadBlocks", "block_ids": ids}, None)))
+            head = _recv_exact(sock, 4)
+            head += _recv_exact(sock, blocknet._U32.unpack(head)[0] + 8)
+            for bid, path in paths.items():
+                if after_header == "unlinked":
+                    path.unlink()
+                elif after_header == "replaced":
+                    cs.store.write(bid, bytes(size))
+                elif bid == ids[-1]:
+                    os.truncate(path, size // 2)
+            payload = _recv_exact(sock, len(want))
+            if len(payload) < len(want):
+                return head, payload, sock.recv(1)  # b"": closed
+            # Still framed: the next request is answered.
+            sock.sendall(b"".join(blocknet._pack_frame(
+                {"m": "ReadBlocks", "block_ids": []}, None)))
+            return head, payload, _recv_exact(sock, len(empty))
+
+    try:
+        before = cs.read_stage_stats()
+        head, payload, after = await asyncio.to_thread(exchange)
+        assert head == _frame_bytes(
+            {"ok": True, "_d": 1, "sizes": [size] * 5}, want)[:len(head)]
+        moved = _moved(before, cs.read_stage_stats())
+        if after_header == "truncated":
+            # The four whole blocks, then the close: not one byte of the
+            # block whose read came up short.
+            assert payload == want[:4 * size] and after == b""
+            assert moved["rbs_torn"] == 1
+            assert moved["rbs_bytes"] == 4 * size
+        else:
+            assert payload == want and after == empty
+            assert "rbs_torn" not in moved
+            assert moved["rbs_bytes"] == len(want)
+            assert moved["rbs_frames"] == 2
+        assert "rbs_missing" not in moved
+    finally:
+        await cluster.stop()
+
+
+async def test_torn_read_blocks_frame_falls_back_in_the_combiner(tmp_path):
+    """A block whose pread fails after the header (here its replica's path
+    on the origin is a directory: it opens and sizes, and every pread of it
+    fails) tears the origin's frame. The combiner's round fails as on any
+    transport error and its blocks fall back to the verified per-block
+    path; the bytes handed over are the file's."""
+    import jax
+
+    from tests.test_tpu import _cluster_with_files, _confirmed_bytes
+    from tpudfs.tpu.hbm_reader import HbmReader
+
+    if not native.has_dataplane():
+        pytest.skip("native dataplane unavailable")
+    data = _rand(8 * 64 * 1024, 27)
+    c, client = await _cluster_with_files(tmp_path, [("/torn/f", data)])
+    try:
+        client.local_reads = False
+        meta = await client.get_file_info("/torn/f")
+        origin_addr = meta["blocks"][0]["locations"][0]
+        origin = next(cs for cs in c.chunkservers
+                      if cs.address == origin_addr)
+        assert origin._native_dp is not None
+        real = client.get_file_info
+
+        async def one_origin(path):
+            # Every block's first replica is the origin: one frame a round.
+            m = await real(path)
+            return dict(m, blocks=[
+                dict(b, locations=[origin_addr] + sorted(
+                    a for a in b["locations"] if a != origin_addr))
+                for b in m["blocks"]])
+
+        client.get_file_info = one_origin
+        bad = meta["blocks"][5]["block_id"]
+        path = origin.store.block_path(bad)
+        path.unlink()
+        path.mkdir()
+        (path / "entry").write_bytes(b"x")  # a directory of non-zero size
+        origin.invalidate_cached(bad)
+        reader = HbmReader(client, jax.devices()[:1], batch_reads=2)
+        before = origin.read_stage_stats()
+        blocks = await reader.read_file_to_device_blocks("/torn/f",
+                                                         verify="lazy")
+        moved = _moved(before, origin.read_stage_stats())
+        assert moved["rbs_torn"] == 1
+        assert blocks[5].batch is None and blocks[4].batch is None
+        assert await _confirmed_bytes(reader, blocks) == data
+    finally:
+        await c.stop()

@@ -228,16 +228,20 @@ class GroupCommitter:
 #: payload bytes sent, read ns (handler start to the response built: cache
 #: lookup, stat, pread + verify, or the cache copy), send ns, ``NOT_FOUND``
 #: answers, calls served from the block cache, QoS admission wait ns.
-#: ``rbs_*``: ``ReadBlocks`` frames, slots, payload bytes, read ns (handler
-#: start through every slot's pread to the response built: what the client
-#: waits for before the header), send ns, slots answered -1, admission
-#: wait ns. The engine serves each connection on a thread of its own, so
-#: admission is the only queue on the server's side (0 while QoS is off).
+#: ``rbs_*``: ``ReadBlocks`` frames, slots, payload bytes sent, read ns
+#: (the server's own read time of a frame: the native engine's opens and
+#: stats up to the header, then each block's pread, summed; this plane's
+#: handler start to the response built), send ns (summed writes), slots
+#: answered -1, admission wait ns, frames torn after their header (the
+#: native engine's pread failed or came up short: it closes the connection
+#: mid-payload; never on this plane). The engine serves each connection on
+#: a thread of its own, so admission is the only queue on the server's
+#: side (0 while QoS is off).
 READ_STAGE_KEYS = (
     "rb_calls", "rb_bytes", "rb_read_ns", "rb_send_ns", "rb_not_found",
     "rb_cache_calls", "rb_admit_ns",
     "rbs_frames", "rbs_slots", "rbs_bytes", "rbs_read_ns", "rbs_send_ns",
-    "rbs_missing", "rbs_admit_ns",
+    "rbs_missing", "rbs_admit_ns", "rbs_torn",
 )
 
 

@@ -172,7 +172,7 @@ async def _read_header(r) -> tuple[dict, int]:
 
 #: The reads whose request carries ``READ_TIMING_KEY`` while tracing is on:
 #: the server (native/dataplane.cc, or the chunkserver's handlers) answers
-#: with its own read time, ns, under ``READ_NS_KEY`` in the header.
+#: with its own time to the header, ns, under ``READ_NS_KEY`` in it.
 _TIMED_READS = frozenset({"ReadBlock", "ReadBlocks"})
 READ_TIMING_KEY = "_rt"
 READ_NS_KEY = "_rns"
@@ -917,13 +917,13 @@ class BlockConnPool:
                 header[READ_TIMING_KEY] = 1
             conn.writelines(_pack_frame(header, req.get("data")))
             await conn.drain()
-            # The wait for the header holds the peer's whole read (it sends
-            # nothing before it has the answer), the request's and the
+            # The wait for the header holds the peer's work up to it (a
+            # ReadBlock's whole read; a native ReadBlocks frame's opens and
+            # sizes, its preads follow the header), the request's and the
             # header's time on the wire, and this loop's lag in noticing
             # the header. ``engine_read_ms`` is the first of those, by the
-            # peer's own clock (cache lookup, stat, pread, verify: every
-            # block of a ReadBlocks frame); the rest of the span is wire and
-            # loop. The payload is the wire's and this loop's.
+            # peer's own clock; the rest of the span is wire and loop. The
+            # payload is the wire's, this loop's and a frame's preads.
             with telemetry.span("blockport.wait_header", method=method,
                                 addr=hostport) as waited:
                 resp, plen = await _read_header(conn)

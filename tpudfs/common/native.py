@@ -230,7 +230,7 @@ def _locked_load() -> ctypes.CDLL | None:
         # symbols and call them with mismatched arguments.
         lib.tpudfs_dataplane_abi.restype = ctypes.c_int64
         lib.tpudfs_dataplane_abi.argtypes = []
-        if lib.tpudfs_dataplane_abi() != 7:
+        if lib.tpudfs_dataplane_abi() != 8:
             raise AttributeError("dataplane ABI mismatch")
         lib.tpudfs_dataplane_start.restype = ctypes.c_int64
         lib.tpudfs_dataplane_start.argtypes = [
@@ -279,7 +279,8 @@ def _locked_load() -> ctypes.CDLL | None:
         lib.tpudfs_dataplane_take_qos.argtypes = [
             ctypes.c_int64, ctypes.c_char_p, ctypes.c_uint64,
         ]
-        # ABI 7: the read path's stage clocks (ChunkServer.read_stage_stats).
+        # ABI 7: the read path's stage clocks (ChunkServer.read_stage_stats);
+        # ABI 8 appends rbs_torn to them.
         lib.tpudfs_dataplane_read_stats.restype = None
         lib.tpudfs_dataplane_read_stats.argtypes = [ctypes.c_int64,
                                                     ctypes.c_void_p]
